@@ -18,6 +18,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import substrate
 from repro.kernels import ref
 from repro.parallel.ring_attention import ring_attention
 from repro.testing.timing import now
@@ -27,7 +28,7 @@ from repro.topology import Topology
 def main():
     # 2 clusters x 4 lanes — the same geometry type the sim prices
     topo = Topology(2, 4, cluster_axis="cluster", lane_axis="lane")
-    mesh = jax.make_mesh(topo.shape, ("cluster", "lane"))
+    mesh = substrate.make_mesh(topo.shape, ("cluster", "lane"))
     n = topo.n_lanes
     rng = np.random.default_rng(0)
     B, S, H, Hkv, D = 1, n * 256, 8, 2, 64       # 2k tokens over the 8-ring

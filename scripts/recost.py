@@ -26,8 +26,6 @@ for f in sorted(outdir.glob("*pod16x16.json")):
     for n in (1, 2):
         lo, co = dr.lower_cell(dr._variant(cfg, n), cshape, mesh, n_micro=1)
         ca = co.cost_analysis()
-        if isinstance(ca, (list, tuple)):   # older jax: one dict per device
-            ca = ca[0]
         colls = parse_collectives(co.as_text())
         costs[n] = {"flops": float(ca.get("flops", 0.0)),
                     "bytes": float(ca.get("bytes accessed", 0.0)),

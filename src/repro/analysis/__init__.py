@@ -2,7 +2,7 @@
 
 AraXL's scaling argument only holds because *every* wire crossing is
 accounted for by the hierarchical interconnect; the software analogue in
-this repo is that every version-drifting jax call routes through
+this repo is that every SPMD-surface jax call routes through
 :mod:`repro.substrate` and every collective prices onto the declared
 :class:`repro.topology.Topology`.  This package turns those prose rules
 (ROADMAP) into a static-analysis pass with two fronts:
@@ -12,8 +12,9 @@ this repo is that every version-drifting jax call routes through
 
   =====  ==================================================================
   L1     substrate-only: no direct ``shard_map`` / ``lax.ppermute`` /
-         ``axis_index`` / ``axis_size`` / halo-``BlockSpec`` spellings
-         outside ``src/repro/substrate.py``
+         ``axis_index`` / ``axis_size`` / halo-``BlockSpec`` spellings,
+         and no ``jax.make_mesh`` / ``Mesh(...)`` construction, outside
+         ``src/repro/substrate.py``
   L2     import hygiene: no x64 flag flips outside
          ``src/repro/testing/x64.py``; no import-time ``XLA_FLAGS`` /
          ``JAX_PLATFORMS`` mutation in test modules outside
@@ -35,8 +36,10 @@ this repo is that every version-drifting jax call routes through
   S2     ring-schedule safety: every ``ppermute`` is a full-ring uniform
          circular shift (deadlock check) and no donated / aliased Pallas
          buffer is read while in flight
-  S3     Pallas budget: grid/BlockSpec divisibility and the static VRF
+  S3     Pallas blocks: grid/BlockSpec divisibility; the static VRF
          budget against the RVV 64 Kibit/vreg ceiling of ``AraXLParams``
+         for the paper's kernels, the TPU (8, 128) block tiling for the
+         language-model kernels
   =====  ==================================================================
 
 Suppression: append ``# repro: noqa(RULE)`` (comma-separated rules) to the
@@ -53,8 +56,8 @@ import pathlib
 
 #: rule id -> one-line description (the catalogue docs/ANALYSIS.md renders)
 RULES = {
-    "L1": "substrate-only: version-drifting jax APIs route through "
-          "repro.substrate",
+    "L1": "substrate-only: jax SPMD APIs and mesh construction route "
+          "through repro.substrate",
     "L2": "import hygiene: x64 flips only in repro.testing.x64; no "
           "import-time XLA_FLAGS/JAX_PLATFORMS mutation in test modules "
           "outside tests/conftest.py",
@@ -64,7 +67,8 @@ RULES = {
           "declared Topology without the flat fallback",
     "S2": "ring-schedule safety: full-ring uniform-shift ppermutes; no "
           "aliased in-flight buffer reads",
-    "S3": "Pallas VRF budget: block divisibility + 64 Kibit/vreg ceiling",
+    "S3": "Pallas blocks: divisibility; 64 Kibit/vreg ceiling for the "
+          "paper's kernels, TPU (8, 128) tiling for the LM kernels",
 }
 
 

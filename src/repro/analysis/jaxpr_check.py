@@ -9,10 +9,13 @@ mismatch, devices outside the topology) the roofline silently falls back
 to flat outermost-wire attribution — exactly the PR 2 fig6 memo-bug class
 this rule exists to catch before runtime.
 
-S3: every Pallas buffer (operand block or scratch) must fit an LMUL=8
-register group (8 x VLEN = 64 KiB at the RVV-maximum 64 Kibit/vreg of
-``AraXLParams``) and all resident buffers together must fit the 32-vreg
-VRF (256 KiB); blocked specs must tile their arrays exactly.
+S3: blocked specs must tile their arrays exactly.  The paper's kernels
+(stencils, reductions, softmax) also keep the emulated machine's budget:
+every Pallas buffer (operand block or scratch) must fit an LMUL=8 register
+group (8 x VLEN = 64 KiB at the RVV-maximum 64 Kibit/vreg of
+``AraXLParams``) and all resident buffers together the 32-vreg VRF
+(256 KiB).  The language-model kernels (matmul, flash, paged attention,
+rmsnorm) run on the TPU instead, so they obey its (8, 128) block tiling.
 
 The registry traces with ``jax.make_jaxpr`` only — nothing executes — but
 the ring/attention/MoE entries shard_map over an 8-device mesh, so the
@@ -137,44 +140,61 @@ def check_collective_pricing(closed_jaxpr, topology,
 
 
 # ---------------------------------------------------------------------------
-# S3 — Pallas grid/BlockSpec divisibility + VRF budget
+# S3 — Pallas grid/BlockSpec divisibility + block budget
 # ---------------------------------------------------------------------------
 
+#: TPU tiling rule: a block's last two dims are multiples of (8, 128) or
+#: equal the array's dims — what the Mosaic compiler enforces on v5e
+TPU_TILE = (8, 128)
+
+
 def _dim(d) -> int:
-    try:
-        return int(d)
-    except TypeError:                        # pl.Element-style wrapper
-        return int(getattr(d, "block_size"))
+    return int(getattr(d, "block_size", d))
+
+
+def _pallas_calls(closed_jaxpr):
+    for eqn, _ in iter_eqns(closed_jaxpr.jaxpr):
+        if eqn.primitive.name == "pallas_call":
+            yield eqn.params["grid_mapping"], eqn.params["jaxpr"]
+
+
+def _operand_blocks(gm):
+    """(index, block shape, array aval, blocked?) per operand/output."""
+    for i, bmap in enumerate(gm.block_mappings):
+        shape = tuple(_dim(d) for d in bmap.block_shape)
+        blocked = all(type(d).__name__ == "Blocked"
+                      for d in bmap.block_shape)
+        yield i, shape, bmap.array_aval, blocked
+
+
+def _ragged(gm, label) -> list[Finding]:
+    findings = []
+    for i, shape, arr, blocked in _operand_blocks(gm):
+        if not blocked or len(shape) != len(arr.shape):
+            continue
+        for bd, ad in zip(shape, arr.shape):
+            if bd and ad % bd:
+                findings.append(Finding(
+                    "S3", label, 0,
+                    f"operand {i}: array dim {ad} not divisible by block "
+                    f"dim {bd} (grid {tuple(gm.grid)}) — ragged trailing "
+                    f"block", "pad the array or pick a divisor block shape"))
+    return findings
 
 
 def check_pallas_budget(closed_jaxpr, params, label: str) -> list[Finding]:
-    """``params`` is an :class:`repro.sim.AraXLParams` — the budget source:
-    64 Kibit/vreg, 32 vregs, LMUL=8 groups."""
+    """The paper's kernels on the emulated vector machine.  ``params`` is
+    an :class:`repro.sim.AraXLParams` — the budget source: 64 Kibit/vreg,
+    32 vregs, LMUL=8 groups."""
     vreg_bytes = params.vlen_bits // 8
     buf_budget = LMUL_MAX * vreg_bytes       # one LMUL=8 register group
     total_budget = VRF_VREGS * vreg_bytes    # the whole VRF
     findings = []
-    for eqn, _ in iter_eqns(closed_jaxpr.jaxpr):
-        if eqn.primitive.name != "pallas_call":
-            continue
-        gm = eqn.params["grid_mapping"]
-        bufs = []                            # (description, nbytes)
-        for i, bmap in enumerate(gm.block_mappings):
-            shape = tuple(_dim(d) for d in bmap.block_shape)
-            arr = bmap.array_shape_dtype
-            nbytes = math.prod(shape) * arr.dtype.itemsize
-            bufs.append((f"operand {i} block {shape} ({arr.dtype})", nbytes))
-            if type(bmap.indexing_mode).__name__ == "Blocked" \
-                    and len(shape) == len(arr.shape):
-                for bd, ad in zip(shape, arr.shape):
-                    if bd and ad % bd:
-                        findings.append(Finding(
-                            "S3", label, 0,
-                            f"operand {i}: array dim {ad} not divisible "
-                            f"by block dim {bd} (grid {tuple(gm.grid)}) — "
-                            f"ragged trailing block",
-                            "pad the array or pick a divisor block shape"))
-        inner = eqn.params["jaxpr"]
+    for gm, inner in _pallas_calls(closed_jaxpr):
+        findings += _ragged(gm, label)
+        bufs = [(f"operand {i} block {shape} ({arr.dtype})",
+                 math.prod(shape) * arr.dtype.itemsize)
+                for i, shape, arr, _ in _operand_blocks(gm)]
         n_io = gm.num_inputs + gm.num_outputs
         for v in inner.invars[n_io:]:
             aval = getattr(v.aval, "inner_aval", v.aval)
@@ -200,6 +220,29 @@ def check_pallas_budget(closed_jaxpr, params, label: str) -> list[Finding]:
     return findings
 
 
+def check_tpu_tiling(closed_jaxpr, label: str) -> list[Finding]:
+    """The language-model kernels, which run on the TPU: blocks tile their
+    arrays exactly and obey :data:`TPU_TILE`.  No register budget — VMEM
+    legality comes from compiling for the chip (tests/test_tpu_compile.py)."""
+    findings = []
+    for gm, _ in _pallas_calls(closed_jaxpr):
+        findings += _ragged(gm, label)
+        for i, shape, arr, blocked in _operand_blocks(gm):
+            if not blocked:
+                continue
+            for bd, ad, q in zip(shape[-2:], arr.shape[-2:],
+                                 TPU_TILE[-len(shape[-2:]):]):
+                if bd != ad and bd % q:
+                    findings.append(Finding(
+                        "S3", label, 0,
+                        f"operand {i}: block {shape} of array "
+                        f"{tuple(arr.shape)} breaks the TPU {TPU_TILE} "
+                        f"tile (dim {bd} is neither a multiple of {q} nor "
+                        f"the whole {ad})",
+                        "round the block up to the tile and pad the array"))
+    return findings
+
+
 # ---------------------------------------------------------------------------
 # Entry-point registry
 # ---------------------------------------------------------------------------
@@ -209,7 +252,8 @@ class Entry:
     label: str
     closed_jaxpr: object
     topology: object | None      # declared Topology (S1) or None
-    params: object | None        # AraXLParams (S3) or None
+    params: object | None        # AraXLParams (S3 register budget) or None
+    tpu: bool = False            # S3 TPU tiling rule (the LM kernels)
 
 
 def _ring_entries():
@@ -261,14 +305,15 @@ def _ring_attention_entries():
     import jax
     import jax.numpy as jnp
     from repro.parallel.ring_attention import ring_attention
+    from repro import substrate
     from repro.topology import Topology
 
     q = jnp.zeros((1, 16, 2, 8), jnp.float32)
     topo3 = Topology.from_levels([("pod", 2, 8.0), ("cluster", 2, 4.0),
                                   ("lane", 2, 2.0)])
-    mesh3 = jax.make_mesh((2, 2, 2), ("pod", "cluster", "lane"))
+    mesh3 = substrate.make_mesh((2, 2, 2), ("pod", "cluster", "lane"))
     topo1 = Topology.from_levels([("lane", 8, 2.0)])
-    mesh1 = jax.make_mesh((8,), ("lane",))
+    mesh1 = substrate.make_mesh((8,), ("lane",))
     for sched in ("seq", "db"):
         yield Entry(
             f"entry:ring_attention[hier2x2x2,{sched}]",
@@ -286,6 +331,7 @@ def _moe_entries():
     import jax
     import jax.numpy as jnp
     from repro.configs import get_smoke_config
+    from repro import substrate
     from repro.models import layers as L
     from repro.parallel.sharding import ShardingRules, init_params
     from repro.topology import Topology
@@ -295,7 +341,7 @@ def _moe_entries():
         experts_per_token=2, capacity_factor=8.0, moe_impl="a2a")
     topo3 = Topology.from_levels([("pod", 2, 8.0), ("cluster", 2, 4.0),
                                   ("lane", 2, 2.0)])
-    mesh3 = jax.make_mesh((2, 2, 2), ("pod", "cluster", "lane"))
+    mesh3 = substrate.make_mesh((2, 2, 2), ("pod", "cluster", "lane"))
     axes = ("pod", "cluster", "lane")
     rules3 = ShardingRules(mesh3, {"batch": None, "seq": None,
                                    "fsdp": None, "model": axes,
@@ -327,7 +373,8 @@ def _kernel_entries():
     p64 = araxl_params(64)
     z = lambda *s: jnp.zeros(s, jnp.float32)
 
-    cases = [
+    # the language-model kernels run on the TPU: tiling rule, no RVV budget
+    tpu_cases = [
         ("fmatmul[256]", lambda: jax.make_jaxpr(
             lambda a, b: mm.matmul(a, b, interpret=True))(
                 z(256, 256), z(256, 256))),
@@ -342,6 +389,9 @@ def _kernel_entries():
         ("rmsnorm[D4096]", lambda: jax.make_jaxpr(
             lambda x, g: rn.rmsnorm(x, g, interpret=True))(
                 z(64, 4096), z(4096))),
+    ]
+    # the paper's kernels keep the AraXL register budget
+    cases = [
         ("jacobi2d[64x512]", lambda: jax.make_jaxpr(
             lambda x: st.jacobi2d(x, interpret=True))(z(66, 514))),
         ("fconv2d[64x512,7x7]", lambda: jax.make_jaxpr(
@@ -355,6 +405,8 @@ def _kernel_entries():
         ("softmax_rows[W2048]", lambda: jax.make_jaxpr(
             lambda x: red.softmax_rows(x, interpret=True))(z(64, 2048))),
     ]
+    for label, trace in tpu_cases:
+        yield Entry(f"entry:{label}", trace(), None, None, tpu=True)
     for label, trace in cases:
         yield Entry(f"entry:{label}", trace(), None, p64)
 
@@ -388,4 +440,6 @@ def semantic_findings() -> list[Finding]:
         if e.params is not None:
             findings += check_pallas_budget(e.closed_jaxpr, e.params,
                                             e.label)
+        if e.tpu:
+            findings += check_tpu_tiling(e.closed_jaxpr, e.label)
     return findings

@@ -27,7 +27,7 @@ L2_ENV_ALLOWED = ("tests/conftest.py",)
 L3_ALLOWED = ("benchmarks/run.py",)
 L4_ALLOWED = ("src/repro/testing/timing.py",)
 
-#: L1 — version-drifting jax surface that must route through the substrate.
+#: L1 — jax SPMD surface that must route through the substrate.
 #: Matched by exact resolved name or dotted prefix (so the module spelling
 #: ``jax.experimental.shard_map`` catches ``....shard_map.shard_map`` too).
 L1_BANNED = {
@@ -38,6 +38,14 @@ L1_BANNED = {
     "jax.lax.axis_size": "substrate.axis_size",
     "jax.experimental.pallas.Element": "substrate.halo_block_spec",
     "jax.experimental.pallas.Unblocked": "substrate.halo_block_spec",
+    "jax.make_mesh": "substrate.make_mesh",
+}
+
+#: L1 — constructors that are legal as types (annotations, isinstance) but
+#: must not be *called* outside the substrate: a direct ``Mesh(...)`` gets
+#: the jax default axis types instead of the repo's Auto axes.
+L1_BANNED_CALLS = {
+    "jax.sharding.Mesh": "substrate.make_mesh",
 }
 
 #: L4 — wall-clock sources (time.sleep stays legal: it waits, not measures)
@@ -161,10 +169,19 @@ class _Linter:
         for banned, repl in L1_BANNED.items():
             if _matches(resolved, banned):
                 self._add("L1", node,
-                          f"direct use of version-drifting `{resolved}`",
-                          f"route through repro.{repl} (the one "
-                          f"jax-version compatibility point)")
+                          f"direct use of `{resolved}`",
+                          f"route through repro.{repl} (the one point "
+                          f"of contact with JAX's SPMD surface)")
                 return
+
+    def _check_l1_call(self, node: ast.Call):
+        if self.relpath in L1_ALLOWED:
+            return
+        resolved = _resolve(node.func, self.aliases)
+        if resolved in L1_BANNED_CALLS:
+            self._add("L1", node, f"direct `{resolved}(...)` construction",
+                      f"route through repro.{L1_BANNED_CALLS[resolved]} "
+                      f"(Auto mesh axes)")
 
     def _check_l1_import(self, node: ast.Import | ast.ImportFrom):
         if self.relpath in L1_ALLOWED:
@@ -182,7 +199,7 @@ class _Linter:
             for banned, repl in L1_BANNED.items():
                 if full and _matches(full, banned):
                     self._add("L1", node,
-                              f"imports version-drifting `{full}`",
+                              f"imports `{full}` directly",
                               f"route through repro.{repl}")
                     return
 
@@ -292,6 +309,7 @@ class _Linter:
                                     ast.Lambda)):
                 child_depth = depth + 1
             elif isinstance(child, ast.Call):
+                self._check_l1_call(child)
                 self._check_l2_call(child)
                 self._check_l3(child)
                 self._check_l4(child)

@@ -12,9 +12,9 @@ form still works and builds the equivalent two-level Topology internally.
 """
 from __future__ import annotations
 
-import jax
 from jax.sharding import Mesh
 
+from repro import substrate
 from repro.topology import Topology
 from .isa import AraXLMachine
 from .layout import VectorMachineSpec
@@ -24,7 +24,7 @@ def make_vector_mesh(n_clusters: int, n_lanes: int,
                      cluster_axis: str = "cluster",
                      lane_axis: str = "lane") -> Mesh:
     """A (C, L) mesh over however many devices exist (C*L must divide in)."""
-    return jax.make_mesh((n_clusters, n_lanes), (cluster_axis, lane_axis))
+    return substrate.make_mesh((n_clusters, n_lanes), (cluster_axis, lane_axis))
 
 
 def make_topology_mesh(topology: Topology) -> Mesh:
@@ -35,7 +35,7 @@ def make_topology_mesh(topology: Topology) -> Mesh:
             raise ValueError(f"make_machine needs single-name level axes, "
                              f"got {l.axis!r}")
         names.append(l.axis)
-    return jax.make_mesh(topology.shape, tuple(names))
+    return substrate.make_mesh(topology.shape, tuple(names))
 
 
 def make_machine(n_clusters: int | None = None, n_lanes: int | None = None,

@@ -168,17 +168,15 @@ def rescale_rules(plan: ElasticPlan, lost_hosts, devices_per_host: int,
     just ``device_put`` against the re-derived shardings (see
     ``repro.checkpoint.restore_checkpoint``).  Returns ``(mesh, rules)``.
     """
-    import numpy as np
-    from jax.sharding import Mesh
-
+    from repro import substrate
     from repro.parallel.sharding import default_rules
 
     keep = survivor_devices(lost_hosts, devices_per_host, devices)
     if len(keep) < plan.new_devices:
         raise RescaleError(f"plan wants {plan.new_devices} devices but only "
                            f"{len(keep)} survived")
-    arr = np.array(keep[: plan.new_devices]).reshape(plan.new_mesh_shape)
-    mesh = Mesh(arr, ("data", "model"))
+    mesh = substrate.make_mesh(plan.new_mesh_shape, ("data", "model"),
+                               devices=keep)
     rule_kw.setdefault("batch", plan.new_global_batch)
     return mesh, default_rules(mesh, **rule_kw)
 

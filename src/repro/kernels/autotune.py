@@ -170,7 +170,8 @@ def enumerate_candidates(kernel: str, shape, dtype: str = "float32", *,
         cands = [{"bt": bt} for bt in _pow2_divisors(T, lo, 256)]
     elif kernel == "rmsnorm":
         R = shape[0]
-        cands = [{"bm": bm} for bm in _pow2_divisors(R, 1, 64)]
+        # the kernel rounds a row block up to the TPU sublane tile (8)
+        cands = [{"bm": bm} for bm in _pow2_divisors(R, min(8, R), 64)]
     elif kernel == "reduction":
         n = shape[0]
         lo = min_block or 256

@@ -1,5 +1,8 @@
 """Jit'd public wrappers: pick the Pallas kernel on TPU, the jnp reference
 elsewhere (the CPU dry-run lowers the jnp path; interpret=True is for tests).
+A call traced for a multi-device mesh also takes the reference: XLA cannot
+partition a Mosaic kernel across devices, and GSPMD partitions the jnp
+expression natively.
 
 Wrappers also normalise shapes (padding to block multiples) so callers never
 see tiling constraints, and resolve block shapes against the ambient
@@ -29,22 +32,40 @@ def _on_tpu() -> bool:
     return jax.devices()[0].platform == "tpu"
 
 
+#: every block choice made in this process: (kernel, shape, dtype) ->
+#: (blocks, source), source one of "explicit", "tuned" (the ambient
+#: autotune table) or "default" — what a chip run reports it compiled
+resolved: dict[tuple, tuple[dict, str]] = {}
+
+
 def _resolve(kernel, shape, dtype, **given):
     """Block-arg resolution: explicit args win, then the ambient autotune
     table, then `autotune.DEFAULTS`."""
     defaults = _at.DEFAULTS[kernel]
+    source = "explicit"
     if any(v is None for v in given.values()):
         cfg = _at.tuned_config(kernel, shape, str(dtype)) or {}
+        source = "tuned" if cfg else "default"
         given = {k: (v if v is not None else cfg.get(k, defaults[k]))
                  for k, v in given.items()}
-    return {k: int(v) for k, v in given.items()}
+    out = {k: int(v) for k, v in given.items()}
+    resolved[(kernel, tuple(int(s) for s in shape), str(dtype))] = (out,
+                                                                    source)
+    return out
 
 
-def _mode(use_pallas):
-    """use_pallas: None=auto (TPU only), True=pallas (interpret off-TPU),
-    False=reference."""
+def _spans_devices(*operands) -> bool:
+    """True when an operand is traced for (or placed on) a mesh of several
+    devices — read off its abstract value, so no caller passes a flag."""
+    return any(jax.typeof(x).sharding.mesh.size > 1 for x in operands)
+
+
+def _mode(use_pallas, *operands):
+    """use_pallas: None=auto (TPU, one device), True=pallas (interpret
+    off-TPU), False=reference."""
     if use_pallas is None:
-        return "pallas" if _on_tpu() else "ref"
+        return "pallas" if _on_tpu() and not _spans_devices(*operands) \
+            else "ref"
     if use_pallas and not _on_tpu():
         return "interpret"
     return "pallas" if use_pallas else "ref"
@@ -60,7 +81,7 @@ def _pad_to(x, mult, axis):
 
 
 def matmul(a, b, *, use_pallas=None, bm=None, bn=None, bk=None):
-    m = _mode(use_pallas)
+    m = _mode(use_pallas, a, b)
     if m == "ref":
         return ref.matmul(a, b)
     cfg = _resolve("matmul", (a.shape[0], a.shape[1], b.shape[1]), a.dtype,
@@ -162,7 +183,7 @@ def softmax_rows(x, *, use_pallas=None, bm=8):
 
 def attention(q, k, v, *, causal=True, window=None, use_pallas=None,
               bq=None, bk=None):
-    m = _mode(use_pallas)
+    m = _mode(use_pallas, q, k, v)
     if m == "ref":
         return ref.attention(q, k, v, causal=causal, window=window)
     B, Hq, S, D = q.shape
@@ -182,15 +203,13 @@ def attention(q, k, v, *, causal=True, window=None, use_pallas=None,
 
 
 def rmsnorm(x, gamma, *, eps=1e-6, use_pallas=None, bm=None):
-    m = _mode(use_pallas)
+    m = _mode(use_pallas, x, gamma)
     if m == "ref":
         return ref.rmsnorm(x, gamma, eps)
     shape = x.shape
     x2 = x.reshape(-1, shape[-1])
     R = x2.shape[0]
     bm = _resolve("rmsnorm", (R, shape[-1]), x.dtype, bm=bm)["bm"]
-    while R % bm:
-        bm -= 1
     out = _rms.rmsnorm(x2, gamma, bm=bm, eps=eps, interpret=(m == "interpret"))
     return out.reshape(shape)
 
@@ -201,7 +220,7 @@ def dense(x, w, *, use_pallas=None):
     Ref mode is *literally* ``x @ w`` (bit-identical to the historical
     inline call sites); Pallas mode flattens the leading dims and runs the
     tuned-block matmul."""
-    if _mode(use_pallas) == "ref":
+    if _mode(use_pallas, x, w) == "ref":
         return x @ w
     lead = x.shape[:-1]
     out = matmul(x.reshape(-1, x.shape[-1]), w, use_pallas=use_pallas)
@@ -216,7 +235,7 @@ def paged_attention(q, kpool, vpool, tables, lens, *, use_pallas=None):
     (scalar-prefetched tables drive the DMA).  The block size is baked
     into the pool layout, so tuning happens where the pool is *sized*
     (``serve.paged`` / :func:`paged_block_tokens`), not per call."""
-    m = _mode(use_pallas)
+    m = _mode(use_pallas, q, kpool, vpool)
     if m == "ref":
         return ref.paged_attention(q, kpool, vpool, tables, lens)
     return _pa.paged_attention(q, kpool, vpool, tables, lens,

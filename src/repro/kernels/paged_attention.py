@@ -20,9 +20,8 @@ Layouts (chosen so a block is contiguous per kv head):
 Grid (B, Hkv, nblk) with the block axis innermost: m/l/acc scratch
 carries the running softmax across a sequence's blocks exactly like
 ``flash_attention.py``'s kv loop.  `bt` (tokens per block) is the tuned
-parameter — the autotuner's VRF budget filter keeps (bt, D) K/V blocks
-inside one LMUL=8 register group, the same constraint the serving
-allocator's `max_block_tokens` applies.
+parameter.  A (1, 1, bt, D) block spans the pool's whole last two dims,
+so the TPU tiling rule accepts any `bt`.
 """
 from __future__ import annotations
 

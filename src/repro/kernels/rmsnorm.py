@@ -11,9 +11,16 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-# a row block must stay inside one LMUL=8 register group at the RVV-max
-# 64 Kibit VLEN (AraXLParams), or the lanes spill mid-sweep
-from .vrf import VREG_GROUP_BYTES as _VREG_GROUP_BYTES
+
+def block_rows(R: int, bm: int, dtype) -> int:
+    """Rows per block under the TPU tiling rule (a block's second-minor dim
+    is a multiple of 8 or the whole array): all of R when it fits in
+    ``bm``, else ``bm`` rounded up to the dtype's sublane tile — 8 rows of
+    32-bit, 16 of 16-bit data."""
+    if R <= bm:
+        return R
+    tile = 8 * max(1, 4 // jnp.dtype(dtype).itemsize)
+    return -(-bm // tile) * tile
 
 
 def _rms_kernel(x_ref, g_ref, o_ref, *, eps: float):
@@ -28,25 +35,23 @@ def rmsnorm(x: jax.Array, gamma: jax.Array, *, bm: int = 8,
             eps: float = 1e-6, interpret: bool = False) -> jax.Array:
     """x (R, D), gamma (D,) -> (R, D).
 
-    ``bm`` is a *ceiling*: it is halved until an (bm, D) f32 block fits one
-    LMUL=8 register group, so wide-model rows (D=4096 busts 8 rows x 16 KiB)
-    still stream without spilling, then lowered to a divisor of R so any
-    row count is legal.
+    ``bm`` is adjusted by :func:`block_rows`; R is zero-padded up to a
+    multiple of the block (a zero row normalises to zero) and the padding
+    sliced off, so any row count is legal.
     """
     R, D = x.shape
     assert gamma.shape == (D,)
-    bm = max(1, min(bm, R))
-    while bm > 1 and bm * D * 4 > _VREG_GROUP_BYTES:
-        bm //= 2
-    while R % bm:
-        bm -= 1
+    bm = block_rows(R, bm, x.dtype)
+    pad = -R % bm
+    xp = jnp.pad(x, ((0, pad), (0, 0))) if pad else x
     kernel = functools.partial(_rms_kernel, eps=eps)
-    return pl.pallas_call(
+    out = pl.pallas_call(
         kernel,
-        grid=(R // bm,),
+        grid=((R + pad) // bm,),
         in_specs=[pl.BlockSpec((bm, D), lambda i: (i, 0)),
                   pl.BlockSpec((D,), lambda i: (0,))],
         out_specs=pl.BlockSpec((bm, D), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((R, D), x.dtype),
+        out_shape=jax.ShapeDtypeStruct((R + pad, D), x.dtype),
         interpret=interpret,
-    )(x, gamma)
+    )(xp, gamma)
+    return out[:R] if pad else out

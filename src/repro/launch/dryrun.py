@@ -219,9 +219,7 @@ def analyse_cell(cfg: ModelConfig, shape: ShapeSpec, mesh,
         "outputs_gib": ma.output_size_in_bytes / 2**30,
         "temps_gib": ma.temp_size_in_bytes / 2**30,
         "aliased_gib": ma.alias_size_in_bytes / 2**30,
-        # this jax's CPU CompiledMemoryStats has no peak; fall back to the
-        # live-set estimate rather than dying on the backend difference
-        "peak_gib": getattr(ma, "peak_memory_in_bytes", live) / 2**30,
+        "peak_gib": ma.peak_memory_in_bytes / 2**30,
         "total_gib": live / 2**30,
     }
     # CPU arenas double-buffer where TPU aliases donated state: report the
@@ -244,8 +242,6 @@ def analyse_cell(cfg: ModelConfig, shape: ShapeSpec, mesh,
         lo, co = lower_cell(_variant(cfg, n), cshape, mesh, n_micro=1,
                             rules=rules, grad_sync=grad_sync)
         ca = co.cost_analysis()
-        if isinstance(ca, (list, tuple)):   # older jax: one dict per device
-            ca = ca[0]
         colls = parse_collectives(co.as_text())
         costs[n] = {
             "flops": float(ca.get("flops", 0.0)),

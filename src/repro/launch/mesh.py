@@ -15,8 +15,7 @@ dry-run compile describe the identical machine.
 """
 from __future__ import annotations
 
-import jax
-
+from repro import substrate
 from repro.topology import Level, Topology, parse_topology
 
 
@@ -66,4 +65,4 @@ def make_production_mesh(*, multi_pod: bool = False,
 
 def make_debug_mesh(n_data: int = 2, n_model: int = 2):
     """Small mesh for CPU multi-device tests."""
-    return jax.make_mesh((n_data, n_model), ("data", "model"))
+    return substrate.make_mesh((n_data, n_model), ("data", "model"))

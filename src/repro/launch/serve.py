@@ -1,5 +1,9 @@
 """Serving launcher: batched requests against a (smoke or full) model.
 
+``--full`` serves the published configuration of ``--arch`` (random
+weights from a fixed seed); without it the d_model-64 smoke variant.
+Prompts come in two fixed lengths (:data:`PROMPT_LENS`), so the dense
+engine compiles two prefill programs, whatever the request count.
 ``--paged`` swaps the dense per-slot KV cache for the block-table pool
 (``repro.serve.paged``) — ``--block-tokens`` sizes the blocks (0 = ask the
 autotune table via :func:`repro.kernels.ops.paged_block_tokens`) and
@@ -15,11 +19,15 @@ import jax
 import numpy as np
 
 from repro.configs import get_config, get_smoke_config
+from repro.launch import compile_cache
 from repro.models import lm
 from repro.parallel.sharding import default_rules, init_params
 from repro.serve import (PagedServeConfig, PagedServingEngine, PrefixRouter,
                          Request, ServeConfig, ServingEngine)
 from repro.testing.timing import now
+
+#: the prompt lengths requests cycle through
+PROMPT_LENS = (8, 20)
 
 
 def _make_engine(cfg, params, rules, *, paged: bool, max_batch: int,
@@ -55,7 +63,7 @@ def run(arch: str, *, smoke: bool = True, n_requests: int = 6,
     rng = np.random.default_rng(seed)
     t0 = now()
     for rid in range(n_requests):
-        plen = int(rng.integers(4, 24))
+        plen = PROMPT_LENS[rid % len(PROMPT_LENS)]
         prompt = rng.integers(1, cfg.vocab_size, plen).astype(np.int32)
         front.submit(Request(rid=rid, prompt=prompt, max_new_tokens=max_new))
     finished = front.run()
@@ -72,10 +80,12 @@ def run(arch: str, *, smoke: bool = True, n_requests: int = 6,
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="llama3-8b")
+    ap.add_argument("--full", action="store_true",
+                    help="full published config (default: smoke widths)")
     ap.add_argument("--requests", type=int, default=6)
     ap.add_argument("--max-new", type=int, default=16)
     ap.add_argument("--max-batch", type=int, default=4)
-    ap.add_argument("--max-seq", type=int, default=128)
+    ap.add_argument("--max-seq", type=int, default=1024)
     ap.add_argument("--paged", action="store_true",
                     help="block-table KV pool instead of dense slots")
     ap.add_argument("--block-tokens", type=int, default=0,
@@ -85,9 +95,11 @@ def main():
     ap.add_argument("--pods", type=int, default=1,
                     help="engines behind the prefix-affinity router")
     args = ap.parse_args()
-    run(args.arch, n_requests=args.requests, max_new=args.max_new,
-        max_batch=args.max_batch, max_seq=args.max_seq, paged=args.paged,
-        block_tokens=args.block_tokens, chunk=args.chunk, pods=args.pods)
+    compile_cache.enable()
+    run(args.arch, smoke=not args.full, n_requests=args.requests,
+        max_new=args.max_new, max_batch=args.max_batch, max_seq=args.max_seq,
+        paged=args.paged, block_tokens=args.block_tokens, chunk=args.chunk,
+        pods=args.pods)
 
 
 if __name__ == "__main__":

@@ -26,10 +26,12 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import substrate
 from repro.checkpoint import CheckpointManager, restore_checkpoint
 from repro.checkpoint.ckpt import latest_step, tear_checkpoint
 from repro.configs import get_config, get_smoke_config
 from repro.data import DataConfig, Pipeline, make_pipeline
+from repro.launch import compile_cache
 from repro.ft import (ChaosSchedule, FaultInjector, HeartbeatMonitor,
                       RestartPolicy, StragglerMitigator, plan_rescale,
                       rescale_rules)
@@ -112,9 +114,8 @@ def _fingerprint(batch: np.ndarray) -> int:
 
 
 def _host_mesh(devices, dp: int, model: int):
-    from jax.sharding import Mesh
-    return Mesh(np.array(devices[: dp * model]).reshape(dp, model),
-                ("data", "model"))
+    return substrate.make_mesh((dp, model), ("data", "model"),
+                               devices=devices)
 
 
 def _place_state(cfg, opt_cfg, seed: int, rules) -> TrainState:
@@ -339,6 +340,7 @@ def main():
     args = ap.parse_args()
     if args.procs and not args.chaos:
         ap.error("--procs requires --chaos")
+    compile_cache.enable()
     if args.chaos and args.procs:
         from repro.ft.cluster import ClusterSupervisor
         spec = args.chaos_spec
@@ -349,9 +351,10 @@ def main():
             spec = ChaosSchedule.from_seed(
                 args.chaos_seed, steps=args.steps, n_hosts=args.hosts,
                 n_kills=1, n_straggles=0, n_ckpt_crashes=0).to_spec()
+        # the parent stays off JAX: each worker owns its (emulated) devices
         sup = ClusterSupervisor(
             args.arch, steps=args.steps, n_hosts=args.hosts,
-            n_devices=len(jax.devices()), model_axis=args.model_axis,
+            model_axis=args.model_axis,
             global_batch=args.batch, seq_len=args.seq, lr=args.lr,
             ckpt_dir=args.ckpt, ckpt_every=args.ckpt_every,
             chaos_spec=spec, timeout_s=args.timeout,

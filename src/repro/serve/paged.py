@@ -26,9 +26,9 @@ Chunked streams are exact per the chunked-attention math but are not
 claimed bit-identical to the dense engine (the attention view is the
 padded ``max_seq`` window rather than the prompt length).
 
-Block sizing is tied to the same `kernels/vrf.py` budgets the S3 check
-enforces on every pallas_call: a (block_tokens, Hkv, Dh) K block must fit
-one LMUL=8 register group (:func:`max_block_tokens`).
+Block sizing: ``block_tokens`` must tile ``max_seq`` (and the prefill
+chunk).  Nothing else bounds it — decode gathers the pool with XLA, so a
+block is any size the pool's memory can hold.
 """
 from __future__ import annotations
 
@@ -40,7 +40,6 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs.base import ATTN, ModelConfig
-from repro.kernels.vrf import VREG_GROUP_BYTES
 from repro.models import lm
 from repro.parallel.sharding import ShardingRules
 from .engine import Request, validate_prompt
@@ -57,17 +56,6 @@ def kv_token_bytes(cfg: ModelConfig) -> int:
                  for kind in layer) * cfg.n_periods
     isz = jnp.dtype(cfg.dtype).itemsize
     return 2 * cfg.n_kv_heads * cfg.head_dim * isz * n_attn
-
-
-def max_block_tokens(cfg: ModelConfig, *, budget: int = VREG_GROUP_BYTES) -> int:
-    """Largest power-of-two block size whose per-layer K block fits one
-    LMUL=8 register group — the same ``kernels/vrf.py`` budget the S3
-    check enforces on pallas_call buffers."""
-    per_tok = cfg.n_kv_heads * cfg.head_dim * jnp.dtype(cfg.dtype).itemsize
-    bt = 1
-    while 2 * (2 * bt) * per_tok <= budget:
-        bt *= 2
-    return bt
 
 
 @dataclasses.dataclass(frozen=True)
@@ -208,10 +196,6 @@ class PagedServingEngine:
         if scfg.chunk and (scfg.chunk % bt or S % scfg.chunk):
             raise ValueError(f"chunk {scfg.chunk} must be a multiple of "
                              f"block_tokens {bt} and divide max_seq {S}")
-        cap = max_block_tokens(cfg)
-        if bt > cap:
-            raise ValueError(f"block_tokens {bt} busts the VREG-group "
-                             f"budget (max {cap} for this config)")
         self.cfg = cfg
         self.params = params
         self.rules = rules

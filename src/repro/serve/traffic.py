@@ -127,6 +127,8 @@ def _build(tag: str, args):
     holds the same token count in ``n_blocks`` blocks but serves
     ``max_batch`` slots over it."""
     import jax
+
+    from repro import substrate
     from repro.configs import get_smoke_config
     from repro.models import lm
     from repro.parallel.sharding import default_rules, init_params
@@ -138,7 +140,7 @@ def _build(tag: str, args):
     cfg = get_smoke_config(args.arch)
     mesh = topo = None
     if len(jax.devices()) >= 8:
-        mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+        mesh = substrate.make_mesh((2, 2, 2), ("pod", "data", "model"))
         topo = Topology.from_levels([("pod", 2, 8.0), ("data", 2, 4.0),
                                      ("model", 2, 2.0)])
     rules = default_rules(mesh, kv_heads=cfg.n_kv_heads, batch=1)

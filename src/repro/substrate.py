@@ -1,17 +1,13 @@
-"""Version-portable collectives substrate.
+"""The collectives and mesh substrate: one import point for the SPMD surface.
 
-JAX has moved/renamed its SPMD surface across minor releases: ``shard_map``
-graduated from ``jax.experimental.shard_map`` to ``jax.shard_map``, and
-``jax.lax.axis_size`` only exists on recent versions.  Every repro module
-resolves the primitives from here instead of guessing, so the whole codebase
-tracks one compatibility point.
+Every repro module takes these primitives from here, so the codebase
+tracks one point of contact with JAX's sharding API (jax 0.9):
 
-The exported surface is the subset the AraXL reproduction actually uses:
-
-* :func:`shard_map`   — the per-device SPMD mapper (wherever it lives)
+* :func:`make_mesh`   — a device mesh whose axes are all ``Auto`` (the
+  sharding-propagation mode every rule table here is written for)
+* :func:`shard_map`   — the per-device SPMD mapper
 * :func:`axis_size`   — static size of one or more mesh axes, usable inside
-  a ``shard_map`` body (derived via the ``psum(1, axes)`` identity, which
-  constant-folds to a Python int on every supported version)
+  a ``shard_map`` body
 * :func:`axis_index`  — flattened (row-major) device index over mesh axes
 * :func:`ppermute`    — neighbour permutation (the RINGI hop)
 * :func:`all_gather` / :func:`psum_scatter` — the XLA-native comparison
@@ -19,8 +15,7 @@ The exported surface is the subset the AraXL reproduction actually uses:
 * :func:`mesh_axis_size` — axis size read off a concrete ``Mesh`` (outside
   any traced context)
 * :func:`halo_block_spec` — an element-offset (overlapping halo) Pallas
-  ``BlockSpec``, portable across the ``pl.Element`` and
-  ``indexing_mode=pl.Unblocked`` spellings
+  ``BlockSpec``
 """
 from __future__ import annotations
 
@@ -28,7 +23,8 @@ import math
 from typing import Callable, Sequence
 
 import jax
-from jax.sharding import Mesh
+import numpy as np
+from jax.sharding import AxisType, Mesh
 
 Axis = str | Sequence[str]
 
@@ -39,52 +35,39 @@ def _axis_tuple(axis_names: Axis) -> tuple[str, ...]:
     return tuple(axis_names)
 
 
-def _resolve_shard_map() -> Callable:
-    fn = getattr(jax, "shard_map", None)
-    if fn is not None:
-        return fn
-    try:
-        from jax.experimental.shard_map import shard_map as fn  # type: ignore
-        return fn
-    except ImportError as e:  # pragma: no cover - one of the two must exist
-        raise ImportError(
-            "neither jax.shard_map nor jax.experimental.shard_map is "
-            f"available in jax {jax.__version__}") from e
+def make_mesh(shape: Sequence[int], axis_names: Sequence[str], *,
+              devices=None) -> Mesh:
+    """A mesh of ``shape`` over ``devices`` (default: the first
+    ``prod(shape)`` of ``jax.devices()``), every axis ``AxisType.Auto``.
 
-
-_SHARD_MAP = _resolve_shard_map()
+    ``jax.make_mesh`` defaults to Explicit axes, under which the rule-table
+    ``with_sharding_constraint`` calls and reshapes of sharded values are
+    refused; the whole repo shards by propagation, so its meshes are Auto.
+    """
+    shape, names = tuple(int(s) for s in shape), tuple(axis_names)
+    auto = (AxisType.Auto,) * len(names)
+    if devices is None:
+        return jax.make_mesh(shape, names, axis_types=auto)
+    arr = np.asarray(devices, dtype=object)[: math.prod(shape)]
+    return Mesh(arr.reshape(shape), names, axis_types=auto)
 
 
 def shard_map(f: Callable, *, mesh: Mesh, in_specs, out_specs, **kwargs):
-    """``shard_map`` resolved from wherever this jax version keeps it.
-
-    Same calling convention as the modern ``jax.shard_map`` for the argument
-    subset this repo uses (``mesh``/``in_specs``/``out_specs`` keywords).
-    """
-    return _SHARD_MAP(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      **kwargs)
+    """``jax.shard_map`` with the ``mesh``/``in_specs``/``out_specs``
+    keywords this repo uses, under ``jax.jit``: called eagerly, a bare
+    shard_map runs its body one primitive at a time, compiling each; jitted
+    it compiles once (and inlines into an enclosing jit)."""
+    return jax.jit(jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                                 out_specs=out_specs, **kwargs))
 
 
 def axis_size(axis_names: Axis) -> int:
-    """Size of (the product of) mesh axes, inside a ``shard_map`` body.
-
-    ``jax.lax.axis_size`` where it exists; otherwise the portable
-    ``psum(1, axes)`` identity, which resolves to a static Python int
-    because the reduced value is a non-traced constant.
-    """
-    names = _axis_tuple(axis_names)
-    fn = getattr(jax.lax, "axis_size", None)
-    if fn is not None:
-        return fn(names)
-    return jax.lax.psum(1, names)
+    """Size of (the product of) mesh axes, inside a ``shard_map`` body."""
+    return jax.lax.axis_size(_axis_tuple(axis_names))
 
 
 def axis_index(axis_names: Axis) -> jax.Array:
-    """Flattened row-major index over ``axis_names`` (first axis major).
-
-    Built from single-axis ``jax.lax.axis_index`` calls so it works on
-    versions where the tuple form is missing.
-    """
+    """Flattened row-major index over ``axis_names`` (first axis major)."""
     names = _axis_tuple(axis_names)
     idx = jax.lax.axis_index(names[0])
     for a in names[1:]:
@@ -127,15 +110,8 @@ def mesh_axis_size(mesh: Mesh, axis_names: Axis) -> int:
 
 
 def halo_block_spec(block_shape: Sequence[int], index_map: Callable):
-    """Pallas ``BlockSpec`` whose ``index_map`` returns *element* offsets.
-
-    Overlapping halo windows (stencil reads) need element-granular block
-    placement.  Recent jax spells this ``pl.Element`` per dimension; older
-    versions use ``indexing_mode=pl.Unblocked()``.  Resolve whichever exists.
-    """
+    """Pallas ``BlockSpec`` whose ``index_map`` returns *element* offsets
+    (``pl.Element`` per dimension): overlapping halo windows for stencil
+    reads."""
     from jax.experimental import pallas as pl
-    element = getattr(pl, "Element", None)
-    if element is not None:
-        return pl.BlockSpec(tuple(element(b) for b in block_shape), index_map)
-    return pl.BlockSpec(tuple(block_shape), index_map,
-                        indexing_mode=pl.Unblocked())
+    return pl.BlockSpec(tuple(pl.Element(b) for b in block_shape), index_map)

@@ -8,6 +8,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import substrate
+
 
 def main(nd: int = 2, nm: int = 4) -> None:
     import dataclasses
@@ -18,7 +20,7 @@ def main(nd: int = 2, nm: int = 4) -> None:
                                          init_params)
     from repro.topology import Topology
 
-    mesh = jax.make_mesh((nd, nm), ("data", "model"))
+    mesh = substrate.make_mesh((nd, nm), ("data", "model"))
     cfg0 = get_smoke_config("qwen3-moe-235b-a22b")
     cfg0 = dataclasses.replace(cfg0, n_experts=8, experts_per_token=2,
                                capacity_factor=8.0)
@@ -51,14 +53,14 @@ def main(nd: int = 2, nm: int = 4) -> None:
     if nd * nm == 8:
         topo = Topology.from_levels([("pod", 2, 8.0), ("cluster", 2, 4.0),
                                      ("lane", 2, 2.0)])
-        mesh3 = jax.make_mesh((2, 2, 2), ("pod", "cluster", "lane"))
+        mesh3 = substrate.make_mesh((2, 2, 2), ("pod", "cluster", "lane"))
         axes = ("pod", "cluster", "lane")
         rules3 = ShardingRules(mesh3, {"batch": None, "seq": None,
                                        "fsdp": None, "model": axes,
                                        "kv": None, "cache_seq": None,
                                        "act_seq": axes})
         assert L.moe_mode(cfg_a2a, rules3) == "ep_a2a"
-        mesh1 = jax.make_mesh((8,), ("model",))
+        mesh1 = substrate.make_mesh((8,), ("model",))
         rules1 = default_rules(mesh1, act_seq=True, batch=B)
         with mesh1:
             got_flat1 = jax.jit(lambda p, x: L.moe_layer(

@@ -30,6 +30,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import substrate
 from repro.testing.timing import median_time_us
 from repro.testing.x64 import x64_mode
 
@@ -44,12 +45,12 @@ def _attn(n: int = 8) -> None:
     k = jnp.asarray(rng.normal(size=(B, S, Hkv, D)), jnp.float32)
     v = jnp.asarray(rng.normal(size=(B, S, Hkv, D)), jnp.float32)
 
-    mesh = jax.make_mesh((n,), ("data",))
+    mesh = substrate.make_mesh((n,), ("data",))
     cases = {"flat": dict(mesh=mesh)}
     if n == 8:
         topo = Topology.from_levels([("pod", 2, 8.0), ("cluster", 2, 4.0),
                                      ("lane", 2, 2.0)])
-        mesh3 = jax.make_mesh((2, 2, 2), ("pod", "cluster", "lane"))
+        mesh3 = substrate.make_mesh((2, 2, 2), ("pod", "cluster", "lane"))
         cases["hier2x2x2"] = dict(mesh=mesh3, topology=topo)
 
     for name, kw in cases.items():

@@ -15,6 +15,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import substrate
 from repro.testing.x64 import x64_mode
 
 #: |hier - flat| bound: same softmax terms, re-associated combine (f32)
@@ -33,7 +34,7 @@ def _main(n: int = 8) -> None:
     from repro.parallel.ring_attention import ring_attention
     from repro.topology import Topology
 
-    mesh = jax.make_mesh((n,), ("data",))
+    mesh = substrate.make_mesh((n,), ("data",))
     rng = np.random.default_rng(0)
     B, S, H, Hkv, D = 2, 8 * 16, 4, 2, 32
     q = jnp.asarray(rng.normal(size=(B, S, H, D)), jnp.float32)
@@ -44,7 +45,7 @@ def _main(n: int = 8) -> None:
     if n == 8:                       # the 2x2x2 three-level machine
         topo = Topology.from_levels([("pod", 2, 8.0), ("cluster", 2, 4.0),
                                      ("lane", 2, 2.0)])
-        mesh3 = jax.make_mesh((2, 2, 2), ("pod", "cluster", "lane"))
+        mesh3 = substrate.make_mesh((2, 2, 2), ("pod", "cluster", "lane"))
         hier = lambda q, k, v, causal, window: ring_attention(
             q, k, v, mesh3, topology=topo, causal=causal, window=window)
 

@@ -29,6 +29,8 @@ import sys
 import jax
 import numpy as np
 
+from repro import substrate
+
 
 def _drive(engine, reqs):
     for r in reqs:
@@ -46,7 +48,7 @@ def main(n: int = 8) -> None:
                              ServingEngine)
 
     assert len(jax.devices()) >= n, "need more fake devices"
-    mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+    mesh = substrate.make_mesh((2, 2, 2), ("pod", "data", "model"))
     cfg = get_smoke_config("llama3-8b")
     rules = default_rules(mesh, kv_heads=cfg.n_kv_heads, batch=1)
     params = init_params(lm.model_defs(cfg), jax.random.key(0))

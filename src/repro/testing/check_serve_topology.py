@@ -25,6 +25,8 @@ import sys
 import jax
 import numpy as np
 
+from repro import substrate
+
 
 def _spec_axes(spec) -> set:
     out = set()
@@ -53,7 +55,7 @@ def main(n: int = 8) -> None:
     from repro.topology import Topology
 
     assert len(jax.devices()) >= n, "need more fake devices"
-    mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+    mesh = substrate.make_mesh((2, 2, 2), ("pod", "data", "model"))
     topo = Topology.from_levels([("pod", 2, 8.0), ("data", 2, 4.0),
                                  ("model", 2, 2.0)])
     cfg = get_smoke_config("llama3-8b")

@@ -20,11 +20,13 @@ def pinned_env(devices: int = 8) -> dict[str, str]:
     """A child-process environment with the fake-device count, ``src`` on
     ``PYTHONPATH``, and the CPU platform pinned — the one way any repro
     subprocess (check modules, chaos cluster workers) gets its devices,
-    regardless of what this process inherited."""
+    regardless of what this process inherited.  These children are CPU
+    emulation: on a machine with an accelerator the chip belongs to one
+    process, so a child must never reach for it."""
     env = dict(os.environ)
     env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={devices}"
     env["PYTHONPATH"] = SRC + os.pathsep + env.get("PYTHONPATH", "")
-    env.setdefault("JAX_PLATFORMS", "cpu")
+    env["JAX_PLATFORMS"] = "cpu"
     return env
 
 

@@ -23,7 +23,8 @@ from repro import substrate
 from repro.analysis import RULES, Finding
 from repro.analysis.bench import validate_section
 from repro.analysis.jaxpr_check import (check_collective_pricing,
-                                        check_pallas_budget)
+                                        check_pallas_budget,
+                                        check_tpu_tiling)
 from repro.analysis.lint import lint_source
 from repro.analysis.schedule_check import (check_aliasing,
                                            check_ppermute_schedules,
@@ -76,6 +77,22 @@ def test_l1_catches_aliased_imports_and_halo_specs():
             "spec = pl.BlockSpec((8,), lambda i: (i,),\n"
             "                    indexing_mode=pl.Unblocked())\n")
     assert _rules(lint_source(halo, "src/repro/kernels/foo.py")) == ["L1"]
+
+
+def test_l1_flags_direct_mesh_construction():
+    """jax.make_mesh (Explicit axes by default) and a called Mesh(...) go
+    through substrate.make_mesh; Mesh as a type annotation stays legal."""
+    direct = "import jax\nmesh = jax.make_mesh((2, 4), ('a', 'b'))\n"
+    called = ("from jax.sharding import Mesh\n"
+              "mesh = Mesh(devs, ('data', 'model'))\n")
+    for src in (direct, called):
+        bad = lint_source(src, "src/repro/launch/foo.py")
+        assert _rules(bad) == ["L1"] and bad[0].line == 2
+        assert "substrate.make_mesh" in bad[0].hint
+    typed = ("from jax.sharding import Mesh\n"
+             "def f(mesh: Mesh) -> Mesh:\n    return mesh\n")
+    assert lint_source(typed, "src/repro/launch/foo.py") == []
+    assert lint_source(called, "src/repro/substrate.py") == []
 
 
 # ---------------------------------------------------------------------------
@@ -205,7 +222,7 @@ def _psum_jaxpr(mesh):
 
 
 def test_s1_fires_on_unpriced_axis_and_passes_on_declared():
-    mesh = jax.make_mesh((2, 4), ("cluster", "lane"))
+    mesh = substrate.make_mesh((2, 4), ("cluster", "lane"))
     closed = _psum_jaxpr(mesh)
     # the topology only declares the lane level: a psum over "cluster"
     # would be priced by the flat fallback -> finding
@@ -219,7 +236,7 @@ def test_s1_fires_on_unpriced_axis_and_passes_on_declared():
 
 
 def test_s1_fires_on_mesh_topology_size_mismatch():
-    mesh = jax.make_mesh((2, 4), ("cluster", "lane"))
+    mesh = substrate.make_mesh((2, 4), ("cluster", "lane"))
     closed = _psum_jaxpr(mesh)
     topo = Topology.from_levels([("cluster", 4, 4.0), ("lane", 2, 2.0)])
     bad = check_collective_pricing(closed, topo, "fixture:s1")
@@ -246,7 +263,7 @@ def test_s2_permutation_checker():
 
 
 def test_s2_fires_on_partial_ring_ppermute_and_not_on_full_shift():
-    mesh = jax.make_mesh((8,), ("lane",))
+    mesh = substrate.make_mesh((8,), ("lane",))
 
     def traced(perm):
         def f(x):
@@ -322,12 +339,37 @@ def test_s3_fires_on_vrf_budget_busts():
     assert _rules(bad) == ["S3"]
     assert any("register group" in f.message for f in bad)
     assert any("VRF" in f.message for f in bad)
-    # the repo's own wide-row kernel clamps its block under the group
+    # the repo's wide-row LM kernel runs on the TPU: S3 holds it to the
+    # (8, 128) tiling rule, not to the register group its block busts
     from repro.kernels.rmsnorm import rmsnorm
     wide = jnp.zeros((64, 4096), jnp.float32)
     closed = jax.make_jaxpr(
         lambda x, g: rmsnorm(x, g, interpret=True))(wide, jnp.ones((4096,)))
-    assert check_pallas_budget(closed, p, "entry:rmsnorm") == []
+    assert check_tpu_tiling(closed, "entry:rmsnorm") == []
+    assert any("register group" in f.message
+               for f in check_pallas_budget(closed, p, "entry:rmsnorm"))
+
+
+@pytest.mark.parametrize("block,ok", [((8, 128), True), ((64, 64), True),
+                                      ((4, 128), False), ((8, 96), False)])
+def test_s3_tpu_tiling_rule(block, ok):
+    """Blocks are multiples of (8, 128) or the whole array dim: (64, 64)
+    equals the array, (4, 128) and (8, 96) are neither."""
+    x = jnp.zeros((64, 64 if block[1] == 64 else 384), jnp.float32)
+
+    def call(x):
+        def k(x_ref, o_ref):
+            o_ref[...] = x_ref[...]
+        return pl.pallas_call(
+            k, grid=(x.shape[0] // block[0], x.shape[1] // block[1]),
+            in_specs=[pl.BlockSpec(block, lambda i, j: (i, j))],
+            out_specs=pl.BlockSpec(block, lambda i, j: (i, j)),
+            out_shape=jax.ShapeDtypeStruct(x.shape, x.dtype),
+            interpret=True)(x)
+
+    found = check_tpu_tiling(jax.make_jaxpr(call)(x), "fixture:s3")
+    assert (found == []) == ok, found
+    assert all("TPU" in f.message for f in found)
 
 
 # ---------------------------------------------------------------------------

@@ -11,6 +11,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro import substrate
 from repro.checkpoint import (CheckpointManager, SimulatedCrash, latest_step,
                               restore_checkpoint, save_checkpoint,
                               tear_checkpoint, valid_steps)
@@ -126,12 +127,10 @@ def test_empty_dir_has_no_latest(tmp_path):
 # ---------------------------------------------------------------------------
 
 def test_restore_onto_smaller_mesh(tmp_path):
-    from jax.sharding import Mesh
-
     defs = {"w": PV((16, 8), jnp.float32, ("fsdp", "model")),
             "b": PV((8,), jnp.float32, ("model",))}
     devices = jax.devices()
-    big = Mesh(np.array(devices).reshape(4, 2), ("data", "model"))
+    big = substrate.make_mesh((4, 2), ("data", "model"), devices=devices)
     from repro.parallel.sharding import default_rules
     big_rules = default_rules(big, batch=8)
 

@@ -100,8 +100,10 @@ def test_flash_attention(cfg, dtype):
                                **TOL[dtype])
 
 
-@pytest.mark.parametrize("shape", [(8, 512), (32, 4096), (16, 3072)])
+@pytest.mark.parametrize("shape", [(8, 512), (32, 4096), (16, 3072),
+                                   (4, 3072), (20, 3072)])
 def test_rmsnorm(shape):
+    """Rows below the block take the whole of R; 20 rows pad to 24."""
     x = rand(shape, jnp.float32)
     g = rand((shape[-1],), jnp.float32)
     got = ops.rmsnorm(x, g, use_pallas=True)

@@ -7,7 +7,7 @@ import pytest
 
 from repro.analysis.bench import load_serve_bench, validate_serve_bench
 from repro.serve import (BlockAllocator, PromptTooLongError, Request,
-                         kv_token_bytes, max_block_tokens, validate_prompt)
+                         kv_token_bytes, validate_prompt)
 from repro.testing.subproc import run_check
 
 
@@ -136,19 +136,33 @@ def test_decode_step_vector_pos_matches_scalar():
 
 
 # ---------------------------------------------------------------------------
-# block sizing against the VRF budget
+# block sizing: the pool only has to tile max_seq (no register budget)
 # ---------------------------------------------------------------------------
 
 def test_block_sizing_respects_vreg_budget():
-    from repro.configs import get_smoke_config
-    from repro.kernels.vrf import VREG_GROUP_BYTES
-    cfg = get_smoke_config("llama3-8b")
-    bt = max_block_tokens(cfg)
-    per_tok = cfg.n_kv_heads * cfg.head_dim * 4       # f32 smoke config
-    assert bt & (bt - 1) == 0                          # power of two
-    assert 2 * bt * per_tok <= VREG_GROUP_BYTES
-    assert 4 * bt * per_tok > VREG_GROUP_BYTES         # largest such
-    assert kv_token_bytes(cfg) > 0
+    """On the TPU the paged pool is sized by tiling alone: a one-layer cut
+    of phi3-mini at its published widths takes 64-token blocks, whose K
+    block (64 x 32 x 96 bf16 = 384 KiB) is six times the RISC-V register
+    group that used to cap it at 2 tokens.  A block that does not tile
+    max_seq is still refused."""
+    import dataclasses
+    from repro.configs import get_config
+    from repro.parallel.sharding import default_rules
+    from repro.serve import PagedServeConfig, PagedServingEngine
+    cfg = dataclasses.replace(get_config("phi3-mini-3.8b"), n_layers=1)
+    rules = default_rules(None)
+    eng = PagedServingEngine(cfg, None, rules,
+                             PagedServeConfig(max_batch=2, max_seq=256,
+                                              block_tokens=64, n_blocks=4,
+                                              chunk=128))
+    pk = eng.pool["l0"]["s0_attn"]["k"]
+    assert pk.shape == (1, 5, 64, cfg.n_kv_heads, cfg.head_dim)
+    assert 64 * cfg.n_kv_heads * cfg.head_dim * 2 == 6 * 65536
+    with pytest.raises(ValueError, match="multiple of"):
+        PagedServingEngine(cfg, None, rules,
+                           PagedServeConfig(max_batch=2, max_seq=256,
+                                            block_tokens=48, n_blocks=4))
+    assert kv_token_bytes(cfg) == 2 * cfg.n_kv_heads * cfg.head_dim * 2
 
 
 # ---------------------------------------------------------------------------

@@ -214,6 +214,43 @@ def test_train_loss_decreases_and_resumes(tmp_path):
     assert len(out2["losses"]) == 4
 
 
+def test_compile_cache_env_wins_else_fixed_path_in_checkout(monkeypatch):
+    from repro.launch import compile_cache
+    was = jax.config.jax_compilation_cache_dir
+    try:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/elsewhere/jax")
+        assert compile_cache.enable() == "/elsewhere/jax"
+        assert jax.config.jax_compilation_cache_dir == was
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        got = compile_cache.enable()
+        root = pathlib.Path(__file__).resolve().parents[1]
+        assert got == str(root / ".jax_cache") == compile_cache.enable()
+        assert jax.config.jax_compilation_cache_dir == got
+        assert ".jax_cache/" in (root / ".gitignore").read_text().split()
+    finally:
+        jax.config.update("jax_compilation_cache_dir", was)
+
+
+def test_make_mesh_axes_are_auto():
+    from jax.sharding import AxisType
+    from repro import substrate
+    devs = jax.devices()
+    for mesh in (substrate.make_mesh((1, 1), ("data", "model")),
+                 substrate.make_mesh((1, 1), ("data", "model"),
+                                     devices=devs[-1:])):
+        assert mesh.axis_types == (AxisType.Auto, AxisType.Auto)
+    assert substrate.make_mesh((1,), ("x",), devices=devs[-1:]) \
+        .devices.item() == devs[-1]
+
+
+def test_child_env_pins_cpu(monkeypatch):
+    """Check-module children never reach for an accelerator the parent
+    may hold."""
+    from repro.testing.subproc import pinned_env
+    monkeypatch.setenv("JAX_PLATFORMS", "tpu")
+    assert pinned_env()["JAX_PLATFORMS"] == "cpu"
+
+
 def test_serving_engine_batches_requests():
     from repro.launch.serve import run
     finished = run("deepseek-7b", smoke=True, n_requests=5, max_new=8,

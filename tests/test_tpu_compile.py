@@ -1,0 +1,179 @@
+"""Compile the serving path's kernels for a TPU v5e that is described, not
+attached: the TPU compiler is installed here, and it refuses what the chip
+would refuse (block tiling, VMEM, memory, a kernel in a multi-device
+jit).  Interpret mode cannot show that.  Shapes are phi3-mini-3.8b's
+published widths, at decode rows (max_batch 4), a prefill chunk (256) and
+a long prefill (2048).
+
+The topology is described inside a fixture (never at import: one process
+at a time may load the TPU library), and the persistent compilation cache
+is off around these compiles — an entry written for a described chip
+cannot be read back without one.
+"""
+import dataclasses
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro import substrate
+from repro.configs import get_config
+from repro.kernels import ops
+from repro.models import lm
+from repro.parallel.sharding import PV, default_rules, param_shardings
+
+PHI3 = get_config("phi3-mini-3.8b")
+D, F = PHI3.d_model, PHI3.d_ff
+PROJ = {"qkvo": (D, D), "mlp_in": (D, F), "mlp_out": (F, D)}
+ROWS = {"decode": 4, "chunk": 256, "prefill": 2048}
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setenv("TPU_LOG_DIR", "disabled")
+        try:
+            topo = topologies.get_topology_desc(platform="tpu",
+                                                topology_name="v5e:2x2")
+        except Exception as e:
+            pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    was_on = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield topo
+    jax.config.update("jax_enable_compilation_cache", was_on)
+    cc.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture
+def on_tpu(monkeypatch):
+    """Steer ``kernels.ops`` onto its TPU path (it asks jax.devices(),
+    which here is the CPU)."""
+    monkeypatch.setattr(ops, "_on_tpu", lambda: True)
+
+
+def _sds(shape, dtype, sharding):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+def _tree_sds(defs, sharding):
+    return jax.tree.map(lambda pv: _sds(pv.shape, pv.dtype, sharding), defs,
+                        is_leaf=lambda x: isinstance(x, PV))
+
+
+def _kernels(compiled) -> int:
+    return compiled.as_text().count("tpu_custom_call")
+
+
+@pytest.mark.parametrize("rows", ROWS.values(), ids=ROWS.keys())
+@pytest.mark.parametrize("proj", PROJ.values(), ids=PROJ.keys())
+def test_matmul_compiles_for_v5e(one_chip, on_tpu, proj, rows):
+    K, N = proj
+    compiled = jax.jit(ops.matmul).lower(
+        _sds((rows, K), jnp.bfloat16, one_chip),
+        _sds((K, N), jnp.bfloat16, one_chip)).compile()
+    assert _kernels(compiled) == 1
+
+
+@pytest.mark.parametrize("rows", [4, 20, 256, 2048])
+def test_rmsnorm_compiles_for_v5e(one_chip, on_tpu, rows):
+    """Decode rows (4) and a short prompt (20) take the whole of R as one
+    block; longer prefills take tile-aligned blocks over padded rows."""
+    compiled = jax.jit(lambda x, g: ops.rmsnorm(x, g, eps=PHI3.norm_eps)) \
+        .lower(_sds((rows, D), jnp.bfloat16, one_chip),
+               _sds((D,), jnp.float32, one_chip)).compile()
+    assert _kernels(compiled) == 1
+
+
+@pytest.mark.parametrize("S", [256, 2048])
+def test_flash_attention_compiles_for_v5e(one_chip, on_tpu, S):
+    """Not on the serving path yet (prefill uses chunked XLA attention),
+    but the kernel ops offers for it: phi3's 32 heads of 96."""
+    H, Dh = PHI3.n_heads, PHI3.head_dim
+    qkv = [_sds((1, H, S, Dh), jnp.bfloat16, one_chip)] * 3
+    compiled = jax.jit(ops.attention).lower(*qkv).compile()
+    assert _kernels(compiled) == 1
+
+
+def test_paged_attention_compiles_for_v5e(one_chip, on_tpu):
+    """The block-table decode kernel over a pool of 16-token blocks
+    (max_batch 4, max_seq 1024)."""
+    B, Hkv, Dh, bt, nblk = 4, PHI3.n_kv_heads, PHI3.head_dim, 16, 64
+    G = PHI3.n_heads // Hkv
+    pool = _sds((Hkv, B * nblk + 1, bt, Dh), jnp.bfloat16, one_chip)
+    compiled = jax.jit(ops.paged_attention).lower(
+        _sds((B, Hkv, G, Dh), jnp.bfloat16, one_chip), pool, pool,
+        _sds((B, nblk), jnp.int32, one_chip),
+        _sds((B,), jnp.int32, one_chip)).compile()
+    assert _kernels(compiled) == 1
+
+
+def test_one_layer_decode_step_compiles_for_v5e(one_chip, on_tpu):
+    """A full-width decode step of a one-layer cut: 2 norms and 7
+    projections are Pallas kernels, and the step fits the chip."""
+    cfg = dataclasses.replace(PHI3, n_layers=1)
+    rules = default_rules(None)
+    B, S = 4, 1024
+    step = jax.jit(lambda p, t, c, pos: lm.decode_step(p, t, c, pos, cfg,
+                                                       rules))
+    compiled = step.lower(
+        _tree_sds(lm.model_defs(cfg), one_chip),
+        _sds((B, 1), jnp.int32, one_chip),
+        _tree_sds(lm.cache_defs(cfg, B, S), one_chip),
+        _sds((B,), jnp.int32, one_chip)).compile()
+    assert _kernels(compiled) >= 9
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 16e9
+
+
+def test_one_layer_prefill_chunk_compiles_for_v5e(one_chip, on_tpu):
+    """The paged engine's chunked prefill at full width: a 256-token chunk
+    scattered into a pool of 16-token blocks."""
+    cfg = dataclasses.replace(PHI3, n_layers=1)
+    rules = default_rules(None)
+    S, bt, c = 1024, 16, 256
+    chunk = jax.jit(lambda p, t, pool, row, start, valid: lm.prefill_chunk(
+        p, t, pool, row, start, valid, cfg, rules))
+    compiled = chunk.lower(
+        _tree_sds(lm.model_defs(cfg), one_chip),
+        _sds((1, c), jnp.int32, one_chip),
+        _tree_sds(lm.pool_defs(cfg, 4 * S // bt + 1, bt), one_chip),
+        _sds((S // bt,), jnp.int32, one_chip),
+        _sds((), jnp.int32, one_chip), _sds((), jnp.int32, one_chip)).compile()
+    assert _kernels(compiled) >= 9
+
+
+def test_one_layer_mesh_decode_compiles_for_v5e_2x2(topo, on_tpu):
+    """The sharded serving step on a 2x2 (data x model) mesh: XLA cannot
+    partition a Mosaic kernel, so every seam takes the XLA expression
+    (GSPMD shards it) and the step compiles with no Pallas call."""
+    cfg = dataclasses.replace(PHI3, n_layers=1)
+    mesh = substrate.make_mesh((2, 2), ("data", "model"),
+                               devices=topo.devices)
+    rules = default_rules(mesh, kv_heads=cfg.n_kv_heads, batch=1)
+    B, S = 4, 1024
+
+    def placed(defs):
+        return jax.tree.map(
+            lambda pv, sh: jax.ShapeDtypeStruct(pv.shape, pv.dtype,
+                                                sharding=sh),
+            defs, param_shardings(defs, rules),
+            is_leaf=lambda x: isinstance(x, PV))
+
+    rep = jax.sharding.NamedSharding(mesh, jax.sharding.PartitionSpec())
+    step = jax.jit(lambda p, t, c, pos: lm.decode_step(p, t, c, pos, cfg,
+                                                       rules))
+    compiled = step.lower(
+        placed(lm.model_defs(cfg)), _sds((B, 1), jnp.int32, rep),
+        placed(lm.cache_defs(cfg, B, S)), _sds((B,), jnp.int32, rep)).compile()
+    text = compiled.as_text()
+    assert _kernels(compiled) == 0
+    assert "all-reduce" in text                 # tensor-parallel partials
