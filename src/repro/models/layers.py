@@ -68,6 +68,7 @@ def attn_defs(cfg: ModelConfig, cross: bool = False) -> dict:
     }
 
 
+@jax.named_scope("attn.qkv")
 def _qkv(p, x, cfg: ModelConfig, rules, positions, rotate: bool):
     B, S, _ = x.shape
     hd = cfg.head_dim
@@ -150,10 +151,12 @@ def attn_layer(p, x, cfg: ModelConfig, rules: ShardingRules, positions,
     """Training / prefill self-attention (residual included)."""
     B, S, d = x.shape
     q, k, v = _qkv(p, x, cfg, rules, positions, rotate=True)
-    o = _sdpa_chunked(q, k, v, cfg, rules, causal=causal)
-    o = kops.dense(o.reshape(B, S, cfg.n_heads * cfg.head_dim), p["wo"])
-    o = constraint(o, rules, "batch", None, None)
-    return x + o.astype(x.dtype)
+    with jax.named_scope("attn.core"):
+        o = _sdpa_chunked(q, k, v, cfg, rules, causal=causal)
+    with jax.named_scope("attn.out"):
+        o = kops.dense(o.reshape(B, S, cfg.n_heads * cfg.head_dim), p["wo"])
+        o = constraint(o, rules, "batch", None, None)
+        return x + o.astype(x.dtype)
 
 
 class AttnCache(NamedTuple):
@@ -291,18 +294,22 @@ def attn_layer_prefill(p, x, cfg: ModelConfig, rules, positions, cache_len):
     """Prefill: run attention AND return the populated cache."""
     B, S, _ = x.shape
     q, k, v = _qkv(p, x, cfg, rules, positions, rotate=True)
-    o = _sdpa_chunked(q, k, v, cfg, rules, causal=True)
-    o = kops.dense(o.reshape(B, S, cfg.n_heads * cfg.head_dim), p["wo"])
+    with jax.named_scope("attn.core"):
+        o = _sdpa_chunked(q, k, v, cfg, rules, causal=True)
+    with jax.named_scope("attn.out"):
+        o = kops.dense(o.reshape(B, S, cfg.n_heads * cfg.head_dim), p["wo"])
     W = cache_len
-    if W >= S:
-        pad = [(0, 0), (0, W - S), (0, 0), (0, 0)]
-        ck, cv = jnp.pad(k, pad), jnp.pad(v, pad)
-    else:                                   # SWA ring buffer: last W tokens,
-        tail_k, tail_v = k[:, S - W:], v[:, S - W:]   # placed at slot pos%W
-        roll = (S - W) % W
-        ck = jnp.roll(tail_k, shift=roll, axis=1)
-        cv = jnp.roll(tail_v, shift=roll, axis=1)
-    return x + o.astype(x.dtype), AttnCache(ck, cv)
+    with jax.named_scope("attn.kv_write"):
+        if W >= S:
+            pad = [(0, 0), (0, W - S), (0, 0), (0, 0)]
+            ck, cv = jnp.pad(k, pad), jnp.pad(v, pad)
+        else:                               # SWA ring buffer: last W tokens,
+            tail_k, tail_v = k[:, S - W:], v[:, S - W:]  # at slot pos % W
+            roll = (S - W) % W
+            ck = jnp.roll(tail_k, shift=roll, axis=1)
+            cv = jnp.roll(tail_v, shift=roll, axis=1)
+    with jax.named_scope("attn.out"):
+        return x + o.astype(x.dtype), AttnCache(ck, cv)
 
 
 # -- paged attention (block-table KV pool) -----------------------------------
@@ -331,29 +338,32 @@ def attn_layer_decode_paged(p, x, pk, pv, tables, pos, live,
     NB, bt, Hkv, hd = pk.shape
     W = tables.shape[1] * bt
     q, k, v = _qkv(p, x, cfg, rules, pos[:, None], rotate=True)
-    blk = jnp.take_along_axis(tables, (pos // bt)[:, None], axis=1)[:, 0]
-    off = pos % bt
-    cur_k, cur_v = pk[blk, off], pv[blk, off]          # (B, Hkv, Dh)
-    nk = jnp.where(live[:, None, None], k[:, 0].astype(pk.dtype), cur_k)
-    nv = jnp.where(live[:, None, None], v[:, 0].astype(pv.dtype), cur_v)
-    pk = pk.at[blk, off].set(nk)
-    pv = pv.at[blk, off].set(nv)
-    ck = pk[tables].reshape(B, W, Hkv, hd)
-    cv = pv[tables].reshape(B, W, Hkv, hd)
-    ck = constraint(ck, rules, "batch", None, "kv", None)
-    cv = constraint(cv, rules, "batch", None, "kv", None)
-    G = cfg.n_heads // cfg.n_kv_heads
-    qg = q.reshape(B, S1, Hkv, G, hd)
-    idx = jnp.arange(W)
-    s = jnp.einsum("bqhgd,bthd->bhgqt", qg.astype(jnp.float32),
-                   ck.astype(jnp.float32)) / math.sqrt(hd)
-    mask = idx <= pos[:, None]                         # (B, W) causal
-    s = jnp.where(mask[:, None, None, None, :], s, -1e30)
-    pr = jax.nn.softmax(s, axis=-1)
-    o = jnp.einsum("bhgqt,bthd->bqhgd", pr, cv.astype(jnp.float32))
-    o = kops.dense(o.reshape(B, S1, cfg.n_heads * hd).astype(x.dtype),
-                   p["wo"])
-    return x + o.astype(x.dtype), pk, pv
+    with jax.named_scope("attn.kv_write"):
+        blk = jnp.take_along_axis(tables, (pos // bt)[:, None], axis=1)[:, 0]
+        off = pos % bt
+        cur_k, cur_v = pk[blk, off], pv[blk, off]      # (B, Hkv, Dh)
+        nk = jnp.where(live[:, None, None], k[:, 0].astype(pk.dtype), cur_k)
+        nv = jnp.where(live[:, None, None], v[:, 0].astype(pv.dtype), cur_v)
+        pk = pk.at[blk, off].set(nk)
+        pv = pv.at[blk, off].set(nv)
+    with jax.named_scope("attn.core"):
+        ck = pk[tables].reshape(B, W, Hkv, hd)
+        cv = pv[tables].reshape(B, W, Hkv, hd)
+        ck = constraint(ck, rules, "batch", None, "kv", None)
+        cv = constraint(cv, rules, "batch", None, "kv", None)
+        G = cfg.n_heads // cfg.n_kv_heads
+        qg = q.reshape(B, S1, Hkv, G, hd)
+        idx = jnp.arange(W)
+        s = jnp.einsum("bqhgd,bthd->bhgqt", qg.astype(jnp.float32),
+                       ck.astype(jnp.float32)) / math.sqrt(hd)
+        mask = idx <= pos[:, None]                     # (B, W) causal
+        s = jnp.where(mask[:, None, None, None, :], s, -1e30)
+        pr = jax.nn.softmax(s, axis=-1)
+        o = jnp.einsum("bhgqt,bthd->bqhgd", pr, cv.astype(jnp.float32))
+    with jax.named_scope("attn.out"):
+        o = kops.dense(o.reshape(B, S1, cfg.n_heads * hd).astype(x.dtype),
+                       p["wo"])
+        return x + o.astype(x.dtype), pk, pv
 
 
 def attn_layer_prefill_paged(p, x, pk, pv, table_row, start, valid,
@@ -373,28 +383,31 @@ def attn_layer_prefill_paged(p, x, pk, pv, table_row, start, valid,
     W = table_row.shape[0] * bt
     positions = start + jnp.arange(c)
     q, k, v = _qkv(p, x, cfg, rules, positions[None, :], rotate=True)
-    ok = (jnp.arange(c) < valid)[None, :, None, None]
-    kz = jnp.where(ok, k, 0).astype(pk.dtype)
-    vz = jnp.where(ok, v, 0).astype(pv.dtype)
-    nblk = c // bt
-    bids = jax.lax.dynamic_slice(table_row, (start // bt,), (nblk,))
-    pk = pk.at[bids].set(kz[0].reshape(nblk, bt, Hkv, hd))
-    pv = pv.at[bids].set(vz[0].reshape(nblk, bt, Hkv, hd))
-    ck = pk[table_row].reshape(1, W, Hkv, hd)
-    cv = pv[table_row].reshape(1, W, Hkv, hd)
-    ck = constraint(ck, rules, "batch", None, "kv", None)
-    cv = constraint(cv, rules, "batch", None, "kv", None)
-    G = cfg.n_heads // cfg.n_kv_heads
-    qg = q.reshape(B, c, Hkv, G, hd)
-    mask = jnp.arange(W)[None, :] <= positions[:, None]   # (c, W) causal
-    s = jnp.einsum("bqhgd,bthd->bhgqt", qg.astype(jnp.float32),
-                   ck.astype(jnp.float32)) / math.sqrt(hd)
-    s = jnp.where(mask[None, None, None], s, -1e30)
-    pr = jax.nn.softmax(s, axis=-1)
-    o = jnp.einsum("bhgqt,bthd->bqhgd", pr, cv.astype(jnp.float32))
-    o = kops.dense(o.reshape(B, c, cfg.n_heads * hd).astype(x.dtype),
-                   p["wo"])
-    return x + o.astype(x.dtype), pk, pv
+    with jax.named_scope("attn.kv_write"):
+        ok = (jnp.arange(c) < valid)[None, :, None, None]
+        kz = jnp.where(ok, k, 0).astype(pk.dtype)
+        vz = jnp.where(ok, v, 0).astype(pv.dtype)
+        nblk = c // bt
+        bids = jax.lax.dynamic_slice(table_row, (start // bt,), (nblk,))
+        pk = pk.at[bids].set(kz[0].reshape(nblk, bt, Hkv, hd))
+        pv = pv.at[bids].set(vz[0].reshape(nblk, bt, Hkv, hd))
+    with jax.named_scope("attn.core"):
+        ck = pk[table_row].reshape(1, W, Hkv, hd)
+        cv = pv[table_row].reshape(1, W, Hkv, hd)
+        ck = constraint(ck, rules, "batch", None, "kv", None)
+        cv = constraint(cv, rules, "batch", None, "kv", None)
+        G = cfg.n_heads // cfg.n_kv_heads
+        qg = q.reshape(B, c, Hkv, G, hd)
+        mask = jnp.arange(W)[None, :] <= positions[:, None]  # (c, W) causal
+        s = jnp.einsum("bqhgd,bthd->bhgqt", qg.astype(jnp.float32),
+                       ck.astype(jnp.float32)) / math.sqrt(hd)
+        s = jnp.where(mask[None, None, None], s, -1e30)
+        pr = jax.nn.softmax(s, axis=-1)
+        o = jnp.einsum("bhgqt,bthd->bqhgd", pr, cv.astype(jnp.float32))
+    with jax.named_scope("attn.out"):
+        o = kops.dense(o.reshape(B, c, cfg.n_heads * hd).astype(x.dtype),
+                       p["wo"])
+        return x + o.astype(x.dtype), pk, pv
 
 
 # -- cross attention ---------------------------------------------------------
@@ -467,6 +480,7 @@ def mlp_defs(cfg: ModelConfig) -> dict:
     }
 
 
+@jax.named_scope("mlp")
 def mlp_layer(p, x, cfg: ModelConfig, rules: ShardingRules):
     xn = rmsnorm(x, p["norm"], cfg.norm_eps)
     h = silu(kops.dense(xn, p["wg"])) * kops.dense(xn, p["wi"])

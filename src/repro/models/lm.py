@@ -210,6 +210,7 @@ def trunk(params, x, cfg: ModelConfig, rules: ShardingRules, positions,
     return x
 
 
+@jax.named_scope("embed")
 def embed_tokens(params, tokens, cfg: ModelConfig, rules: ShardingRules):
     mesh = rules.mesh
     if mesh is None or "model" not in mesh.shape or \
@@ -249,6 +250,7 @@ def _mask_pad_vocab(logits, cfg: ModelConfig):
                      logits)
 
 
+@jax.named_scope("head")
 def logits_fn(params, x, cfg: ModelConfig, rules: ShardingRules):
     x = L.rmsnorm(x, params["final_norm"], cfg.norm_eps)
     head = params["embed"].T if cfg.tie_embeddings else params["head"]

@@ -85,6 +85,11 @@ class Request:
     out: list = dataclasses.field(default_factory=list)
     done: bool = False
     slot: int | None = None             # set at admit (observability)
+    # stamps on repro.testing.timing.now(), set by the paged engine
+    t_submit: float | None = None       # submit() accepted it
+    t_admit: float | None = None        # it left the queue for a slot
+    t_prefill_start: float | None = None  # its first prefill dispatched
+    t_first: float | None = None        # its first token read back
 
 
 @dataclasses.dataclass(frozen=True)

@@ -29,6 +29,17 @@ padded ``max_seq`` window rather than the prompt length).
 Block sizing: ``block_tokens`` must tile ``max_seq`` (and the prefill
 chunk).  Nothing else bounds it — decode gathers the pool with XLA, so a
 block is any size the pool's memory can hold.
+
+Tracing: the three programs are named (``jit_decode_step_paged``,
+``jit_prefill_chunk``, ``jit_prefill`` in a device trace), and every
+:meth:`PagedServingEngine.step` is a ``serve.step`` span holding
+``serve.admit`` (``admitted``, ``blocked``), ``serve.prefill_chunk``
+(``start``, ``valid``), ``serve.decode.prepare``, ``serve.decode.dispatch``
+(``rows``), ``serve.decode.readback`` and ``serve.retire``.  A span costs
+a flag check when no profiler runs.  Each request is stamped on
+:func:`repro.testing.timing.now`: ``t_submit``, ``t_admit``,
+``t_prefill_start`` (dispatch of its first chunk, or of its whole-prompt
+prefill) and ``t_first``, which split its time to the first token.
 """
 from __future__ import annotations
 
@@ -38,10 +49,12 @@ import math
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.configs.base import ATTN, ModelConfig
 from repro.models import lm
 from repro.parallel.sharding import ShardingRules
+from repro.testing.timing import now
 from .engine import Request, validate_prompt
 
 # chunked-prefill slot states
@@ -220,14 +233,21 @@ class PagedServingEngine:
         self.decode_steps = 0
         self.prefill_chunks = 0
 
-        self._prefill = jax.jit(
-            lambda p, t: lm.prefill(p, t, cfg, rules, S))
-        self._step = jax.jit(
-            lambda p, t, pool, tab, pos, lv: lm.decode_step_paged(
-                p, t, pool, tab, pos, lv, cfg, rules))
-        self._chunk = jax.jit(
-            lambda p, t, pool, row, start, valid: lm.prefill_chunk(
-                p, t, pool, row, start, valid, cfg, rules))
+        # named, so a device trace shows which program ran; ``lm`` is
+        # looked up when each is traced, not here
+        def prefill(p, t):
+            return lm.prefill(p, t, cfg, rules, S)
+
+        def decode_step_paged(p, t, pool, tab, pos, lv):
+            return lm.decode_step_paged(p, t, pool, tab, pos, lv, cfg, rules)
+
+        def prefill_chunk(p, t, pool, row, start, valid):
+            return lm.prefill_chunk(p, t, pool, row, start, valid, cfg,
+                                    rules)
+
+        self._prefill = jax.jit(prefill)
+        self._step = jax.jit(decode_step_paged)
+        self._chunk = jax.jit(prefill_chunk)
 
     # -- observability -------------------------------------------------------
     @property
@@ -260,6 +280,7 @@ class PagedServingEngine:
             raise ValueError(
                 f"request needs up to {worst} blocks but the pool holds "
                 f"{self.scfg.n_blocks}")
+        req.t_submit = now()
         self.waiting.append(req)
 
     def _plan(self, req: Request):
@@ -308,14 +329,19 @@ class PagedServingEngine:
     def _free_slots(self) -> list[int]:
         return [i for i, s in enumerate(self.slots) if s is None]
 
-    def _admit(self):
+    def _admit(self) -> int:
+        """Admit from the head of the queue while a slot is free and the
+        pool covers the head's plan; returns how many were admitted."""
         free = self._free_slots()
+        admitted = 0
         while free and self.waiting:
             plan = self._plan(self.waiting[0])
             if plan is None:
                 break                       # head-of-line waits for blocks
             row, own, shared, reserve = plan
             req = self.waiting.pop(0)
+            req.t_admit = now()
+            admitted += 1
             slot = free.pop(0)
             req.slot = slot
             for bid in shared:
@@ -341,6 +367,7 @@ class PagedServingEngine:
                 self.slot_pos[slot] = 0
             else:
                 self._prefill_whole(slot, req, new_bids)
+        return admitted
 
     def _prefill_whole(self, slot: int, req: Request,
                        new_bids: list[tuple[int, int]]):
@@ -350,8 +377,10 @@ class PagedServingEngine:
         shared blocks already hold identical content and are skipped."""
         bt = self.scfg.block_tokens
         toks = jnp.asarray(req.prompt, jnp.int32)[None, :]
+        req.t_prefill_start = now()
         cache, logits = self._prefill(self.params, toks)
         req.out.append(int(jnp.argmax(logits[0, -1])))
+        req.t_first = now()
         if new_bids:
             js = jnp.asarray([j for j, _ in new_bids])
             bids = jnp.asarray([b for _, b in new_bids])
@@ -383,27 +412,34 @@ class PagedServingEngine:
         plen = len(prompt)
         start = int(self.slot_fill[i])
         valid = min(c, plen - start)
-        chunk = np.zeros((1, c), np.int32)
-        chunk[0, :valid] = prompt[start:start + valid]
-        logits, self.pool = self._chunk(
-            self.params, jnp.asarray(chunk), self.pool,
-            jnp.asarray(self.tables[i]), jnp.int32(start), jnp.int32(valid))
-        self.prefill_chunks += 1
-        self.slot_fill[i] = start + valid
-        if self.slot_fill[i] >= plen:
-            req.out.append(int(jnp.argmax(logits[0, valid - 1])))
-            self.slot_state[i] = DECODE
-            self.slot_pos[i] = plen
-            # content now complete: publish the owned prompt blocks
-            bt = self.scfg.block_tokens
-            nfull = plen // bt
-            for j, bid in self._slot_new[i]:
-                if j < nfull:
-                    key = ("full", tuple(int(t) for t in prompt[:(j + 1) * bt]))
-                else:
-                    key = ("part", tuple(int(t) for t in prompt))
-                self.alloc.register(bid, key)
-            self._slot_new[i] = []
+        with TraceAnnotation("serve.prefill_chunk", start=start,
+                             valid=valid):
+            chunk = np.zeros((1, c), np.int32)
+            chunk[0, :valid] = prompt[start:start + valid]
+            if start == 0:
+                req.t_prefill_start = now()
+            logits, self.pool = self._chunk(
+                self.params, jnp.asarray(chunk), self.pool,
+                jnp.asarray(self.tables[i]), jnp.int32(start),
+                jnp.int32(valid))
+            self.prefill_chunks += 1
+            self.slot_fill[i] = start + valid
+            if self.slot_fill[i] >= plen:
+                req.out.append(int(jnp.argmax(logits[0, valid - 1])))
+                req.t_first = now()
+                self.slot_state[i] = DECODE
+                self.slot_pos[i] = plen
+                # content now complete: publish the owned prompt blocks
+                bt = self.scfg.block_tokens
+                nfull = plen // bt
+                for j, bid in self._slot_new[i]:
+                    if j < nfull:
+                        key = ("full",
+                               tuple(int(t) for t in prompt[:(j + 1) * bt]))
+                    else:
+                        key = ("part", tuple(int(t) for t in prompt))
+                    self.alloc.register(bid, key)
+                self._slot_new[i] = []
         return True
 
     # -- decode --------------------------------------------------------------
@@ -450,12 +486,24 @@ class PagedServingEngine:
         self.slots[i] = None
 
     def step(self) -> bool:
-        self._admit()
-        worked = False
-        if self.scfg.chunk:
-            worked |= self._prefill_step()
-        live = self._decode_live()
-        if live:
+        with TraceAnnotation("serve.step"):
+            with TraceAnnotation("serve.admit") as span:
+                admitted = self._admit()
+                span.set_metadata(admitted=admitted, blocked=len(self.waiting))
+            worked = False
+            if self.scfg.chunk:
+                worked |= self._prefill_step()
+            live = self._decode_live()
+            if live:
+                self._decode(live)
+                worked = True
+        return worked
+
+    def _decode(self, live: list[int]):
+        """One decode step over the live slots: make each slot's next
+        position writable, run the step, read the tokens back, retire the
+        finished."""
+        with TraceAnnotation("serve.decode.prepare"):
             for i in live:
                 self._ensure_writable(i)
             B = self.scfg.max_batch
@@ -464,23 +512,24 @@ class PagedServingEngine:
             for i in live:
                 tok[i, 0] = self.slots[i].out[-1]
                 lv[i] = True
+        with TraceAnnotation("serve.decode.dispatch", rows=len(live)):
             logits, self.pool = self._step(
                 self.params, jnp.asarray(tok), self.pool,
                 jnp.asarray(self.tables), jnp.asarray(self.slot_pos),
                 jnp.asarray(lv))
             self.decode_steps += 1
+        with TraceAnnotation("serve.decode.readback"):
             nxt = np.asarray(jnp.argmax(logits[:, 0], axis=-1))
-            for i in live:
-                req = self.slots[i]
-                t = int(nxt[i])
-                req.out.append(t)
-                self.slot_pos[i] += 1
-                if t == self.scfg.eos_id or \
-                        len(req.out) >= req.max_new_tokens or \
-                        self.slot_pos[i] >= self.scfg.max_seq - 1:
+        for i in live:
+            req = self.slots[i]
+            t = int(nxt[i])
+            req.out.append(t)
+            self.slot_pos[i] += 1
+            if t == self.scfg.eos_id or \
+                    len(req.out) >= req.max_new_tokens or \
+                    self.slot_pos[i] >= self.scfg.max_seq - 1:
+                with TraceAnnotation("serve.retire"):
                     self._retire(i)
-            worked = True
-        return worked
 
     def run(self, max_steps: int = 10_000):
         for _ in range(max_steps):

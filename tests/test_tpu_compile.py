@@ -11,6 +11,7 @@ is off around these compiles — an entry written for a described chip
 cannot be read back without one.
 """
 import dataclasses
+import re
 
 import jax
 import jax.numpy as jnp
@@ -27,6 +28,9 @@ PHI3 = get_config("phi3-mini-3.8b")
 D, F = PHI3.d_model, PHI3.d_ff
 PROJ = {"qkvo": (D, D), "mlp_in": (D, F), "mlp_out": (F, D)}
 ROWS = {"decode": 4, "chunk": 256, "prefill": 2048}
+#: the sublayer scopes of models/lm.py and models/layers.py
+SCOPES = {"embed", "attn.qkv", "attn.kv_write", "attn.core", "attn.out",
+          "mlp", "head"}
 
 
 @pytest.fixture(scope="module")
@@ -71,6 +75,12 @@ def _tree_sds(defs, sharding):
 
 def _kernels(compiled) -> int:
     return compiled.as_text().count("tpu_custom_call")
+
+
+def _scopes(compiled) -> set[str]:
+    """The sublayer scopes named in the compiled program's op metadata."""
+    names = re.findall(r'op_name="([^"]*)"', compiled.as_text())
+    return {part for n in names for part in n.split("/")} & SCOPES
 
 
 @pytest.mark.parametrize("rows", ROWS.values(), ids=ROWS.keys())
@@ -149,6 +159,28 @@ def test_one_layer_prefill_chunk_compiles_for_v5e(one_chip, on_tpu):
         _sds((S // bt,), jnp.int32, one_chip),
         _sds((), jnp.int32, one_chip), _sds((), jnp.int32, one_chip)).compile()
     assert _kernels(compiled) >= 9
+    assert _scopes(compiled) == SCOPES
+
+
+def test_one_layer_paged_decode_step_compiles_for_v5e(one_chip, on_tpu):
+    """The paged engine's decode step at full width (8 slots, max_seq
+    1024, blocks of 16): 3 norms and 7 projections are Pallas kernels,
+    and every sublayer scope reaches the compiled program's op
+    metadata."""
+    cfg = dataclasses.replace(PHI3, n_layers=1)
+    rules = default_rules(None)
+    B, S, bt = 8, 1024, 16
+    step = jax.jit(lambda p, t, pool, tab, pos, lv: lm.decode_step_paged(
+        p, t, pool, tab, pos, lv, cfg, rules))
+    compiled = step.lower(
+        _tree_sds(lm.model_defs(cfg), one_chip),
+        _sds((B, 1), jnp.int32, one_chip),
+        _tree_sds(lm.pool_defs(cfg, 6 * S // bt + 1, bt), one_chip),
+        _sds((B, S // bt), jnp.int32, one_chip),
+        _sds((B,), jnp.int32, one_chip),
+        _sds((B,), jnp.bool_, one_chip)).compile()
+    assert _kernels(compiled) >= 10
+    assert _scopes(compiled) == SCOPES
 
 
 def test_one_layer_mesh_decode_compiles_for_v5e_2x2(topo, on_tpu):
