@@ -1,5 +1,7 @@
 """Jit'd public wrappers: pick the Pallas kernel on TPU, the jnp reference
 elsewhere (the CPU dry-run lowers the jnp path; interpret=True is for tests).
+The models reach Pallas through ``rmsnorm`` alone; their projections go
+through ``dense``, which is XLA's dot everywhere.
 A call traced for a multi-device mesh also takes the reference: XLA cannot
 partition a Mosaic kernel across devices, and GSPMD partitions the jnp
 expression natively.
@@ -214,17 +216,13 @@ def rmsnorm(x, gamma, *, eps=1e-6, use_pallas=None, bm=None):
     return out.reshape(shape)
 
 
-def dense(x, w, *, use_pallas=None):
-    """The models' projection seam: ``x @ w`` contracting the last dim.
-
-    Ref mode is *literally* ``x @ w`` (bit-identical to the historical
-    inline call sites); Pallas mode flattens the leading dims and runs the
-    tuned-block matmul."""
-    if _mode(use_pallas, x, w) == "ref":
-        return x @ w
-    lead = x.shape[:-1]
-    out = matmul(x.reshape(-1, x.shape[-1]), w, use_pallas=use_pallas)
-    return out.reshape(*lead, w.shape[-1])
+def dense(x, w):
+    """The models' projection seam: ``x @ w`` contracting the last dim, on
+    every backend.  On one TPU XLA's dot beats the Pallas ``matmul`` at
+    every projection shape the served models run, and inside the layer
+    scan it reads the stacked weight in place, where a custom call takes a
+    copy of each layer's slice (PERF.md §6)."""
+    return x @ w
 
 
 def paged_attention(q, kpool, vpool, tables, lens, *, use_pallas=None):

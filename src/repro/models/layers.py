@@ -79,6 +79,11 @@ def _qkv(p, x, cfg: ModelConfig, rules, positions, rotate: bool):
     qf = constraint(kops.dense(xn, p["wq"]), rules, "batch", None, "model")
     kf = constraint(kops.dense(xn, p["wk"]), rules, "batch", None, "model")
     vf = constraint(kops.dense(xn, p["wv"]), rules, "batch", None, "model")
+    # On TPU the split into heads is no bitcast of the tiled (rows, N)
+    # output, and XLA would fold it into the dots by copying each layer's
+    # weight transposed; the barrier keeps the dots plain and moves the
+    # activations instead (PERF.md §6).
+    qf, kf, vf = jax.lax.optimization_barrier((qf, kf, vf))
     q = qf.reshape(B, S, cfg.n_heads, hd)
     k = kf.reshape(B, S, cfg.n_kv_heads, hd)
     v = vf.reshape(B, S, cfg.n_kv_heads, hd)

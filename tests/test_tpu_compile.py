@@ -28,6 +28,8 @@ PHI3 = get_config("phi3-mini-3.8b")
 D, F = PHI3.d_model, PHI3.d_ff
 PROJ = {"qkvo": (D, D), "mlp_in": (D, F), "mlp_out": (F, D)}
 ROWS = {"decode": 4, "chunk": 256, "prefill": 2048}
+#: Pallas kernels in a one-layer step: its two norms and the final one
+NORMS = 3
 #: the sublayer scopes of models/lm.py and models/layers.py
 SCOPES = {"embed", "attn.qkv", "attn.kv_write", "attn.core", "attn.out",
           "mlp", "head"}
@@ -75,6 +77,41 @@ def _tree_sds(defs, sharding):
 
 def _kernels(compiled) -> int:
     return compiled.as_text().count("tpu_custom_call")
+
+
+def _weights(cfg) -> set[tuple[int, int]]:
+    """(K, N) of one layer's projections: q, k, v, o, gate/up, down."""
+    q, kv = cfg.n_heads * cfg.head_dim, cfg.n_kv_heads * cfg.head_dim
+    return {(cfg.d_model, q), (cfg.d_model, kv), (q, cfg.d_model),
+            (cfg.d_model, cfg.d_ff), (cfg.d_ff, cfg.d_model)}
+
+
+def _no_projection_kernel(compiled, cfg) -> bool:
+    """No Pallas call takes a projection's weight: the projections are
+    XLA's own dots and the kernels that remain are the norms."""
+    calls = [ln for ln in compiled.as_text().splitlines()
+             if "tpu_custom_call" in ln]
+    return not any(f"{k},{n}]" in ln for ln in calls
+                   for k, n in _weights(cfg))
+
+
+def _top_level_outputs(compiled) -> list[tuple[int, ...]]:
+    """Output dims of every instruction that writes a buffer: those of
+    every computation but the bodies of fusions, whose instructions live
+    in registers and on-chip memory inside the fused kernel."""
+    text = compiled.as_text()
+    fused = set(re.findall(r"\bfusion\(.*\bcalls=%?([\w.\-]+)", text))
+    out, keep = [], True
+    for ln in text.splitlines():
+        head = re.match(r"(?:ENTRY\s+)?%?([\w.\-]+) .*\{$", ln.rstrip())
+        if head:                                # a computation's header
+            keep = head.group(1) not in fused
+            continue
+        m = re.match(r"\s*(?:ROOT\s+)?%?[\w.\-]+\s*=\s*\w+\[([\d,]*)\]",
+                     ln)
+        if keep and m:
+            out.append(tuple(int(d) for d in m.group(1).split(",") if d))
+    return out
 
 
 def _scopes(compiled) -> set[str]:
@@ -127,8 +164,9 @@ def test_paged_attention_compiles_for_v5e(one_chip, on_tpu):
 
 
 def test_one_layer_decode_step_compiles_for_v5e(one_chip, on_tpu):
-    """A full-width decode step of a one-layer cut: 2 norms and 7
-    projections are Pallas kernels, and the step fits the chip."""
+    """A full-width decode step of a one-layer cut: the 3 norms (the
+    layer's two and the final one) are Pallas kernels, the 7 projections
+    XLA's dots, and the step fits the chip."""
     cfg = dataclasses.replace(PHI3, n_layers=1)
     rules = default_rules(None)
     B, S = 4, 1024
@@ -139,14 +177,16 @@ def test_one_layer_decode_step_compiles_for_v5e(one_chip, on_tpu):
         _sds((B, 1), jnp.int32, one_chip),
         _tree_sds(lm.cache_defs(cfg, B, S), one_chip),
         _sds((B,), jnp.int32, one_chip)).compile()
-    assert _kernels(compiled) >= 9
+    assert _kernels(compiled) == NORMS
+    assert _no_projection_kernel(compiled, cfg)
     mem = compiled.memory_analysis()
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 16e9
 
 
 def test_one_layer_prefill_chunk_compiles_for_v5e(one_chip, on_tpu):
     """The paged engine's chunked prefill at full width: a 256-token chunk
-    scattered into a pool of 16-token blocks."""
+    scattered into a pool of 16-token blocks; the norms are the only
+    Pallas kernels."""
     cfg = dataclasses.replace(PHI3, n_layers=1)
     rules = default_rules(None)
     S, bt, c = 1024, 16, 256
@@ -158,15 +198,15 @@ def test_one_layer_prefill_chunk_compiles_for_v5e(one_chip, on_tpu):
         _tree_sds(lm.pool_defs(cfg, 4 * S // bt + 1, bt), one_chip),
         _sds((S // bt,), jnp.int32, one_chip),
         _sds((), jnp.int32, one_chip), _sds((), jnp.int32, one_chip)).compile()
-    assert _kernels(compiled) >= 9
+    assert _kernels(compiled) == NORMS
+    assert _no_projection_kernel(compiled, cfg)
     assert _scopes(compiled) == SCOPES
 
 
 def test_one_layer_paged_decode_step_compiles_for_v5e(one_chip, on_tpu):
     """The paged engine's decode step at full width (8 slots, max_seq
-    1024, blocks of 16): 3 norms and 7 projections are Pallas kernels,
-    and every sublayer scope reaches the compiled program's op
-    metadata."""
+    1024, blocks of 16): the 3 norms are the only Pallas kernels, and
+    every sublayer scope reaches the compiled program's op metadata."""
     cfg = dataclasses.replace(PHI3, n_layers=1)
     rules = default_rules(None)
     B, S, bt = 8, 1024, 16
@@ -179,8 +219,36 @@ def test_one_layer_paged_decode_step_compiles_for_v5e(one_chip, on_tpu):
         _sds((B, S // bt), jnp.int32, one_chip),
         _sds((B,), jnp.int32, one_chip),
         _sds((B,), jnp.bool_, one_chip)).compile()
-    assert _kernels(compiled) >= 10
+    assert _kernels(compiled) == NORMS
+    assert _no_projection_kernel(compiled, cfg)
     assert _scopes(compiled) == SCOPES
+
+
+@pytest.mark.parametrize("arch,B,S", [("phi3-mini-3.8b", 8, 1024),
+                                      ("deepseek-7b", 8, 2048)],
+                         ids=["phi3", "deepseek"])
+def test_two_layer_paged_decode_reads_weights_in_place(one_chip, on_tpu,
+                                                       arch, B, S):
+    """The benchmark's decode step over a real layer scan (two layers):
+    each projection's dot reads its layer's slice of the stacked weight
+    in place, so no instruction writes one layer's weight out first."""
+    cfg = dataclasses.replace(get_config(arch), n_layers=2)
+    rules = default_rules(None)
+    bt = 16
+    step = jax.jit(lambda p, t, pool, tab, pos, lv: lm.decode_step_paged(
+        p, t, pool, tab, pos, lv, cfg, rules))
+    compiled = step.lower(
+        _tree_sds(lm.model_defs(cfg), one_chip),
+        _sds((B, 1), jnp.int32, one_chip),
+        _tree_sds(lm.pool_defs(cfg, 4 * S // bt + 1, bt), one_chip),
+        _sds((B, S // bt), jnp.int32, one_chip),
+        _sds((B,), jnp.int32, one_chip),
+        _sds((B,), jnp.bool_, one_chip)).compile()
+    outs = {tuple(d for d in dims if d != 1)
+            for dims in _top_level_outputs(compiled)}
+    assert outs, "no instruction found in the compiled program"
+    assert not outs & _weights(cfg)
+    assert _no_projection_kernel(compiled, cfg)
 
 
 def test_one_layer_mesh_decode_compiles_for_v5e_2x2(topo, on_tpu):
