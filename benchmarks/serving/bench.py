@@ -87,8 +87,7 @@ def run_cell(name: str, seed: int, seconds: float, traced: bool,
     compiles = harness.CompileClock()
     planned = workload.plan(cell, c, seed, gen)
     w = weights.init(c, seed)
-    engine = harness.make_engine(c, weights.to_program(w, harness
-                                                       .program_config(c)))
+    engine = harness.make_engine(c, w)
     harness.warm_up(engine, c)
     jax.block_until_ready(engine.pool)
     n_compiled, s_compiled = compiles.n, compiles.secs

@@ -13,6 +13,7 @@ TINY_CONFIG = {
     "name": "tiny_dense",
     "source": "test configuration: phi3_mini's layout at widths a CPU "
               "test can run",
+    "family": "dense",
     "arch": "phi3-mini-3.8b",
     "overrides": {"n_layers": 2, "d_model": 128, "n_heads": 4,
                   "n_kv_heads": 4, "d_ff": 256, "vocab_size": 512},
@@ -47,7 +48,7 @@ def tiny_bench(tmp: pathlib.Path, cell: dict | None = None) -> pathlib.Path:
     tiny configuration and cell added; returns the copy's directory."""
     base = tmp / "serving"
     shutil.copytree(HERE, base, ignore=shutil.ignore_patterns(
-        "__pycache__", "testdata"))
+        "__pycache__", "testdata*"))
     (base / "configs" / "tiny_dense.json").write_text(json.dumps(TINY_CONFIG))
     cell = cell or TINY_CELL
     (base / "traffic" / f"{cell['name']}.json").write_text(json.dumps(cell))

@@ -81,35 +81,21 @@ def enable_compile_cache() -> str:
     return path
 
 
-def program_config(c: dict):
-    """The repo's model configuration for ``c``, checked against the
-    published sizes the file states."""
-    from repro.configs import get_config
-    cfg = dataclasses.replace(get_config(c["arch"]), **c["overrides"])
-    want = {"d_model": c["hidden_size"], "d_ff": c["intermediate_size"],
-            "n_heads": c["num_attention_heads"],
-            "n_kv_heads": c["num_key_value_heads"],
-            "n_layers": c["num_hidden_layers"],
-            "vocab_size": c["vocab_size"], "norm_eps": c["rms_norm_eps"],
-            "rope_theta": c["rope_theta"], "window": None,
-            "tie_embeddings": c.get("tie_word_embeddings", False)}
-    have = {k: getattr(cfg, k) for k in want}
-    if have != want or cfg.n_experts or cfg.family != "dense":
-        raise spec.SpecError(f"repo arch {c['arch']} with {c['overrides']} "
-                             f"is {have}, the file states {want}")
-    return cfg
-
-
-def make_engine(c: dict, params):
+def make_engine(c: dict, w: dict):
+    """The paged engine over the weights ``w`` (``weights.init``), in the
+    program's configuration and parameter tree that the family of ``c``
+    gives."""
     from repro.parallel.sharding import default_rules
     from repro.serve import PagedServeConfig, PagedServingEngine
+    fam = spec.family(c)
+    cfg = fam.program_config(c)
     e = c["engine"]
     scfg = PagedServeConfig(max_batch=e["max_batch"], max_seq=e["max_seq"],
                             eos_id=-1, block_tokens=e["block_tokens"],
                             n_blocks=e["pool_tokens"] // e["block_tokens"],
                             chunk=e["chunk"])
-    return PagedServingEngine(program_config(c), params, default_rules(None),
-                              scfg)
+    return PagedServingEngine(cfg, fam.to_program(w, cfg),
+                              default_rules(None), scfg)
 
 
 @dataclasses.dataclass

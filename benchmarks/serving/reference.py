@@ -1,12 +1,14 @@
-"""The plain reference: a float32 ``jax.numpy`` forward of a dense
-decoder (pre-norm RMSNorm, rotary multi-head attention, SwiGLU MLP),
+"""The plain reference: a float32 ``jax.numpy`` forward of the model,
 written from the published architecture and nothing of the program.
 
-It reads the weights in ``weights.py``'s layout and runs one layer at a
-time, so that it fits on the chip beside them, with every matrix product
-at ``highest`` precision (a TPU otherwise multiplies float32 in bfloat16).
-Rotary embedding is the half-split form of the published code
-(``rotate_half``, inverse frequencies ``theta ** (-2i / head_dim)``).
+Each family (``families/<family>.py``) writes its own forward,
+``forward_rows``, over its own weights layout, from the pieces here:
+every matrix product at ``highest`` precision (a TPU otherwise
+multiplies float32 in bfloat16), RMSNorm, and rotary embedding in the
+half-split form of the published code (``rotate_half``, inverse
+frequencies ``theta ** (-2i / head_dim)``).  It runs one layer at a
+time, so that it fits on the chip beside the weights.  What is shared
+here batches the sampled requests and scores the served tokens.
 
 ``fp8=True`` is the control: every projection and the head multiply
 float8 (e4m3, one scale per tensor) operands, the precision below the
@@ -14,14 +16,12 @@ configuration's bfloat16.  A comparison that passes it is too loose.
 """
 from __future__ import annotations
 
-import functools
-
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-LAYER_KEYS = ("attn_norm", "wq", "wk", "wv", "wo", "mlp_norm", "w_gate",
-              "w_up", "w_down")
+import spec
+
 #: sequences in one forward: the (batch, heads, L, L) float32 scores of
 #: one layer stay near 1 GB at any length
 BATCH_TOKENS = 4096
@@ -55,79 +55,13 @@ def _rope(x, theta):
     return x * cos + jnp.concatenate([-x2, x1], -1) * sin
 
 
-@functools.partial(jax.jit, static_argnames=("c", "fp8"))
-def _layer(x, w, i, c, fp8):
-    """One decoder layer on x (B, L, d) float32."""
-    B, L, d = x.shape
-    H, Hkv = c.heads, c.kv_heads
-    hd = d // H
-    lw = {k: jax.lax.dynamic_index_in_dim(w[k], i, keepdims=False)
-          for k in LAYER_KEYS}
-    h = _rms(x, lw["attn_norm"], c.eps)
-    q = _rope(_mm(h, lw["wq"], fp8).reshape(B, L, H, hd), c.theta)
-    k = _rope(_mm(h, lw["wk"], fp8).reshape(B, L, Hkv, hd), c.theta)
-    v = _mm(h, lw["wv"], fp8).reshape(B, L, Hkv, hd)
-    k = jnp.repeat(k, H // Hkv, axis=2)
-    v = jnp.repeat(v, H // Hkv, axis=2)
-    s = jnp.einsum("bqhd,bkhd->bhqk", q, k,
-                   precision=jax.lax.Precision.HIGHEST) / np.sqrt(hd)
-    causal = jnp.tril(jnp.ones((L, L), bool))
-    p = jax.nn.softmax(jnp.where(causal, s, -jnp.inf), axis=-1)
-    o = jnp.einsum("bhqk,bkhd->bqhd", p, v,
-                   precision=jax.lax.Precision.HIGHEST).reshape(B, L, H * hd)
-    x = x + _mm(o, lw["wo"], fp8)
-    h = _rms(x, lw["mlp_norm"], c.eps)
-    g = _mm(h, lw["w_gate"], fp8)
-    u = _mm(h, lw["w_up"], fp8)
-    return x + _mm(g * jax.nn.sigmoid(g) * u, lw["w_down"], fp8)
-
-
-@functools.partial(jax.jit, static_argnames=("c", "fp8"))
-def _logits(x, rows, w, c, fp8):
-    """Logits (B, R, vocab) float32 at positions ``rows`` (B, R)."""
-    x = _rms(x, w["final_norm"], c.eps)
-    x = jnp.take_along_axis(x, rows[..., None], axis=1)
-    return _mm(x, w["head"][:, :c.vocab], fp8)
-
-
-@functools.partial(jax.jit, static_argnames=("c",))
-def _embed(tokens, w, c):
-    return w["embed"][tokens].astype(jnp.float32)
-
-
-class Dims:
-    """The hashable sizes the jitted pieces specialise on."""
-
-    def __init__(self, c: dict):
-        self.heads = c["num_attention_heads"]
-        self.kv_heads = c["num_key_value_heads"]
-        self.layers = c["num_hidden_layers"]
-        self.vocab = c["vocab_size"]
-        self.eps = float(c["rms_norm_eps"])
-        self.theta = float(c["rope_theta"])
-
-    def _key(self):
-        return (self.heads, self.kv_heads, self.layers, self.vocab,
-                self.eps, self.theta)
-
-    def __hash__(self):
-        return hash(self._key())
-
-    def __eq__(self, other):
-        return isinstance(other, Dims) and self._key() == other._key()
-
-
 def forward_rows(w: dict, c: dict, tokens: np.ndarray, rows: np.ndarray,
                  *, fp8: bool = False) -> jax.Array:
-    """Teacher-forced logits: ``tokens`` (B, L) int32 (padding after each
-    sequence is harmless under the causal mask), ``rows`` (B, R) the
-    positions whose next-token logits are wanted -> (B, R, vocab)."""
-    dims = Dims(c)
-    with jax.default_matmul_precision("highest"):
-        x = _embed(jnp.asarray(tokens), w, dims)
-        for i in range(dims.layers):
-            x = _layer(x, w, jnp.int32(i), dims, fp8)
-        return _logits(x, jnp.asarray(rows), w, dims, fp8)
+    """Teacher-forced logits by the family of ``c``: ``tokens`` (B, L)
+    int32 (padding after each sequence is harmless under the causal
+    mask), ``rows`` (B, R) the positions whose next-token logits are
+    wanted -> (B, R, vocab) float32."""
+    return spec.family(c).forward_rows(w, c, tokens, rows, fp8=fp8)
 
 
 def gaps(w: dict, c: dict, prompts: list, served: list, *, length: int,

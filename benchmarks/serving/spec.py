@@ -1,17 +1,22 @@
 """Finds each piece of the benchmark by its name, and checks it.
 
     configs/<config>.json      one model configuration (sizes, engine)
+    families/<family>.py       one architecture family: the weights'
+                               layout, the plain reference's forward and
+                               the work counts of the configurations
+                               that name it (``FAMILY_ATTRS``)
     traffic/<cell>.json        one cell: its configuration and traffic mix
     generators/<kind>.py       one arrival law: ``CLOSED``, ``gaps(spec, u)``
     metrics/<metric>.py        one metric, ``compute(record)``
     peaks.json                 chip peaks keyed by ``device_kind``
 
-A later cell, configuration, arrival law or metric is a new file; nothing
-here names one.  Every loader takes the directory it reads from, so a
-test can point it at a copy.
+A later cell, configuration, family, arrival law or metric is a new file;
+nothing here names one.  Every loader takes the directory it reads from,
+so a test can point it at a copy.
 """
 from __future__ import annotations
 
+import functools
 import importlib.util
 import json
 import pathlib
@@ -21,8 +26,8 @@ HERE = pathlib.Path(__file__).resolve().parent
 ROOT = HERE.parents[1]                       # the checkout
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.-]{0,63}$")
 
-CONFIG_KEYS = ("name", "source", "arch", "overrides", "hidden_size",
-               "intermediate_size", "num_attention_heads",
+CONFIG_KEYS = ("name", "source", "family", "arch", "overrides",
+               "hidden_size", "intermediate_size", "num_attention_heads",
                "num_key_value_heads", "num_hidden_layers", "vocab_size",
                "rms_norm_eps", "rope_theta", "reduced", "assumed",
                "deployment", "engine")
@@ -32,6 +37,12 @@ CELL_KEYS = ("name", "config", "arrivals", "prompt_len", "output_len",
              "requests", "strata", "check", "trace", "why", "who")
 LENGTH_KEYS = ("dist", "median", "sigma", "min", "max")
 METRIC_ATTRS = ("NAME", "UNIT", "LAYER", "MOVES", "SOURCE", "compute")
+FAMILY_ATTRS = ("KEYS", "check", "program_config", "shapes", "to_program",
+                "forward_rows", "matmuls", "weight_map", "head_shape",
+                "decode_step", "prefill_chunk")
+#: the key under which a loaded configuration keeps the directory it was
+#: read from, so that its family is read from the same directory
+BASE_KEY = "_base"
 
 
 class SpecError(ValueError):
@@ -83,11 +94,31 @@ def load_config(name: str, base: pathlib.Path = HERE) -> dict:
             or e["pool_tokens"] % bt:
         raise SpecError(f"config {name}: block_tokens must tile max_seq, "
                         f"chunk and pool_tokens, and chunk divide max_seq")
-    if c["hidden_size"] % c["num_attention_heads"]:
-        raise SpecError(f"config {name}: heads do not divide hidden_size")
     for k in c["reduced"]:
         _name("reduced key", k)
+    fam = load_family(c["family"], base)
+    _need(c, fam.KEYS, f"config {name} (family {c['family']})")
+    fam.check(c)
+    c[BASE_KEY] = str(base)
     return c
+
+
+@functools.cache
+def _family(path: pathlib.Path):
+    return _module(path, FAMILY_ATTRS, f"family {path.stem}")
+
+
+def load_family(name: str, base: pathlib.Path = HERE):
+    """The module of architecture family ``name`` in ``base``; loaded once
+    per file, so that every caller shares its compiled programs."""
+    return _family(base.resolve() / "families"
+                   / f"{_name('family', name)}.py")
+
+
+def family(c: dict):
+    """The family module of configuration ``c``, from the directory it
+    was loaded from (a configuration written in code: this one)."""
+    return load_family(c["family"], pathlib.Path(c.get(BASE_KEY, HERE)))
 
 
 def load_cell(name: str, base: pathlib.Path = HERE) -> dict:

@@ -41,8 +41,7 @@ def main(argv=None) -> int:
     c = spec.load_config(cell["config"])
     gen = spec.load_generator(cell["arrivals"]["kind"])
     w = weights.init(c, args.seed)
-    engine = harness.make_engine(c, weights.to_program(
-        w, harness.program_config(c)))
+    engine = harness.make_engine(c, w)
     harness.warm_up(engine, c)
     for rate in (float(r) for r in args.rates.split(",")):
         cell_r = dict(cell, arrivals=dict(cell["arrivals"], rate_rps=rate))

@@ -32,15 +32,19 @@ def state_unchanged(step):
 
 
 def half_batch_left_out(step):
-    """The upper half of the slots is not computed; its rows take the
-    mean of the rows that were."""
+    """The rows at odd positions, half of every request's decode steps,
+    are not computed; they take the mean of the rows that were.  Chosen
+    by position and not by slot, so that every request the check samples
+    holds broken tokens."""
     def broken(params, token, pool, tables, pos, live, *a, **k):
-        half = live.shape[0] // 2
-        kept = live.at[half:].set(False)
+        odd = pos % 2 == 1
+        kept = live & ~odd
         logits, new_pool = step(params, token, pool, tables, pos, kept,
                                 *a, **k)
-        rest = logits[:half].mean(axis=0, keepdims=True)
-        return logits.at[half:].set(rest), new_pool
+        n = jnp.maximum(kept.sum(), 1).astype(logits.dtype)
+        rest = jnp.sum(jnp.where(kept[:, None, None], logits, 0), axis=0,
+                       keepdims=True) / n
+        return jnp.where(odd[:, None, None], rest, logits), new_pool
     return broken
 
 

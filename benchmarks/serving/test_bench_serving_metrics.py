@@ -8,6 +8,7 @@ import trace_reduce
 import work
 
 PHI3 = spec.load_config("phi3_mini")
+DENSE = spec.family(PHI3)
 PEAKS = spec.load_peaks("TPU v5 lite")
 
 
@@ -94,7 +95,7 @@ def _xla(K, N, rows=8):
 def _decode_trace(text_of, program="jit__lambda(1)"):
     """Two decode steps, each with every projection of phi3 once, 1 ms
     each, and a weight-slice copy of 1 ms that is no projection."""
-    mats = [(K, N) for _, K, N in work.layer_mats(PHI3)] + [(3072, 32256)]
+    mats = [(K, N) for _, K, N in DENSE.layer_mats(PHI3)] + [(3072, 32256)]
     ops, kinds = {}, {}
     for k in range(2):
         t = 100.0 * k
@@ -113,7 +114,7 @@ def test_matmul_roofline_reads_the_same_for_pallas_and_xla():
     reader = spec.load_metric("matmul_roofline.decode")
     pallas = reader.compute(_record(steps, _decode_trace(_pallas)))
     xla = reader.compute(_record(steps, _decode_trace(_xla)))
-    mats = [(K, N) for _, K, N in work.layer_mats(PHI3)] + [(3072, 32064)]
+    mats = [(K, N) for _, K, N in DENSE.layer_mats(PHI3)] + [(3072, 32064)]
     need = work.roofline_s([work.matmul(5, K, N) for K, N in mats], PEAKS)
     assert pallas == pytest.approx(xla)
     assert pallas == pytest.approx(100 * need / (len(mats) * 1e-3))
@@ -131,7 +132,7 @@ def test_prefill_roofline_leaves_the_decode_program_out():
                                      spans=[(0, 200, False)])]
     got = spec.load_metric("matmul_roofline.prefill").compute(
         _record(steps, tr))
-    layer = [(K, N) for _, K, N in work.layer_mats(PHI3)]
+    layer = [(K, N) for _, K, N in DENSE.layer_mats(PHI3)]
     need = work.roofline_s([work.matmul(200, K, N) for K, N in layer],
                            PEAKS)
     # the head of a chunk that does not end its prompt is needed by no one

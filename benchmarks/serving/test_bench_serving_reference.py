@@ -10,8 +10,8 @@ import jax.numpy as jnp
 import numpy as np
 
 import bench_serving_testkit as kit
-import harness
 import reference
+import spec
 import weights
 
 #: float32 rounding over two layers of width 128 and a 512-row head
@@ -23,9 +23,10 @@ def test_reference_matches_paged_prefill_and_decode():
     from repro.parallel.sharding import default_rules
 
     c = kit.TINY_CONFIG
-    cfg = dataclasses.replace(harness.program_config(c), dtype=jnp.float32)
+    dense = spec.family(c)
+    cfg = dataclasses.replace(dense.program_config(c), dtype=jnp.float32)
     w = {k: v.astype(jnp.float32) for k, v in weights.init(c, 11).items()}
-    params = weights.to_program(w, cfg)
+    params = dense.to_program(w, cfg)
     rules = default_rules(None)
     bt, chunk, max_seq = 8, 32, 128
     nblk = max_seq // bt
