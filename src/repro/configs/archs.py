@@ -44,6 +44,24 @@ _reg(ModelConfig(
     n_experts=8, experts_per_token=2, moe_tp=True,
     window=4096, rope_theta=1e6, norm_eps=1e-5))
 
+# [hf:deepseek-ai/DeepSeek-V2-Lite; arXiv:2405.04434] 27L d2048 16H,
+# MLA (no q LoRA, kv_lora_rank 512, nope 128 + rope 64, v 128), YaRN x40
+# from 4096; layer 0 dense ff10944, layers 1-26 MoE: 64 experts of 1408,
+# top-6 of a softmax over all, not renormalised, + 2 shared experts;
+# dropless, as the published model routes every token at inference
+_reg(ModelConfig(
+    name="deepseek-v2-lite", family="moe",
+    n_layers=27, d_model=2048, n_heads=16, n_kv_heads=16, d_ff=10944,
+    d_ff_expert=1408, vocab_size=102400,
+    n_experts=64, experts_per_token=6, n_shared_experts=2,
+    capacity_factor=None, norm_topk_prob=False, first_dense=1,
+    kv_lora_rank=512, qk_nope_head_dim=128, qk_rope_head_dim=64,
+    v_head_dim=128,
+    rope_theta=1e4, yarn_factor=40.0, yarn_original_max=4096,
+    yarn_beta_fast=32.0, yarn_beta_slow=1.0, yarn_mscale=0.707,
+    yarn_mscale_all_dim=0.707, norm_eps=1e-6,
+    skip_shapes=dict(_FULL_ATTN_SKIP)))
+
 # --- enc-dec audio ----------------------------------------------------------
 
 # [arXiv:2308.11596; hf] SeamlessM4T-large-v2 text dec: 24L d1024 16H ff8192;
@@ -133,10 +151,15 @@ _reg(ModelConfig(
 def smoke_variant(cfg: ModelConfig) -> ModelConfig:
     """Same family/period structure, tiny dimensions, CPU-friendly."""
     np_ = len(cfg.layer_period)
+    mla = dict(kv_lora_rank=32, qk_nope_head_dim=16, qk_rope_head_dim=16,
+               v_head_dim=16) if cfg.kv_lora_rank else {}
     return dataclasses.replace(
         cfg,
         name=cfg.name + "-smoke",
-        n_layers=2 * np_,
+        n_layers=min(cfg.first_dense, 1) + 2 * np_,
+        first_dense=min(cfg.first_dense, 1),
+        yarn_original_max=min(cfg.yarn_original_max, 16),
+        **mla,
         d_model=64,
         n_heads=4, n_kv_heads=max(1, min(cfg.n_kv_heads, 2)), d_head=16,
         d_ff=128, d_ff_expert=128 if cfg.d_ff_expert else 0,
@@ -155,6 +178,6 @@ def smoke_variant(cfg: ModelConfig) -> ModelConfig:
         moe_tp=False,
         # capacity high enough that smoke-scale dispatch never drops —
         # batched-vs-sequential drop patterns would legitimately diverge
-        capacity_factor=8.0,
+        capacity_factor=None if cfg.capacity_factor is None else 8.0,
         remat=False,
     )

@@ -11,6 +11,7 @@ import jax.numpy as jnp
 
 # sublayer kinds; a layer is a tuple of sublayers, a period a tuple of layers
 ATTN, MAMBA, XATTN = "attn", "mamba", "xattn"
+MLA = "mla"                      # multi-head latent attention (DeepSeek-V2)
 MLP, MOE = "mlp", "moe"
 
 
@@ -55,14 +56,38 @@ class ModelConfig:
     n_experts: int = 0
     experts_per_token: int = 0
     d_ff_expert: int = 0
-    capacity_factor: float = 1.25
+    capacity_factor: float | None = 1.25  # an expert keeps ceil(N k / E x
+    #                              factor) of a call's N rows; None: dropless,
+    #                              every routed (row, expert) pair is computed
     moe_tp: bool = False         # experts < |model| axis: shard d_ff instead
     moe_impl: str = "psum"       # "psum" (tokens replicated over model) |
     #                              "a2a" (GLSU-style token all-to-all EP)
+    norm_topk_prob: bool = True  # False: gates are the softmax over all
+    #                              experts, read at the top k (DeepSeek-V2)
+    n_shared_experts: int = 0    # always-on experts, run as one SwiGLU of
+    #                              n_shared_experts * d_ff_expert
+    first_dense: int = 0         # first_k_dense_replace: leading layers with
+    #                              a dense MLP of d_ff, outside the period
+
+    # multi-head latent attention (kv_lora_rank > 0): keys and values come
+    # from a normalised latent of kv_lora_rank plus one roped key shared by
+    # every head, which is what the cache holds
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
 
     # attention
     rope_theta: float = 1e4
     window: int | None = None    # sliding-window attention
+    # YaRN context extension (rope_scaling type "yarn"); factor 1 is plain
+    # rotary embedding
+    yarn_factor: float = 1.0
+    yarn_original_max: int = 0
+    yarn_beta_fast: float = 32.0
+    yarn_beta_slow: float = 1.0
+    yarn_mscale: float = 1.0
+    yarn_mscale_all_dim: float = 0.0
 
     # SSM (mamba2 / jamba)
     ssm_state: int = 0
@@ -101,16 +126,37 @@ class ModelConfig:
         return ((self.vocab_size + 255) // 256) * 256
 
     @property
+    def attn_kind(self) -> str:
+        return MLA if self.kv_lora_rank else ATTN
+
+    @property
     def layer_period(self) -> tuple:
         if self.period:
             return self.period
-        return ((ATTN, MOE if self.n_experts else MLP),)
+        return ((self.attn_kind, MOE if self.n_experts else MLP),)
+
+    @property
+    def lead_period(self) -> tuple:
+        """The layer kinds of the ``first_dense`` leading layers."""
+        return ((self.attn_kind, MLP),)
 
     @property
     def n_periods(self) -> int:
         p = len(self.layer_period)
-        assert self.n_layers % p == 0, (self.name, self.n_layers, p)
-        return self.n_layers // p
+        n = self.n_layers - self.first_dense
+        assert n % p == 0, (self.name, n, p)
+        return n // p
+
+    @property
+    def qk_head_dim(self) -> int:
+        """Query/key width per head under latent attention."""
+        return self.qk_nope_head_dim + self.qk_rope_head_dim
+
+    @property
+    def latent_dim(self) -> int:
+        """Values cached per token and latent-attention layer: the
+        normalised latent and the roped shared key."""
+        return self.kv_lora_rank + self.qk_rope_head_dim
 
     @property
     def d_inner_ssm(self) -> int:
@@ -130,11 +176,17 @@ class ModelConfig:
             H, N = self.n_ssm_heads, self.ssm_state
             return (d * (2 * di + 2 * N + H) + self.ssm_conv * (di + 2 * N)
                     + 3 * H + di + di * d + d)
+        if kind == MLA:
+            H, r = self.n_heads, self.kv_lora_rank
+            return (d * H * self.qk_head_dim + d * self.latent_dim + r
+                    + r * H * (self.qk_nope_head_dim + self.v_head_dim)
+                    + H * self.v_head_dim * d + d)
         if kind == MLP:
             return 3 * d * self.d_ff + d
         if kind == MOE:
             ffe = self.d_ff_expert or self.d_ff
-            return (d * self.n_experts + self.n_experts * 3 * d * ffe + d)
+            return (d * self.n_experts + self.n_experts * 3 * d * ffe
+                    + 3 * d * self.n_shared_experts * ffe + d)
         raise ValueError(kind)
 
     def n_params(self) -> int:
@@ -146,6 +198,9 @@ class ModelConfig:
         for layer in self.layer_period:
             for kind in layer:
                 n += self.n_periods * self._sublayer_params(kind)
+        for layer in self.lead_period:
+            for kind in layer:
+                n += self.first_dense * self._sublayer_params(kind)
         n += d                                        # final norm
         if self.family == "encdec":
             n += self.n_enc_layers * (self._sublayer_params(ATTN)

@@ -17,10 +17,11 @@ from typing import Any, NamedTuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.sharding import PartitionSpec as P
 
 from repro import substrate
-from repro.configs.base import ATTN, MAMBA, MLP, MOE, XATTN, ModelConfig
+from repro.configs.base import ATTN, MAMBA, MLA, MLP, MOE, XATTN, ModelConfig
 from repro.kernels import ops as kops
 from repro.parallel.sharding import PV, ShardingRules, constraint
 
@@ -37,15 +38,72 @@ def rmsnorm(x: jax.Array, g: jax.Array, eps: float) -> jax.Array:
 
 def rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
     """x (..., S, H, Dh), positions (..., S) or (S,)."""
-    dh = x.shape[-1]
-    half = dh // 2
-    freqs = theta ** (-jnp.arange(0, half, dtype=jnp.float32) / half)
-    ang = positions[..., :, None].astype(jnp.float32) * freqs      # (..., S, half)
+    half = x.shape[-1] // 2
+    return rotate(x, positions,
+                  theta ** (-jnp.arange(0, half, dtype=jnp.float32) / half))
+
+
+def rotate(x: jax.Array, positions: jax.Array, inv_freq,
+           scale: float = 1.0) -> jax.Array:
+    """Rotary embedding in the half-split layout at inverse frequencies
+    ``inv_freq`` (Dh / 2,), cos and sin scaled by ``scale``."""
+    half = x.shape[-1] // 2
+    ang = positions[..., :, None].astype(jnp.float32) * inv_freq   # (..., S, half)
     ang = ang[..., :, None, :]                                     # broadcast heads
     cos, sin = jnp.cos(ang), jnp.sin(ang)
+    if scale != 1.0:
+        cos, sin = cos * scale, sin * scale
     x1, x2 = x[..., :half], x[..., half:]
     out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
     return out.astype(x.dtype)
+
+
+def yarn_mscale(factor: float, mscale: float) -> float:
+    """YaRN's attention temperature term (``yarn_get_mscale``)."""
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def yarn_inv_freq(cfg: ModelConfig, dim: int) -> np.ndarray:
+    """Inverse frequencies of a ``dim``-wide rotary part under YaRN
+    (DeepSeek-V2's ``DeepseekV2YarnRotaryEmbedding``): pairs that turn
+    more than ``yarn_beta_fast`` times over the original context keep
+    their frequency, those that turn fewer than ``yarn_beta_slow`` times
+    are interpolated by ``yarn_factor``, and a linear ramp blends the
+    pairs between.  Plain rotary frequencies where the factor is 1."""
+    theta = cfg.rope_theta
+    extra = 1.0 / theta ** (np.arange(0, dim, 2, dtype=np.float64) / dim)
+    if cfg.yarn_factor <= 1:
+        return extra.astype(np.float32)
+
+    def turns_dim(turns):
+        return dim * math.log(cfg.yarn_original_max / (turns * 2 * math.pi)) \
+            / (2 * math.log(theta))
+
+    low = max(math.floor(turns_dim(cfg.yarn_beta_fast)), 0)
+    high = min(math.ceil(turns_dim(cfg.yarn_beta_slow)), dim - 1)
+    if low == high:
+        high += 0.001
+    ramp = np.clip((np.arange(dim // 2, dtype=np.float64) - low)
+                   / (high - low), 0, 1)
+    keep = 1.0 - ramp
+    return (extra / cfg.yarn_factor * (1 - keep) + extra * keep
+            ).astype(np.float32)
+
+
+def yarn_cos_scale(cfg: ModelConfig) -> float:
+    """The factor on cos and sin under YaRN (1 where mscale and
+    mscale_all_dim agree, as in DeepSeek-V2)."""
+    return yarn_mscale(cfg.yarn_factor, cfg.yarn_mscale) \
+        / yarn_mscale(cfg.yarn_factor, cfg.yarn_mscale_all_dim)
+
+
+def mla_softmax_scale(cfg: ModelConfig) -> float:
+    """1/sqrt(q-k width), times YaRN's temperature squared where
+    ``yarn_mscale_all_dim`` is set."""
+    scale = cfg.qk_head_dim ** -0.5
+    if cfg.yarn_mscale_all_dim:
+        scale *= yarn_mscale(cfg.yarn_factor, cfg.yarn_mscale_all_dim) ** 2
+    return scale
 
 
 def silu(x):
@@ -415,6 +473,172 @@ def attn_layer_prefill_paged(p, x, pk, pv, table_row, start, valid,
         return x + o.astype(x.dtype), pk, pv
 
 
+# -- multi-head latent attention (DeepSeek-V2) -------------------------------
+#
+# Keys and values come from one normalised latent of kv_lora_rank per token
+# (kv_b expands it to every head's key and value) plus a roped key of
+# qk_rope_head_dim shared by every head.  The cache holds those 576 values
+# per token (kv_lora_rank 512 + 64), not the heads' keys and values.  The
+# program attends *absorbed*: kv_b's key half is folded into each query, so
+# scores are taken against the cached rows directly, and kv_b's value half
+# is applied once to the weighted sum of latents.  The plain reference
+# (benchmarks/serving/families/deepseek_v2.py) expands keys and values as
+# published; the two agree up to rounding.
+
+def mla_defs(cfg: ModelConfig) -> dict:
+    d, H, r, dt = cfg.d_model, cfg.n_heads, cfg.kv_lora_rank, cfg.dtype
+    return {
+        "norm": PV((d,), jnp.float32, ("",), "ones"),
+        "wq": PV((d, H * cfg.qk_head_dim), dt, ("fsdp", "model")),
+        "wkv_a": PV((d, cfg.latent_dim), dt, ("fsdp", "")),
+        "kv_norm": PV((r,), jnp.float32, ("",), "ones"),
+        "wkv_b": PV((r, H * (cfg.qk_nope_head_dim + cfg.v_head_dim)), dt,
+                    ("", "model")),
+        "wo": PV((H * cfg.v_head_dim, d), dt, ("model", "fsdp")),
+    }
+
+
+def _kv_b(p, cfg: ModelConfig):
+    """kv_b as (rank, heads, nope + v): its key half and value half."""
+    w = p["wkv_b"].reshape(cfg.kv_lora_rank, cfg.n_heads,
+                           cfg.qk_nope_head_dim + cfg.v_head_dim)
+    return w[..., :cfg.qk_nope_head_dim], w[..., cfg.qk_nope_head_dim:]
+
+
+@jax.named_scope("attn.qkv")
+def _mla_qkv(p, x, cfg: ModelConfig, positions):
+    """-> absorbed queries (B, S, H, latent_dim) float32 and the rows the
+    cache holds, (B, S, latent_dim) in cfg.dtype: the normalised latent
+    and the roped shared key."""
+    B, S, _ = x.shape
+    H, nope, r = cfg.n_heads, cfg.qk_nope_head_dim, cfg.kv_lora_rank
+    xn = rmsnorm(x, p["norm"], cfg.norm_eps)
+    q = kops.dense(xn, p["wq"])
+    kv = kops.dense(xn, p["wkv_a"])
+    q, kv = jax.lax.optimization_barrier((q, kv))
+    q = q.reshape(B, S, H, cfg.qk_head_dim)
+    inv = yarn_inv_freq(cfg, cfg.qk_rope_head_dim)
+    scale = yarn_cos_scale(cfg)
+    q_pe = rotate(q[..., nope:], positions, inv, scale)
+    k_pe = rotate(kv[..., None, r:], positions, inv, scale)[..., 0, :]
+    c = rmsnorm(kv[..., :r], p["kv_norm"], cfg.norm_eps)
+    w_k, _ = _kv_b(p, cfg)
+    q_lat = kops.einsum_f32("bshn,rhn->bshr", q[..., :nope], w_k)
+    qa = jnp.concatenate([q_lat, q_pe.astype(jnp.float32)], axis=-1)
+    rows = jnp.concatenate([c.astype(cfg.dtype), k_pe.astype(cfg.dtype)],
+                           axis=-1)
+    return qa, rows
+
+
+def _mla_attend(qa, ctx, mask, cfg: ModelConfig):
+    """Absorbed attention of queries qa (B, S, H, C) over cached rows ctx
+    (B, T, C) under mask (B, S, T) -> latents (B, S, H, rank) float32."""
+    s = kops.einsum_f32("bshc,btc->bhst", qa.astype(ctx.dtype), ctx) \
+        * mla_softmax_scale(cfg)
+    s = jnp.where(mask[:, None], s, -1e30)
+    pr = jax.nn.softmax(s, axis=-1)
+    return kops.einsum_f32("bhst,btr->bshr", pr.astype(ctx.dtype),
+                           ctx[..., :cfg.kv_lora_rank])
+
+
+@jax.named_scope("attn.out")
+def _mla_out(p, x, o_lat, cfg: ModelConfig):
+    """kv_b's value half, then o, then the residual."""
+    B, S = o_lat.shape[:2]
+    _, w_v = _kv_b(p, cfg)
+    o = kops.einsum_f32("bshr,rhv->bshv", o_lat.astype(w_v.dtype), w_v)
+    o = kops.dense(o.reshape(B, S, -1).astype(x.dtype), p["wo"])
+    return x + o.astype(x.dtype)
+
+
+def mla_layer(p, x, cfg: ModelConfig, rules: ShardingRules, positions):
+    """Training / whole-sequence latent attention (causal, residual
+    included)."""
+    qa, rows = _mla_qkv(p, x, cfg, positions)
+    with jax.named_scope("attn.core"):
+        S = x.shape[1]
+        mask = jnp.tril(jnp.ones((S, S), bool))[None]
+        o = _mla_attend(qa, rows, mask, cfg)
+    return _mla_out(p, x, o, cfg)
+
+
+def mla_cache_defs(cfg: ModelConfig, batch: int, seq_len: int) -> dict:
+    return {"c": PV((batch, seq_len, cfg.latent_dim), cfg.dtype,
+                    ("batch", "cache_seq", ""), "zeros")}
+
+
+def mla_layer_prefill(p, x, cfg: ModelConfig, rules, positions,
+                      cache_len: int):
+    """Prefill: latent attention and the cache (B, cache_len, C)."""
+    S = x.shape[1]
+    qa, rows = _mla_qkv(p, x, cfg, positions)
+    with jax.named_scope("attn.core"):
+        o = _mla_attend(qa, rows, jnp.tril(jnp.ones((S, S), bool))[None],
+                        cfg)
+    with jax.named_scope("attn.kv_write"):
+        c = jnp.pad(rows, [(0, 0), (0, cache_len - S), (0, 0)])
+    return _mla_out(p, x, o, cfg), {"c": c}
+
+
+def mla_layer_decode(p, x, cache: dict, pos, cfg: ModelConfig,
+                     rules: ShardingRules):
+    """One-token step over a dense latent cache; pos scalar or (B,)."""
+    B = x.shape[0]
+    W = cache["c"].shape[1]
+    pos = jnp.broadcast_to(jnp.asarray(pos, jnp.int32), (B,))
+    qa, rows = _mla_qkv(p, x, cfg, pos[:, None])
+    with jax.named_scope("attn.kv_write"):
+        c = cache["c"].at[jnp.arange(B), pos].set(rows[:, 0])
+    with jax.named_scope("attn.core"):
+        mask = (jnp.arange(W)[None, :] <= pos[:, None])[:, None, :]
+        o = _mla_attend(qa, c, mask, cfg)
+    return _mla_out(p, x, o, cfg), {"c": c}
+
+
+def mla_layer_decode_paged(p, x, pool, tables, pos, live, cfg: ModelConfig,
+                           rules: ShardingRules):
+    """One-token decode against a paged latent pool (NB, bt, C): the
+    counterpart of :func:`attn_layer_decode_paged` (block 0 the zero
+    block, dead slots rewrite what they read)."""
+    B = x.shape[0]
+    NB, bt, C = pool.shape
+    W = tables.shape[1] * bt
+    qa, rows = _mla_qkv(p, x, cfg, pos[:, None])
+    with jax.named_scope("attn.kv_write"):
+        blk = jnp.take_along_axis(tables, (pos // bt)[:, None], axis=1)[:, 0]
+        off = pos % bt
+        new = jnp.where(live[:, None], rows[:, 0].astype(pool.dtype),
+                        pool[blk, off])
+        pool = pool.at[blk, off].set(new)
+    with jax.named_scope("attn.core"):
+        ctx = pool[tables].reshape(B, W, C)
+        mask = (jnp.arange(W)[None, :] <= pos[:, None])[:, None, :]
+        o = _mla_attend(qa, ctx, mask, cfg)
+    return _mla_out(p, x, o, cfg), pool
+
+
+def mla_layer_prefill_paged(p, x, pool, table_row, start, valid,
+                            cfg: ModelConfig, rules: ShardingRules):
+    """One prefill chunk (B == 1) against the paged latent pool: the
+    counterpart of :func:`attn_layer_prefill_paged`."""
+    _, c, _ = x.shape
+    NB, bt, C = pool.shape
+    W = table_row.shape[0] * bt
+    positions = start + jnp.arange(c)
+    qa, rows = _mla_qkv(p, x, cfg, positions[None, :])
+    with jax.named_scope("attn.kv_write"):
+        ok = (jnp.arange(c) < valid)[:, None]
+        rz = jnp.where(ok, rows[0], 0).astype(pool.dtype)
+        nblk = c // bt
+        bids = jax.lax.dynamic_slice(table_row, (start // bt,), (nblk,))
+        pool = pool.at[bids].set(rz.reshape(nblk, bt, C))
+    with jax.named_scope("attn.core"):
+        ctx = pool[table_row].reshape(1, W, C)
+        mask = (jnp.arange(W)[None, :] <= positions[:, None])[None]
+        o = _mla_attend(qa, ctx, mask, cfg)
+    return _mla_out(p, x, o, cfg), pool
+
+
 # -- cross attention ---------------------------------------------------------
 
 def xattn_defs(cfg: ModelConfig) -> dict:
@@ -498,31 +722,42 @@ def mlp_layer(p, x, cfg: ModelConfig, rules: ShardingRules):
 # MoE — top-k routing, capacity dispatch, expert parallelism over `model`
 # ---------------------------------------------------------------------------
 
+def _shared_defs(cfg: ModelConfig, defs: dict) -> dict:
+    """Adds the shared experts, one SwiGLU of n_shared x d_ff_expert."""
+    if cfg.n_shared_experts:
+        f = cfg.n_shared_experts * (cfg.d_ff_expert or cfg.d_ff)
+        d, dt = cfg.d_model, cfg.dtype
+        defs["shared"] = {"wi": PV((d, f), dt, ("fsdp", "model")),
+                          "wg": PV((d, f), dt, ("fsdp", "model")),
+                          "wo": PV((f, d), dt, ("model", "fsdp"))}
+    return defs
+
+
 def moe_defs(cfg: ModelConfig) -> dict:
     d, dt = cfg.d_model, cfg.dtype
     E = cfg.n_experts
     ffe = cfg.d_ff_expert or cfg.d_ff
     # expert dim over `model` when divisible (EP), else ff dim (expert-TP)
-    return {
+    return _shared_defs(cfg, {
         "norm": PV((d,), jnp.float32, ("",), "ones"),
         "router": PV((d, E), jnp.float32, ("fsdp", "")),
         "wi": PV((E, d, ffe), dt, ("model", "fsdp", "")),
         "wg": PV((E, d, ffe), dt, ("model", "fsdp", "")),
         "wo": PV((E, ffe, d), dt, ("model", "", "fsdp")),
-    }
+    })
 
 
 def moe_defs_tp(cfg: ModelConfig) -> dict:
     d, dt = cfg.d_model, cfg.dtype
     E = cfg.n_experts
     ffe = cfg.d_ff_expert or cfg.d_ff
-    return {
+    return _shared_defs(cfg, {
         "norm": PV((d,), jnp.float32, ("",), "ones"),
         "router": PV((d, E), jnp.float32, ("fsdp", "")),
         "wi": PV((E, d, ffe), dt, ("", "fsdp", "model")),
         "wg": PV((E, d, ffe), dt, ("", "fsdp", "model")),
         "wo": PV((E, ffe, d), dt, ("", "model", "fsdp")),
-    }
+    })
 
 
 def _model_axes(rules: ShardingRules) -> tuple:
@@ -586,12 +821,112 @@ def _dispatch_ffn(xf, top_idx, top_gate, wi, wg, wo, e_base, E_loc, C):
     return out
 
 
+@jax.named_scope("moe.router")
+def moe_route(xn, router, cfg: ModelConfig):
+    """(gates, expert ids), each (..., k).  With ``norm_topk_prob`` the
+    gates are a softmax over the k highest router logits (Mixtral,
+    Qwen3); without it they are the softmax over every expert's score,
+    read at the k highest and not renormalised, with the scores in
+    float32 at full precision as DeepSeek-V2's ``MoEGate`` takes them."""
+    k = cfg.experts_per_token
+    if cfg.norm_topk_prob:
+        logits = (xn.astype(jnp.float32) @ router)
+        top_gate, top_idx = jax.lax.top_k(logits, k)
+        return jax.nn.softmax(top_gate, axis=-1), top_idx
+    logits = jnp.matmul(xn.astype(jnp.float32), router.astype(jnp.float32),
+                        precision=jax.lax.Precision.HIGHEST)
+    return jax.lax.top_k(jax.nn.softmax(logits, axis=-1), k)
+
+
+@jax.named_scope("moe.experts")
+def routed_experts(xf, top_idx, top_gate, wg, wi, wo, e_lo=0, layer=None,
+                   capacity=None):
+    """Routed experts: every (row, chosen expert) pair whose expert this
+    share holds (``e_lo`` .. ``e_lo + E_held - 1``) is computed, by one
+    grouped matmul per projection over the pairs sorted by expert.  xf
+    (N, d); top_idx / top_gate (N, k) -> (N, d) float32, each row's gated
+    sum over its held experts.  With ``capacity``, an expert keeps its
+    first ``capacity`` rows in row order and the rest add nothing, as
+    :func:`_dispatch_ffn` drops them; else it keeps every row.
+
+    Expert stacks are (E_held, d, f) etc., or, with ``layer``, every
+    layer's (n_layers, E_held, d, f): the grouped matmul then takes the
+    whole stack, with rows only in this layer's groups, so that it reads
+    the layer's experts where they lie (a grouped kernel takes a copy of
+    a slice it is given)."""
+    N, d = xf.shape
+    k = top_idx.shape[-1]
+    E_held = wg.shape[-3]
+    local = top_idx.reshape(N * k) - e_lo
+    held = (local >= 0) & (local < E_held)
+    key = jnp.where(held, local, E_held)            # the rest sort last
+    order = jnp.argsort(key, stable=True)
+    counts = jnp.bincount(key, length=E_held + 1).astype(jnp.int32)
+    sizes = counts[:E_held]
+    if capacity is not None:                        # rank within its expert
+        starts = jnp.cumsum(counts) - counts
+        rank = jnp.arange(N * k) - starts[key[order]]
+        held = held & jnp.zeros(N * k, bool).at[order].set(rank < capacity)
+    if layer is not None:
+        wg, wi, wo = (w.reshape((-1,) + w.shape[2:]) for w in (wg, wi, wo))
+        sizes = jax.lax.dynamic_update_slice(
+            jnp.zeros(wg.shape[0], jnp.int32), sizes, (layer * E_held,))
+    tok = order // k
+    xs = xf[tok].astype(wg.dtype)
+    g = kops.ragged_dot_f32(xs, wg, sizes)
+    u = kops.ragged_dot_f32(xs, wi, sizes)
+    h = (silu(g) * u).astype(wo.dtype)
+    y = kops.ragged_dot_f32(h, wo, sizes)
+    gate = jnp.where(held, top_gate.reshape(N * k), 0.0)[order]
+    y = jnp.where(held[order][:, None], y, 0.0)     # rows past the groups
+    return jnp.zeros((N, d), jnp.float32).at[tok].add(gate[:, None] * y)
+
+
+@jax.named_scope("moe.shared")
+def shared_experts(sp, xn):
+    """The always-on experts, one SwiGLU."""
+    h = silu(kops.dense(xn, sp["wg"])) * kops.dense(xn, sp["wi"])
+    return kops.dense(h, sp["wo"])
+
+
+def moe_local(p, xn, cfg: ModelConfig, *, e_lo: int = 0,
+              shared: bool = True, layer=None, count_rows=None):
+    """One chip's share of a MoE layer, without the residual: routing
+    over every expert (``p["router"]``), the routed experts that ``p``'s
+    expert stacks hold (ids from ``e_lo``; stacks of every layer with
+    ``layer``, :func:`routed_experts`; dropless unless the config sets a
+    ``capacity_factor``, which then caps each expert's rows of this
+    call), and, with ``shared``, the shared experts (a layer's shares
+    count them once).  With ``count_rows``, a mask over xn's rows, it
+    returns (y, how many of the held experts those rows chose)."""
+    top_gate, top_idx = moe_route(xn, p["router"], cfg)
+    xf = xn.reshape(-1, xn.shape[-1])
+    k = cfg.experts_per_token
+    cap = None if cfg.capacity_factor is None else max(1, int(math.ceil(
+        xf.shape[0] * k / cfg.n_experts * cfg.capacity_factor)))
+    y = routed_experts(xf, top_idx.reshape(-1, k), top_gate.reshape(-1, k),
+                       p["wg"], p["wi"], p["wo"], e_lo, layer,
+                       cap).reshape(xn.shape)
+    if shared and "shared" in p:
+        y = y + shared_experts(p["shared"], xn).astype(jnp.float32)
+    if count_rows is None:
+        return y
+    E_held = p["wg"].shape[-3]
+    local = top_idx.reshape(-1, k) - e_lo
+    pick = count_rows.reshape(-1, 1) & (local >= 0) & (local < E_held)
+    hits = jnp.zeros(E_held + 1, jnp.int32).at[
+        jnp.where(pick, local, E_held)].add(1)
+    return y, jnp.sum(hits[:E_held] > 0).astype(jnp.int32)
+
+
+@jax.named_scope("mlp")
 def moe_layer(p, x, cfg: ModelConfig, rules: ShardingRules, topology=None):
     """Top-k MoE with per-shard capacity.  EP mode: experts sharded over
     the `model` axes via shard_map (tokens replicated on the model axes —
     the GLSU "shuffle stage" becomes a local scatter + cross-lane psum
     combine).  TP mode (n_experts < |model|): all experts everywhere, ff
-    dim sharded.
+    dim sharded.  A config without a ``capacity_factor`` routes dropless
+    (:func:`moe_local`), on one device only.
 
     ``topology`` (a :class:`repro.topology.Topology` whose level axes are
     the `model` mesh axes) makes the ep_a2a dispatch hierarchical: the
@@ -602,10 +937,15 @@ def moe_layer(p, x, cfg: ModelConfig, rules: ShardingRules, topology=None):
     xn = rmsnorm(x, p["norm"], cfg.norm_eps)
     k = cfg.experts_per_token
     E = cfg.n_experts
-    logits = (xn.astype(jnp.float32) @ p["router"])            # (B,S,E)
-    top_gate, top_idx = jax.lax.top_k(logits, k)
-    top_gate = jax.nn.softmax(top_gate, axis=-1)               # normalised
     mode = moe_mode(cfg, rules)
+    if cfg.capacity_factor is None:
+        if mode != "local":
+            raise ValueError(f"{cfg.name}: dropless MoE runs on one device; "
+                             f"MoE mode {mode!r} needs a capacity_factor")
+        return x + moe_local(p, xn, cfg).astype(x.dtype)
+    top_gate, top_idx = moe_route(xn, p["router"], cfg)
+    if "shared" in p:
+        x = x + shared_experts(p["shared"], xn).astype(x.dtype)
 
     def run_local(xn_, ti_, tg_, wi, wg, wo, e_base, E_loc):
         N = xn_.shape[0] * xn_.shape[1]

@@ -25,7 +25,7 @@ import jax
 import jax.numpy as jnp
 
 from repro import substrate
-from repro.configs.base import ATTN, MAMBA, MLP, MOE, XATTN, ModelConfig
+from repro.configs.base import ATTN, MAMBA, MLA, MLP, MOE, XATTN, ModelConfig
 from repro.parallel.sharding import PV, ShardingRules, constraint
 from . import layers as L
 
@@ -44,6 +44,8 @@ def _stack(defs, n: int):
 def _sublayer_defs(kind: str, cfg: ModelConfig):
     if kind == ATTN:
         return L.attn_defs(cfg)
+    if kind == MLA:
+        return L.mla_defs(cfg)
     if kind == XATTN:
         return L.xattn_defs(cfg)
     if kind == MAMBA:
@@ -53,6 +55,45 @@ def _sublayer_defs(kind: str, cfg: ModelConfig):
     if kind == MOE:
         return L.moe_defs_tp(cfg) if cfg.moe_tp else L.moe_defs(cfg)
     raise ValueError(kind)
+
+
+def stacks(cfg: ModelConfig) -> list[tuple[str, tuple, int]]:
+    """(parameter key, layer kinds, repeats) of each scanned stack of
+    layers, in the order they run: the ``first_dense`` leading layers
+    (DeepSeek's dense MLP layers before the MoE period), then the period.
+    A cache or pool holds one subtree per stack (:func:`split_state`)."""
+    lead = [("lead", cfg.lead_period, cfg.first_dense)] \
+        if cfg.first_dense else []
+    return lead + [("period", cfg.layer_period, cfg.n_periods)]
+
+
+def split_state(cfg: ModelConfig, state) -> list:
+    """A cache or pool tree -> its subtree per stack.  Without leading
+    layers the tree is the period's own (the layout every dense model
+    had)."""
+    if cfg.first_dense:
+        return [state[name] for name, _, _ in stacks(cfg)]
+    return [state]
+
+
+def join_state(cfg: ModelConfig, parts: list):
+    if cfg.first_dense:
+        return {name: part for (name, _, _), part in zip(stacks(cfg), parts)}
+    return parts[0]
+
+
+def _stack_defs(kinds, n: int, leaf_defs) -> dict:
+    """{"l<i>": {"s<j>_<kind>": stacked defs}} over the kinds that
+    ``leaf_defs(kind)`` gives defs for (None: no entry)."""
+    out = {}
+    for li, layer in enumerate(kinds):
+        slots = {}
+        for si, kind in enumerate(layer):
+            d = leaf_defs(kind)
+            if d is not None:
+                slots[f"s{si}_{kind}"] = _stack(d, n)
+        out[f"l{li}"] = slots
+    return out
 
 
 def model_defs(cfg: ModelConfig) -> dict:
@@ -67,14 +108,8 @@ def model_defs(cfg: ModelConfig) -> dict:
     }
     if not cfg.tie_embeddings:
         defs["head"] = PV((d, Vp), dt, ("", "model"))
-    period = {}
-    for li, layer in enumerate(cfg.layer_period):
-        slots = {}
-        for si, kind in enumerate(layer):
-            slots[f"s{si}_{kind}"] = _stack(_sublayer_defs(kind, cfg),
-                                            cfg.n_periods)
-        period[f"l{li}"] = slots
-    defs["period"] = period
+    for name, kinds, n in stacks(cfg):
+        defs[name] = _stack_defs(kinds, n, lambda k: _sublayer_defs(k, cfg))
     if cfg.family == "encdec":
         enc_layer = {"attn": L.attn_defs(cfg), "mlp": L.mlp_defs(cfg)}
         defs["encoder"] = {"layers": _stack(enc_layer, cfg.n_enc_layers),
@@ -89,27 +124,25 @@ def model_defs(cfg: ModelConfig) -> dict:
 # ---------------------------------------------------------------------------
 
 def cache_defs(cfg: ModelConfig, batch: int, seq_len: int) -> dict:
-    period = {}
-    for li, layer in enumerate(cfg.layer_period):
-        slots = {}
-        for si, kind in enumerate(layer):
-            if kind == ATTN:
-                slots[f"s{si}_{kind}"] = _stack(
-                    L.attn_cache_defs(cfg, batch, seq_len)._asdict(),
-                    cfg.n_periods)
-            elif kind == XATTN:
-                slots[f"s{si}_{kind}"] = _stack(
-                    L.xattn_cache_defs(cfg, batch)._asdict(), cfg.n_periods)
-            elif kind == MAMBA:
-                slots[f"s{si}_{kind}"] = _stack(
-                    L.mamba_cache_defs(cfg, batch)._asdict(), cfg.n_periods)
-        period[f"l{li}"] = slots
-    return period
+    def leaf(kind):
+        if kind == ATTN:
+            return L.attn_cache_defs(cfg, batch, seq_len)._asdict()
+        if kind == MLA:
+            return L.mla_cache_defs(cfg, batch, seq_len)
+        if kind == XATTN:
+            return L.xattn_cache_defs(cfg, batch)._asdict()
+        if kind == MAMBA:
+            return L.mamba_cache_defs(cfg, batch)._asdict()
+        return None
+
+    return join_state(cfg, [_stack_defs(kinds, n, leaf)
+                            for _, kinds, n in stacks(cfg)])
 
 
 def pool_defs(cfg: ModelConfig, n_blocks: int, block_tokens: int) -> dict:
     """Paged-KV block pool defs: same tree shape as :func:`cache_defs` but
-    each ATTN leaf is (n_periods, n_blocks, block_tokens, Hkv, Dh) — a
+    each ATTN leaf is (n_periods, n_blocks, block_tokens, Hkv, Dh) and
+    each MLA leaf ``{"c": (n, n_blocks, block_tokens, latent_dim)}`` — a
     shared pool of fixed-size token blocks indexed by per-request block
     tables (block 0 is the reserved zero block).  Paged serving supports
     pure-attention caches only (no SSM/xattn state) and full attention
@@ -118,21 +151,22 @@ def pool_defs(cfg: ModelConfig, n_blocks: int, block_tokens: int) -> dict:
         raise ValueError("paged KV supports full attention only "
                          f"(cfg.window={cfg.window})")
     shp = (n_blocks, block_tokens, cfg.n_kv_heads, cfg.head_dim)
-    period = {}
-    for li, layer in enumerate(cfg.layer_period):
-        slots = {}
-        for si, kind in enumerate(layer):
-            if kind == ATTN:
-                slots[f"s{si}_{kind}"] = _stack(
-                    {"k": PV(shp, cfg.dtype, ("", "", "kv", ""), "zeros"),
-                     "v": PV(shp, cfg.dtype, ("", "", "kv", ""), "zeros")},
-                    cfg.n_periods)
-            elif kind in (XATTN, MAMBA):
-                raise ValueError(
-                    f"paged KV serving supports attention caches only, "
-                    f"layer period has {kind}")
-        period[f"l{li}"] = slots
-    return period
+
+    def leaf(kind):
+        if kind == ATTN:
+            return {"k": PV(shp, cfg.dtype, ("", "", "kv", ""), "zeros"),
+                    "v": PV(shp, cfg.dtype, ("", "", "kv", ""), "zeros")}
+        if kind == MLA:
+            return {"c": PV((n_blocks, block_tokens, cfg.latent_dim),
+                            cfg.dtype, ("", "", ""), "zeros")}
+        if kind in (XATTN, MAMBA):
+            raise ValueError(
+                f"paged KV serving supports attention caches only, "
+                f"layer period has {kind}")
+        return None
+
+    return join_state(cfg, [_stack_defs(kinds, n, leaf)
+                            for _, kinds, n in stacks(cfg)])
 
 
 # ---------------------------------------------------------------------------
@@ -176,6 +210,8 @@ def encode_context(params, ctx_embeds, cfg: ModelConfig, rules: ShardingRules):
 def _apply_slot(kind, sp, x, cfg, rules, positions, ctx):
     if kind == ATTN:
         return L.attn_layer(sp, x, cfg, rules, positions, causal=True)
+    if kind == MLA:
+        return L.mla_layer(sp, x, cfg, rules, positions)
     if kind == XATTN:
         return L.xattn_layer(sp, x, ctx, cfg, rules)
     if kind == MAMBA:
@@ -189,24 +225,22 @@ def _apply_slot(kind, sp, x, cfg, rules, positions, ctx):
 
 def trunk(params, x, cfg: ModelConfig, rules: ShardingRules, positions,
           ctx=None):
-    period = params["period"]
-    kinds = cfg.layer_period
+    for name, kinds, n in stacks(cfg):
+        def body(xc, pp, kinds=kinds):
+            for li, layer in enumerate(kinds):
+                for si, kind in enumerate(layer):
+                    sp = pp[f"l{li}"][f"s{si}_{kind}"]
+                    xc = _apply_slot(kind, sp, xc, cfg, rules, positions, ctx)
+                    xc = constraint(xc, rules, "batch", "act_seq", None)
+            return xc, None
 
-    def body(xc, pp):
-        for li, layer in enumerate(kinds):
-            for si, kind in enumerate(layer):
-                sp = pp[f"l{li}"][f"s{si}_{kind}"]
-                xc = _apply_slot(kind, sp, xc, cfg, rules, positions, ctx)
-                xc = constraint(xc, rules, "batch", "act_seq", None)
-        return xc, None
-
-    if cfg.remat:
-        body = jax.checkpoint(body)
-    if cfg.unroll_layers:
-        for i in range(cfg.n_periods):
-            x, _ = body(x, jax.tree.map(lambda t: t[i], period))
-        return x
-    x, _ = jax.lax.scan(body, x, period)
+        if cfg.remat:
+            body = jax.checkpoint(body)
+        if cfg.unroll_layers:
+            for i in range(n):
+                x, _ = body(x, jax.tree.map(lambda t: t[i], params[name]))
+            continue
+        x, _ = jax.lax.scan(body, x, params[name])
     return x
 
 
@@ -324,10 +358,9 @@ def prefill(params, tokens, cfg: ModelConfig, rules: ShardingRules,
     if cfg.family in ("encdec", "vlm"):
         ctx = encode_context(params, ctx_embeds, cfg, rules)
     x = embed_tokens(params, tokens, cfg, rules)
-    kinds = cfg.layer_period
     W = L.attn_cache_len(cfg, cache_seq_len)
 
-    def body(xc, pp):
+    def body(xc, pp, kinds):
         caches = {}
         for li, layer in enumerate(kinds):
             lcaches = {}
@@ -338,6 +371,9 @@ def prefill(params, tokens, cfg: ModelConfig, rules: ShardingRules,
                     xc, c = L.attn_layer_prefill(sp, xc, cfg, rules,
                                                  positions, W)
                     lcaches[key] = c._asdict()
+                elif kind == MLA:
+                    xc, lcaches[key] = L.mla_layer_prefill(
+                        sp, xc, cfg, rules, positions, W)
                 elif kind == XATTN:
                     xc = L.xattn_layer(sp, xc, ctx, cfg, rules)
                     lcaches[key] = L.xattn_prefill_cache(sp, ctx, cfg)._asdict()
@@ -352,18 +388,22 @@ def prefill(params, tokens, cfg: ModelConfig, rules: ShardingRules,
         xc = constraint(xc, rules, "batch", None, None)
         return xc, caches
 
-    if cfg.remat:
-        body = jax.checkpoint(body)
-    if cfg.unroll_layers:                      # cost-analysis variants
-        caches = []
-        for i in range(cfg.n_periods):
-            x, c = body(x, jax.tree.map(lambda t: t[i], params["period"]))
-            caches.append(c)
-        cache = jax.tree.map(lambda *xs: jnp.stack(xs), *caches)
-    else:
-        x, cache = jax.lax.scan(body, x, params["period"])
+    parts = []
+    for name, kinds, n in stacks(cfg):
+        fn = functools.partial(body, kinds=kinds)
+        if cfg.remat:
+            fn = jax.checkpoint(fn)
+        if cfg.unroll_layers:                  # cost-analysis variants
+            caches = []
+            for i in range(n):
+                x, c = fn(x, jax.tree.map(lambda t: t[i], params[name]))
+                caches.append(c)
+            parts.append(jax.tree.map(lambda *xs: jnp.stack(xs), *caches))
+        else:
+            x, c = jax.lax.scan(fn, x, params[name])
+            parts.append(c)
     logits = logits_fn(params, x[:, -1:], cfg, rules)
-    return cache, logits
+    return join_state(cfg, parts), logits
 
 
 def decode_step(params, token, cache, pos, cfg: ModelConfig,
@@ -373,9 +413,8 @@ def decode_step(params, token, cache, pos, cfg: ModelConfig,
     engine's continuous batch; it is bit-identical to the scalar form
     when every slot sits at the same position."""
     x = embed_tokens(params, token, cfg, rules)
-    kinds = cfg.layer_period
 
-    def body(xc, pc):
+    def body(xc, pc, kinds):
         pp, cc = pc
         new_caches = {}
         for li, layer in enumerate(kinds):
@@ -387,6 +426,9 @@ def decode_step(params, token, cache, pos, cfg: ModelConfig,
                     c = L.AttnCache(**cc[f"l{li}"][key])
                     xc, c = L.attn_layer_decode(sp, xc, c, pos, cfg, rules)
                     lcaches[key] = c._asdict()
+                elif kind == MLA:
+                    xc, lcaches[key] = L.mla_layer_decode(
+                        sp, xc, cc[f"l{li}"][key], pos, cfg, rules)
                 elif kind == XATTN:
                     c = L.XAttnCache(**cc[f"l{li}"][key])
                     xc, c = L.xattn_layer_decode(sp, xc, c, cfg, rules)
@@ -400,17 +442,94 @@ def decode_step(params, token, cache, pos, cfg: ModelConfig,
             new_caches[f"l{li}"] = lcaches
         return xc, new_caches
 
-    if cfg.unroll_layers:                      # cost-analysis variants
-        caches = []
-        for i in range(cfg.n_periods):
-            x, c = body(x, jax.tree.map(lambda t: t[i],
-                                        (params["period"], cache)))
-            caches.append(c)
-        new_cache = jax.tree.map(lambda *xs: jnp.stack(xs), *caches)
-    else:
-        x, new_cache = jax.lax.scan(body, x, (params["period"], cache))
+    parts = []
+    for (name, kinds, n), part in zip(stacks(cfg), split_state(cfg, cache)):
+        fn = functools.partial(body, kinds=kinds)
+        if cfg.unroll_layers:                  # cost-analysis variants
+            caches = []
+            for i in range(n):
+                x, c = fn(x, jax.tree.map(lambda t: t[i],
+                                          (params[name], part)))
+                caches.append(c)
+            parts.append(jax.tree.map(lambda *xs: jnp.stack(xs), *caches))
+        else:
+            x, c = jax.lax.scan(fn, x, (params[name], part))
+            parts.append(c)
     logits = logits_fn(params, x, cfg, rules)
-    return logits, new_cache
+    return logits, join_state(cfg, parts)
+
+
+#: the MoE weights the paged programs pass whole, not per layer
+EXPERT_STACKS = ("wg", "wi", "wo")
+
+
+def _paged_layers(params, x, pool, cfg: ModelConfig, rules: ShardingRules,
+                  attend, count_rows=None):
+    """Scan every stack of layers over its share of the paged pool:
+    ``attend(kind, sp, x, pool_leaf) -> (x, pool_leaf)`` runs each
+    attention sublayer; MoE sublayers route through
+    :func:`repro.models.layers.moe_local`, on one device only.  Their
+    expert stacks stay out of the scan: each layer's grouped matmuls take
+    the whole stack and the layer's index, and read its experts in
+    place.  Returns (x, new pool, reached): with ``count_rows``, a mask
+    over x's rows, ``reached`` holds per MoE layer, in order, how many
+    experts those rows chose; else None."""
+    in_place = bool(cfg.n_experts)
+    if in_place and L.moe_mode(cfg, rules) != "local":
+        raise ValueError("paged serving reads MoE experts in place on one "
+                         f"device; MoE mode {L.moe_mode(cfg, rules)!r} "
+                         "shards them")
+
+    def body(xc, pc, kinds, experts):
+        pp, cc, i = pc if in_place else (*pc, None)
+        new_pool, reached = {}, []
+        for li, layer in enumerate(kinds):
+            lpool = {}
+            for si, kind in enumerate(layer):
+                key = f"s{si}_{kind}"
+                sp = pp[f"l{li}"][key]
+                if kind in (ATTN, MLA):
+                    xc, lpool[key] = attend(kind, sp, xc, cc[f"l{li}"][key])
+                elif kind == MOE:
+                    sp = dict(sp, **experts[f"l{li}"][key])
+                    with jax.named_scope("mlp"):
+                        xn = L.rmsnorm(xc, sp["norm"], cfg.norm_eps)
+                        y = L.moe_local(sp, xn, cfg, layer=i,
+                                        count_rows=count_rows)
+                        if count_rows is not None:
+                            y, n = y
+                            reached.append(n)
+                        xc = xc + y.astype(xc.dtype)
+                else:
+                    xc = _apply_slot(kind, sp, xc, cfg, rules, None, None)
+            new_pool[f"l{li}"] = lpool
+        if count_rows is None:
+            return xc, new_pool
+        return xc, (new_pool, jnp.stack(reached) if reached
+                    else jnp.zeros(0, jnp.int32))
+
+    parts, counts = [], []
+    for (name, kinds, n), part in zip(stacks(cfg), split_state(cfg, pool)):
+        scanned, experts = params[name], {}
+        if in_place:
+            scanned = {lk: {key: {w: v for w, v in sp.items()
+                                  if not key.endswith(f"_{MOE}")
+                                  or w not in EXPERT_STACKS}
+                            for key, sp in slots.items()}
+                       for lk, slots in params[name].items()}
+            experts = {lk: {key: {w: sp[w] for w in EXPERT_STACKS}
+                            for key, sp in slots.items()
+                            if key.endswith(f"_{MOE}")}
+                       for lk, slots in params[name].items()}
+        xs = (scanned, part, jnp.arange(n)) if in_place else (scanned, part)
+        x, c = jax.lax.scan(
+            functools.partial(body, kinds=kinds, experts=experts), x, xs)
+        if count_rows is not None:
+            c, r = c
+            counts.append(r.reshape(-1))
+        parts.append(c)
+    reached = jnp.concatenate(counts) if count_rows is not None else None
+    return x, join_state(cfg, parts), reached
 
 
 def decode_step_paged(params, token, pool, tables, pos, live,
@@ -424,28 +543,16 @@ def decode_step_paged(params, token, pool, tables, pos, live,
     cache (zero block 0 ≡ unwritten dense rows)."""
     x = embed_tokens(params, token, cfg, rules)
 
-    def step(sp, xc, pk, pv):
-        return L.attn_layer_decode_paged(sp, xc, pk, pv, tables, pos, live,
-                                         cfg, rules)
+    def attend(kind, sp, xc, c):
+        if kind == MLA:
+            xc, pc = L.mla_layer_decode_paged(sp, xc, c["c"], tables, pos,
+                                              live, cfg, rules)
+            return xc, {"c": pc}
+        xc, pk, pv = L.attn_layer_decode_paged(sp, xc, c["k"], c["v"],
+                                               tables, pos, live, cfg, rules)
+        return xc, {"k": pk, "v": pv}
 
-    def body(xc, pc):
-        pp, cc = pc
-        new_pool = {}
-        for li, layer in enumerate(cfg.layer_period):
-            lpool = {}
-            for si, kind in enumerate(layer):
-                key = f"s{si}_{kind}"
-                sp = pp[f"l{li}"][key]
-                if kind == ATTN:
-                    c = cc[f"l{li}"][key]
-                    xc, pk, pv = step(sp, xc, c["k"], c["v"])
-                    lpool[key] = {"k": pk, "v": pv}
-                else:
-                    xc = _apply_slot(kind, sp, xc, cfg, rules, None, None)
-            new_pool[f"l{li}"] = lpool
-        return xc, new_pool
-
-    x, new_pool = jax.lax.scan(body, x, (params["period"], pool))
+    x, new_pool, _ = _paged_layers(params, x, pool, cfg, rules, attend)
     logits = logits_fn(params, x, cfg, rules)
     return logits, new_pool
 
@@ -459,28 +566,25 @@ def prefill_chunk(params, tokens, pool, table_row, start, valid,
     tokens.  Scatters the chunk's K/V into the pre-allocated blocks of
     ``table_row`` and returns (logits (1, c, V), new pool) — the engine
     reads logits[0, valid-1] on the final chunk for the first generated
-    token.  Compiles once per chunk shape, not once per prompt length."""
+    token — and, for a MoE model, a third output: per MoE layer, how many
+    experts the chunk's valid rows chose.  Compiles once per chunk shape,
+    not once per prompt length."""
     x = embed_tokens(params, tokens, cfg, rules)
 
-    def body(xc, pc):
-        pp, cc = pc
-        new_pool = {}
-        for li, layer in enumerate(cfg.layer_period):
-            lpool = {}
-            for si, kind in enumerate(layer):
-                key = f"s{si}_{kind}"
-                sp = pp[f"l{li}"][key]
-                if kind == ATTN:
-                    c = cc[f"l{li}"][key]
-                    xc, pk, pv = L.attn_layer_prefill_paged(
-                        sp, xc, c["k"], c["v"], table_row, start, valid,
-                        cfg, rules)
-                    lpool[key] = {"k": pk, "v": pv}
-                else:
-                    xc = _apply_slot(kind, sp, xc, cfg, rules, None, None)
-            new_pool[f"l{li}"] = lpool
-        return xc, new_pool
+    def attend(kind, sp, xc, c):
+        if kind == MLA:
+            xc, pc = L.mla_layer_prefill_paged(sp, xc, c["c"], table_row,
+                                               start, valid, cfg, rules)
+            return xc, {"c": pc}
+        xc, pk, pv = L.attn_layer_prefill_paged(
+            sp, xc, c["k"], c["v"], table_row, start, valid, cfg, rules)
+        return xc, {"k": pk, "v": pv}
 
-    x, new_pool = jax.lax.scan(body, x, (params["period"], pool))
+    count = (jnp.arange(tokens.shape[1]) < valid)[None] \
+        if cfg.n_experts else None
+    x, new_pool, reached = _paged_layers(params, x, pool, cfg, rules, attend,
+                                         count)
     logits = logits_fn(params, x, cfg, rules)
-    return logits, new_pool
+    if reached is None:
+        return logits, new_pool
+    return logits, new_pool, reached

@@ -90,6 +90,9 @@ class Request:
     t_admit: float | None = None        # it left the queue for a slot
     t_prefill_start: float | None = None  # its first prefill dispatched
     t_first: float | None = None        # its first token read back
+    shared_blocks: int | None = None    # prompt blocks found shared at admit
+    # (dispatch time, experts chosen per MoE layer) of each prefill chunk
+    chunk_experts: list = dataclasses.field(default_factory=list)
 
 
 @dataclasses.dataclass(frozen=True)

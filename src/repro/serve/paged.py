@@ -39,7 +39,11 @@ Tracing: the three programs are named (``jit_decode_step_paged``,
 a flag check when no profiler runs.  Each request is stamped on
 :func:`repro.testing.timing.now`: ``t_submit``, ``t_admit``,
 ``t_prefill_start`` (dispatch of its first chunk, or of its whole-prompt
-prefill) and ``t_first``, which split its time to the first token.
+prefill) and ``t_first``, which split its time to the first token; at
+admission it also gets ``shared_blocks``, the count of its prompt's
+blocks found in the prefix registry, and under a MoE model
+``chunk_experts``, each chunk's dispatch time with the count of experts
+its rows chose in each MoE layer (a device array).
 """
 from __future__ import annotations
 
@@ -51,7 +55,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.profiler import TraceAnnotation
 
-from repro.configs.base import ATTN, ModelConfig
+from repro.configs.base import ATTN, MLA, ModelConfig
 from repro.models import lm
 from repro.parallel.sharding import ShardingRules
 from repro.testing.timing import now
@@ -62,13 +66,29 @@ PREFILL, DECODE = 0, 1
 
 
 def kv_token_bytes(cfg: ModelConfig) -> int:
-    """KV bytes per token across the whole model (k+v, every attention
-    sublayer instance) — the unit both engines' resident-bytes metrics
-    are denominated in."""
-    n_attn = sum(kind == ATTN for layer in cfg.layer_period
-                 for kind in layer) * cfg.n_periods
-    isz = jnp.dtype(cfg.dtype).itemsize
-    return 2 * cfg.n_kv_heads * cfg.head_dim * isz * n_attn
+    """Cache bytes per token across the whole model, by kind of
+    attention: k and v of every attention sublayer instance, the latent
+    row (``latent_dim`` values) of every latent-attention one — the unit
+    both engines' resident-bytes metrics are denominated in."""
+    per = {ATTN: 2 * cfg.n_kv_heads * cfg.head_dim, MLA: cfg.latent_dim}
+    values = sum(per.get(kind, 0) * n for _, kinds, n in lm.stacks(cfg)
+                 for layer in kinds for kind in layer)
+    return values * jnp.dtype(cfg.dtype).itemsize
+
+
+def prefix_keys(prompt, block_tokens: int) -> list[tuple]:
+    """Registry keys of a prompt's blocks, in order: ``("full", tokens up
+    to the block's end)`` for each full block, then ``("part", the whole
+    prompt)`` for a trailing partial block.  Tokens are held as the bytes
+    of their int64 values, which hash and compare in C; a tuple of Python
+    ints per key cost O(plen² / block_tokens) interpreter work a prompt."""
+    flat = np.asarray(prompt, np.int64)
+    plen = len(flat)
+    keys = [("full", flat[:end].tobytes())
+            for end in range(block_tokens, plen + 1, block_tokens)]
+    if plen % block_tokens:
+        keys.append(("part", flat.tobytes()))
+    return keys
 
 
 @dataclasses.dataclass(frozen=True)
@@ -225,7 +245,9 @@ class PagedServingEngine:
         self.slot_state = np.full(B, DECODE, np.int32)
         self.slot_fill = np.zeros(B, np.int32)      # chunked-prefill progress
         self.slot_reserve = np.zeros(B, np.int64)   # worst-case future allocs
-        self._slot_new: list[list[tuple[int, int]]] = [[] for _ in range(B)]
+        # per slot, (bid, registry key) of the owned prompt blocks that
+        # chunked prefill publishes once their content is written
+        self._slot_new: list[list[tuple[int, tuple]]] = [[] for _ in range(B)]
         self.waiting: list[Request] = []
         self.finished: list[Request] = []
         self.peak_live = 0
@@ -288,32 +310,20 @@ class PagedServingEngine:
         shared bids, reservation).  None if the pool cannot cover this
         request's worst case plus every outstanding reservation."""
         bt = self.scfg.block_tokens
-        prompt = np.asarray(req.prompt)
-        plen = len(prompt)
-        nfull = plen // bt
+        plen = len(req.prompt)
         row: list[int] = []
-        own: list[tuple[int, tuple | None]] = []   # (blk_idx, registry key)
+        own: list[tuple[int, tuple]] = []          # (blk_idx, registry key)
         shared: list[int] = []
         partial_shared = False
-        for j in range(nfull):
-            key = ("full", tuple(int(t) for t in prompt[:(j + 1) * bt]))
+        for j, key in enumerate(prefix_keys(req.prompt, bt)):
             bid = self.alloc.lookup(key)
             if bid is not None:
                 row.append(bid)
                 shared.append(bid)
+                partial_shared = key[0] == "part"
             else:
                 row.append(-1)
                 own.append((j, key))
-        if plen % bt:
-            key = ("part", tuple(int(t) for t in prompt))
-            bid = self.alloc.lookup(key)
-            if bid is not None:
-                row.append(bid)
-                shared.append(bid)
-                partial_shared = True
-            else:
-                row.append(-1)
-                own.append((nfull, key))
         prompt_blocks = len(row)
         total = min(math.ceil((plen + req.max_new_tokens) / bt),
                     self.max_blocks)
@@ -341,6 +351,7 @@ class PagedServingEngine:
             row, own, shared, reserve = plan
             req = self.waiting.pop(0)
             req.t_admit = now()
+            req.shared_blocks = len(shared)
             admitted += 1
             slot = free.pop(0)
             req.slot = slot
@@ -353,9 +364,11 @@ class PagedServingEngine:
                 # fully written (prefill completion), so a concurrent
                 # admit never shares a half-filled block
                 bid = self.alloc.alloc(None if chunked else key)
-                row[row.index(-1)] = bid
+                row[j] = bid
                 new_bids.append((j, bid))
-            self._slot_new[slot] = new_bids
+            if chunked:
+                self._slot_new[slot] = [(bid, key) for (_, key), (_, bid)
+                                        in zip(own, new_bids)]
             self.tables[slot] = 0
             self.tables[slot, :len(row)] = row
             self.slot_reserve[slot] = reserve
@@ -387,9 +400,8 @@ class PagedServingEngine:
 
             def put(pool_leaf, cache_leaf):
                 P = pool_leaf.shape[0]
-                H, D = pool_leaf.shape[-2:]
-                blocks = cache_leaf[:, 0].reshape(P, self.max_blocks, bt,
-                                                  H, D)
+                blocks = cache_leaf[:, 0].reshape(
+                    (P, self.max_blocks, bt) + pool_leaf.shape[3:])
                 return pool_leaf.at[:, bids].set(blocks[:, js])
 
             self.pool = jax.tree.map(put, self.pool, cache)
@@ -416,12 +428,16 @@ class PagedServingEngine:
                              valid=valid):
             chunk = np.zeros((1, c), np.int32)
             chunk[0, :valid] = prompt[start:start + valid]
+            t = now()
             if start == 0:
-                req.t_prefill_start = now()
-            logits, self.pool = self._chunk(
+                req.t_prefill_start = t
+            logits, self.pool, *reached = self._chunk(
                 self.params, jnp.asarray(chunk), self.pool,
                 jnp.asarray(self.tables[i]), jnp.int32(start),
                 jnp.int32(valid))
+            if reached:
+                # left on the device: read after serving, not per chunk
+                req.chunk_experts.append((t, reached[0]))
             self.prefill_chunks += 1
             self.slot_fill[i] = start + valid
             if self.slot_fill[i] >= plen:
@@ -430,14 +446,7 @@ class PagedServingEngine:
                 self.slot_state[i] = DECODE
                 self.slot_pos[i] = plen
                 # content now complete: publish the owned prompt blocks
-                bt = self.scfg.block_tokens
-                nfull = plen // bt
-                for j, bid in self._slot_new[i]:
-                    if j < nfull:
-                        key = ("full",
-                               tuple(int(t) for t in prompt[:(j + 1) * bt]))
-                    else:
-                        key = ("part", tuple(int(t) for t in prompt))
+                for bid, key in self._slot_new[i]:
                     self.alloc.register(bid, key)
                 self._slot_new[i] = []
         return True

@@ -8,6 +8,7 @@ import pytest
 from repro.analysis.bench import load_serve_bench, validate_serve_bench
 from repro.serve import (BlockAllocator, PromptTooLongError, Request,
                          kv_token_bytes, validate_prompt)
+from repro.serve.paged import prefix_keys
 from repro.testing.subproc import run_check
 
 
@@ -66,6 +67,26 @@ def test_register_first_writer_wins_and_forget():
     a.forget_key(b1)                        # pre-divergence unpublish
     assert a.lookup(key) is None
     assert a.refcount[b1] == 1              # forget does not free
+
+
+def test_prefix_keys_name_each_block_by_its_whole_prefix():
+    prompt = list(range(100, 137))          # 2 full blocks of 16 + 5 tokens
+    keys = prefix_keys(prompt, 16)
+    assert [k[0] for k in keys] == ["full", "full", "part"]
+    # the same tokens give the same keys whatever container holds them
+    assert prefix_keys(np.asarray(prompt, np.int32), 16) == keys
+    assert prefix_keys(np.asarray(prompt, np.int64), 16) == keys
+    # a block's key covers every token before it: a change in block 0
+    # changes every later key, a change in the tail changes only "part"
+    early = prefix_keys([7] + prompt[1:], 16)
+    assert all(a != b for a, b in zip(early, keys))
+    late = prefix_keys(prompt[:-1] + [7], 16)
+    assert late[:2] == keys[:2] and late[2] != keys[2]
+    # a prompt of whole blocks has no "part" key; its first blocks' keys
+    # are those of any longer prompt that starts with it
+    whole = prefix_keys(prompt[:32], 16)
+    assert whole == keys[:2]
+    assert len(set(keys)) == len(keys)
 
 
 # ---------------------------------------------------------------------------
