@@ -384,34 +384,97 @@ def attn_layer_prefill(p, x, cfg: ModelConfig, rules, positions, cache_len):
 # cache's unwritten rows hold, so paged decode is bit-identical to the
 # dense engine.  Full attention only (no SWA ring) — the paged engine
 # rejects windowed configs.
+#
+# Each pool leaf is (n, NB, bt, *row): a stack's n layers, and in each
+# block bt token rows, each a token's keys (or values, or latent) in
+# whole 128-lane vector registers (:func:`kv_row`, :func:`pool_row`).
+# Given a minor dimension that is not whole registers, the TPU keeps an
+# array with its block axis minor-most, and each program would copy the
+# whole pool into row-major order and back.  A program reads and writes
+# a layer's rows in place, by its index in the stack, and whole: the
+# TPU's gather and scatter of part of a row are loops, and the lanes
+# past a token's values are zero.
 
-def attn_layer_decode_paged(p, x, pk, pv, tables, pos, live,
+#: lanes of a TPU vector register
+LANES = 128
+
+
+def pool_row(width: int) -> int:
+    """Lanes of a pool row that holds ``width`` values."""
+    return -(-width // LANES) * LANES
+
+
+def kv_row(cfg: ModelConfig) -> tuple[int, ...]:
+    """Shape of one token's keys (or values) in the paged pool: its heads
+    as they are where a head fills whole registers (the gathered context
+    is then laid out for attention as it comes), else all heads in one
+    row of whole registers (phi3's 32 heads of 96 in 3,072 lanes, not
+    padded to 128 each: on the TPU the padded heads compile to float32
+    casts of the whole context, the one row to a cheaper bf16 relayout)."""
+    if cfg.head_dim % LANES == 0:
+        return (cfg.n_kv_heads, cfg.head_dim)
+    return (pool_row(cfg.n_kv_heads * cfg.head_dim),)
+
+
+def whole_rows(x, pool, lead: int):
+    """Values x (*lead, ...) as whole rows of the pool leaf ``pool``
+    (n, NB, bt, *row): (*lead, *row), zeros in the lanes past x's."""
+    row = pool.shape[3:]
+    if x.shape[lead:] == row:
+        return x
+    flat = x.reshape(x.shape[:lead] + (-1,))
+    pad = math.prod(row) - flat.shape[-1]
+    return jnp.pad(flat, [(0, 0)] * lead + [(0, pad)]).reshape(
+        x.shape[:lead] + row)
+
+
+def _context(rows, lead: tuple[int, ...], values: tuple[int, ...]):
+    """Gathered pool rows (..., *row) -> (*lead, *values): each token's
+    values.  A row of whole registers loses the lanes past them before
+    its blocks merge into positions, where the TPU keeps the slice in
+    place (a bitcast) for the ops that read the context."""
+    if rows.shape[-len(values):] != values:
+        rows = rows[..., :math.prod(values)]
+    return rows.reshape(lead + values)
+
+
+def attn_layer_decode_paged(p, x, pk, pv, i, tables, pos, live,
                             cfg: ModelConfig, rules: ShardingRules):
     """One-token decode against a block-table paged KV pool.
 
-    pk/pv (NB, bt, Hkv, Dh) — the shared block pool (block 0 is the
-    reserved zero block, never written by a live slot); tables
-    (B, max_blocks) int32; pos (B,) per-slot positions; live (B,) bool.
-    Dead slots write a predicated no-op (they re-write the zero block's
-    current value) so the batched step stays shape-stable.  The gathered
-    view ``pk[tables].reshape(B, W, ...)`` is elementwise identical to the
-    dense cache rows, and the math below is the same expression as
-    :func:`attn_layer_decode`'s vector-pos path — bit-identical streams."""
+    pk/pv (n, NB, bt, *row) — the stack's shared block pool, of which
+    this layer owns ``pk[i]``, a token's Hkv x Dh keys in its row
+    (:func:`kv_row`; block 0 is the reserved zero block, never written by
+    a live slot); tables (B, max_blocks) int32; pos (B,) per-slot
+    positions; live (B,) bool.  The new rows go in with one scatter into
+    the stacked leaf and the context comes out with one gather that
+    carries ``i``, so no layer's pool is sliced out; rows are written and
+    read whole.  Dead slots write a predicated no-op (they re-write the
+    zero block's current value) so the batched step stays shape-stable.
+    The gathered view ``_context(pk[i, tables], ...)`` is elementwise
+    identical to the dense cache rows, and the math below is the same
+    expression as :func:`attn_layer_decode`'s vector-pos path —
+    bit-identical streams."""
     B, S1, d = x.shape                      # S1 == 1
-    NB, bt, Hkv, hd = pk.shape
+    bt = pk.shape[2]
+    Hkv, hd = cfg.n_kv_heads, cfg.head_dim
     W = tables.shape[1] * bt
     q, k, v = _qkv(p, x, cfg, rules, pos[:, None], rotate=True)
     with jax.named_scope("attn.kv_write"):
         blk = jnp.take_along_axis(tables, (pos // bt)[:, None], axis=1)[:, 0]
         off = pos % bt
-        cur_k, cur_v = pk[blk, off], pv[blk, off]      # (B, Hkv, Dh)
-        nk = jnp.where(live[:, None, None], k[:, 0].astype(pk.dtype), cur_k)
-        nv = jnp.where(live[:, None, None], v[:, 0].astype(pv.dtype), cur_v)
-        pk = pk.at[blk, off].set(nk)
-        pv = pv.at[blk, off].set(nv)
+        live_rows = live.reshape((B,) + (1,) * (pk.ndim - 3))
+        nk = jnp.where(live_rows,
+                       whole_rows(k[:, 0].astype(pk.dtype), pk, 1),
+                       pk[i, blk, off])
+        nv = jnp.where(live_rows,
+                       whole_rows(v[:, 0].astype(pv.dtype), pv, 1),
+                       pv[i, blk, off])
+        pk = pk.at[i, blk, off].set(nk)
+        pv = pv.at[i, blk, off].set(nv)
     with jax.named_scope("attn.core"):
-        ck = pk[tables].reshape(B, W, Hkv, hd)
-        cv = pv[tables].reshape(B, W, Hkv, hd)
+        ck = _context(pk[i, tables], (B, W), (Hkv, hd))
+        cv = _context(pv[i, tables], (B, W), (Hkv, hd))
         ck = constraint(ck, rules, "batch", None, "kv", None)
         cv = constraint(cv, rules, "batch", None, "kv", None)
         G = cfg.n_heads // cfg.n_kv_heads
@@ -429,20 +492,23 @@ def attn_layer_decode_paged(p, x, pk, pv, tables, pos, live,
         return x + o.astype(x.dtype), pk, pv
 
 
-def attn_layer_prefill_paged(p, x, pk, pv, table_row, start, valid,
+def attn_layer_prefill_paged(p, x, pk, pv, i, table_row, start, valid,
                              cfg: ModelConfig, rules: ShardingRules):
     """One prefill *chunk* (B == 1) against the paged pool.
 
     x (1, c, d) is the embedded chunk, padded to the fixed chunk length c;
-    ``valid`` counts real tokens, ``start`` is the chunk's base position
-    (a multiple of the block size).  The chunk's K/V are scattered whole
-    blocks at a time into the pre-allocated blocks of ``table_row``
-    (padding rows zeroed first, so the zero block stays zero even when the
-    tail of the slice lands on unallocated entries), then the chunk
-    attends causally over the full gathered view — earlier chunks' blocks
-    are already resident, which is what makes chunked prefill exact."""
+    pk/pv the stacked pool leaves and ``i`` this layer's index in them, as
+    in :func:`attn_layer_decode_paged`; ``valid`` counts real tokens,
+    ``start`` is the chunk's base position (a multiple of the block
+    size).  The chunk's K/V are scattered whole blocks at a time into the
+    pre-allocated blocks of ``table_row`` (padding rows zeroed first, so
+    the zero block stays zero even when the tail of the slice lands on
+    unallocated entries), then the chunk attends causally over the full
+    gathered view — earlier chunks' blocks are already resident, which is
+    what makes chunked prefill exact."""
     B, c, d = x.shape                       # B == 1
-    NB, bt, Hkv, hd = pk.shape
+    bt = pk.shape[2]
+    Hkv, hd = cfg.n_kv_heads, cfg.head_dim
     W = table_row.shape[0] * bt
     positions = start + jnp.arange(c)
     q, k, v = _qkv(p, x, cfg, rules, positions[None, :], rotate=True)
@@ -452,11 +518,13 @@ def attn_layer_prefill_paged(p, x, pk, pv, table_row, start, valid,
         vz = jnp.where(ok, v, 0).astype(pv.dtype)
         nblk = c // bt
         bids = jax.lax.dynamic_slice(table_row, (start // bt,), (nblk,))
-        pk = pk.at[bids].set(kz[0].reshape(nblk, bt, Hkv, hd))
-        pv = pv.at[bids].set(vz[0].reshape(nblk, bt, Hkv, hd))
+        pk = pk.at[i, bids].set(
+            whole_rows(kz[0].reshape(nblk, bt, Hkv, hd), pk, 2))
+        pv = pv.at[i, bids].set(
+            whole_rows(vz[0].reshape(nblk, bt, Hkv, hd), pv, 2))
     with jax.named_scope("attn.core"):
-        ck = pk[table_row].reshape(1, W, Hkv, hd)
-        cv = pv[table_row].reshape(1, W, Hkv, hd)
+        ck = _context(pk[i, table_row], (1, W), (Hkv, hd))
+        cv = _context(pv[i, table_row], (1, W), (Hkv, hd))
         ck = constraint(ck, rules, "batch", None, "kv", None)
         cv = constraint(cv, rules, "batch", None, "kv", None)
         G = cfg.n_heads // cfg.n_kv_heads
@@ -595,34 +663,38 @@ def mla_layer_decode(p, x, cache: dict, pos, cfg: ModelConfig,
     return _mla_out(p, x, o, cfg), {"c": c}
 
 
-def mla_layer_decode_paged(p, x, pool, tables, pos, live, cfg: ModelConfig,
-                           rules: ShardingRules):
-    """One-token decode against a paged latent pool (NB, bt, C): the
-    counterpart of :func:`attn_layer_decode_paged` (block 0 the zero
-    block, dead slots rewrite what they read)."""
+def mla_layer_decode_paged(p, x, pool, i, tables, pos, live,
+                           cfg: ModelConfig, rules: ShardingRules):
+    """One-token decode against a paged latent pool (n, NB, bt, R), of
+    which this layer owns ``pool[i]``, a token's C latent values in the
+    first lanes of its row: the counterpart of
+    :func:`attn_layer_decode_paged` (one scatter and one gather into the
+    stacked leaf, block 0 the zero block, dead slots rewrite what they
+    read)."""
     B = x.shape[0]
-    NB, bt, C = pool.shape
+    bt, C = pool.shape[2], cfg.latent_dim
     W = tables.shape[1] * bt
     qa, rows = _mla_qkv(p, x, cfg, pos[:, None])
     with jax.named_scope("attn.kv_write"):
         blk = jnp.take_along_axis(tables, (pos // bt)[:, None], axis=1)[:, 0]
         off = pos % bt
-        new = jnp.where(live[:, None], rows[:, 0].astype(pool.dtype),
-                        pool[blk, off])
-        pool = pool.at[blk, off].set(new)
+        new = jnp.where(live[:, None],
+                        whole_rows(rows[:, 0].astype(pool.dtype), pool, 1),
+                        pool[i, blk, off])
+        pool = pool.at[i, blk, off].set(new)
     with jax.named_scope("attn.core"):
-        ctx = pool[tables].reshape(B, W, C)
+        ctx = _context(pool[i, tables], (B, W), (C,))
         mask = (jnp.arange(W)[None, :] <= pos[:, None])[:, None, :]
         o = _mla_attend(qa, ctx, mask, cfg)
     return _mla_out(p, x, o, cfg), pool
 
 
-def mla_layer_prefill_paged(p, x, pool, table_row, start, valid,
+def mla_layer_prefill_paged(p, x, pool, i, table_row, start, valid,
                             cfg: ModelConfig, rules: ShardingRules):
-    """One prefill chunk (B == 1) against the paged latent pool: the
-    counterpart of :func:`attn_layer_prefill_paged`."""
+    """One prefill chunk (B == 1) against the stacked paged latent pool
+    at layer ``i``: the counterpart of :func:`attn_layer_prefill_paged`."""
     _, c, _ = x.shape
-    NB, bt, C = pool.shape
+    bt, C = pool.shape[2], cfg.latent_dim
     W = table_row.shape[0] * bt
     positions = start + jnp.arange(c)
     qa, rows = _mla_qkv(p, x, cfg, positions[None, :])
@@ -631,9 +703,10 @@ def mla_layer_prefill_paged(p, x, pool, table_row, start, valid,
         rz = jnp.where(ok, rows[0], 0).astype(pool.dtype)
         nblk = c // bt
         bids = jax.lax.dynamic_slice(table_row, (start // bt,), (nblk,))
-        pool = pool.at[bids].set(rz.reshape(nblk, bt, C))
+        pool = pool.at[i, bids].set(
+            whole_rows(rz.reshape(nblk, bt, C), pool, 2))
     with jax.named_scope("attn.core"):
-        ctx = pool[table_row].reshape(1, W, C)
+        ctx = _context(pool[i, table_row], (1, W), (C,))
         mask = (jnp.arange(W)[None, :] <= positions[:, None])[None]
         o = _mla_attend(qa, ctx, mask, cfg)
     return _mla_out(p, x, o, cfg), pool

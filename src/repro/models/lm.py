@@ -141,8 +141,11 @@ def cache_defs(cfg: ModelConfig, batch: int, seq_len: int) -> dict:
 
 def pool_defs(cfg: ModelConfig, n_blocks: int, block_tokens: int) -> dict:
     """Paged-KV block pool defs: same tree shape as :func:`cache_defs` but
-    each ATTN leaf is (n_periods, n_blocks, block_tokens, Hkv, Dh) and
-    each MLA leaf ``{"c": (n, n_blocks, block_tokens, latent_dim)}`` — a
+    each ATTN leaf is ``{"k", "v": (n, n_blocks, block_tokens, *row)}``,
+    a token's Hkv x Dh keys or values in a row of
+    :func:`repro.models.layers.kv_row`, and each MLA leaf ``{"c": (n,
+    n_blocks, block_tokens, R)}``, its latent_dim values in the first of
+    R lanes (:func:`repro.models.layers.pool_row`) — a
     shared pool of fixed-size token blocks indexed by per-request block
     tables (block 0 is the reserved zero block).  Paged serving supports
     pure-attention caches only (no SSM/xattn state) and full attention
@@ -150,14 +153,17 @@ def pool_defs(cfg: ModelConfig, n_blocks: int, block_tokens: int) -> dict:
     if cfg.window:
         raise ValueError("paged KV supports full attention only "
                          f"(cfg.window={cfg.window})")
-    shp = (n_blocks, block_tokens, cfg.n_kv_heads, cfg.head_dim)
+
+    blocks = (n_blocks, block_tokens)
 
     def leaf(kind):
         if kind == ATTN:
-            return {"k": PV(shp, cfg.dtype, ("", "", "kv", ""), "zeros"),
-                    "v": PV(shp, cfg.dtype, ("", "", "kv", ""), "zeros")}
+            row = L.kv_row(cfg)
+            axes = ("", "") + ("kv", "")[:len(row)]
+            return {"k": PV(blocks + row, cfg.dtype, axes, "zeros"),
+                    "v": PV(blocks + row, cfg.dtype, axes, "zeros")}
         if kind == MLA:
-            return {"c": PV((n_blocks, block_tokens, cfg.latent_dim),
+            return {"c": PV(blocks + (L.pool_row(cfg.latent_dim),),
                             cfg.dtype, ("", "", ""), "zeros")}
         if kind in (XATTN, MAMBA):
             raise ValueError(
@@ -167,6 +173,21 @@ def pool_defs(cfg: ModelConfig, n_blocks: int, block_tokens: int) -> dict:
 
     return join_state(cfg, [_stack_defs(kinds, n, leaf)
                             for _, kinds, n in stacks(cfg)])
+
+
+def cache_into_pool(pool, cache, js, bids):
+    """Copy blocks ``js`` of a batch-1 dense cache (the tree of
+    :func:`cache_defs`, its sequence a whole number of the pool's blocks)
+    into pool blocks ``bids``, each token as a row of the pool's format
+    (:func:`pool_defs`)."""
+    def put(pool_leaf, cache_leaf):
+        n, _, bt = pool_leaf.shape[:3]
+        blocks = cache_leaf[:, 0].reshape(
+            (n, -1, bt) + cache_leaf.shape[3:])
+        return pool_leaf.at[:, bids].set(
+            L.whole_rows(blocks[:, js], pool_leaf, 3))
+
+    return jax.tree.map(put, pool, cache)
 
 
 # ---------------------------------------------------------------------------
@@ -465,31 +486,33 @@ EXPERT_STACKS = ("wg", "wi", "wo")
 
 def _paged_layers(params, x, pool, cfg: ModelConfig, rules: ShardingRules,
                   attend, count_rows=None):
-    """Scan every stack of layers over its share of the paged pool:
-    ``attend(kind, sp, x, pool_leaf) -> (x, pool_leaf)`` runs each
-    attention sublayer; MoE sublayers route through
-    :func:`repro.models.layers.moe_local`, on one device only.  Their
-    expert stacks stay out of the scan: each layer's grouped matmuls take
-    the whole stack and the layer's index, and read its experts in
-    place.  Returns (x, new pool, reached): with ``count_rows``, a mask
-    over x's rows, ``reached`` holds per MoE layer, in order, how many
-    experts those rows chose; else None."""
-    in_place = bool(cfg.n_experts)
-    if in_place and L.moe_mode(cfg, rules) != "local":
+    """Scan every stack of layers with its share of the paged pool in the
+    carry: ``attend(kind, sp, x, pool_leaf, i) -> (x, pool_leaf)`` runs
+    each attention sublayer on the stack's whole pool leaf and the layer's
+    index ``i``, so its rows are written in place (with the pool donated
+    to the program, no layer's pool is sliced, copied or written back).
+    MoE sublayers route through :func:`repro.models.layers.moe_local`, on
+    one device only.  Their expert stacks stay out of the scan: each
+    layer's grouped matmuls take the whole stack and ``i``, and read its
+    experts in place.  Returns (x, new pool, reached): with
+    ``count_rows``, a mask over x's rows, ``reached`` holds per MoE layer,
+    in order, how many experts those rows chose; else None."""
+    if cfg.n_experts and L.moe_mode(cfg, rules) != "local":
         raise ValueError("paged serving reads MoE experts in place on one "
                          f"device; MoE mode {L.moe_mode(cfg, rules)!r} "
                          "shards them")
 
-    def body(xc, pc, kinds, experts):
-        pp, cc, i = pc if in_place else (*pc, None)
+    def body(carry, xs, kinds, experts):
+        xc, cc = carry
+        pp, i = xs
         new_pool, reached = {}, []
         for li, layer in enumerate(kinds):
-            lpool = {}
+            lpool = dict(cc[f"l{li}"])
             for si, kind in enumerate(layer):
                 key = f"s{si}_{kind}"
                 sp = pp[f"l{li}"][key]
                 if kind in (ATTN, MLA):
-                    xc, lpool[key] = attend(kind, sp, xc, cc[f"l{li}"][key])
+                    xc, lpool[key] = attend(kind, sp, xc, lpool[key], i)
                 elif kind == MOE:
                     sp = dict(sp, **experts[f"l{li}"][key])
                     with jax.named_scope("mlp"):
@@ -504,30 +527,27 @@ def _paged_layers(params, x, pool, cfg: ModelConfig, rules: ShardingRules,
                     xc = _apply_slot(kind, sp, xc, cfg, rules, None, None)
             new_pool[f"l{li}"] = lpool
         if count_rows is None:
-            return xc, new_pool
-        return xc, (new_pool, jnp.stack(reached) if reached
-                    else jnp.zeros(0, jnp.int32))
+            return (xc, new_pool), None
+        return (xc, new_pool), (jnp.stack(reached) if reached
+                                else jnp.zeros(0, jnp.int32))
 
     parts, counts = [], []
     for (name, kinds, n), part in zip(stacks(cfg), split_state(cfg, pool)):
-        scanned, experts = params[name], {}
-        if in_place:
-            scanned = {lk: {key: {w: v for w, v in sp.items()
-                                  if not key.endswith(f"_{MOE}")
-                                  or w not in EXPERT_STACKS}
-                            for key, sp in slots.items()}
-                       for lk, slots in params[name].items()}
-            experts = {lk: {key: {w: sp[w] for w in EXPERT_STACKS}
-                            for key, sp in slots.items()
-                            if key.endswith(f"_{MOE}")}
-                       for lk, slots in params[name].items()}
-        xs = (scanned, part, jnp.arange(n)) if in_place else (scanned, part)
-        x, c = jax.lax.scan(
-            functools.partial(body, kinds=kinds, experts=experts), x, xs)
+        scanned = {lk: {key: {w: v for w, v in sp.items()
+                              if not key.endswith(f"_{MOE}")
+                              or w not in EXPERT_STACKS}
+                        for key, sp in slots.items()}
+                   for lk, slots in params[name].items()}
+        experts = {lk: {key: {w: sp[w] for w in EXPERT_STACKS}
+                        for key, sp in slots.items()
+                        if key.endswith(f"_{MOE}")}
+                   for lk, slots in params[name].items()}
+        (x, part), r = jax.lax.scan(
+            functools.partial(body, kinds=kinds, experts=experts),
+            (x, part), (scanned, jnp.arange(n)))
         if count_rows is not None:
-            c, r = c
             counts.append(r.reshape(-1))
-        parts.append(c)
+        parts.append(part)
     reached = jnp.concatenate(counts) if count_rows is not None else None
     return x, join_state(cfg, parts), reached
 
@@ -543,12 +563,12 @@ def decode_step_paged(params, token, pool, tables, pos, live,
     cache (zero block 0 ≡ unwritten dense rows)."""
     x = embed_tokens(params, token, cfg, rules)
 
-    def attend(kind, sp, xc, c):
+    def attend(kind, sp, xc, c, i):
         if kind == MLA:
-            xc, pc = L.mla_layer_decode_paged(sp, xc, c["c"], tables, pos,
+            xc, pc = L.mla_layer_decode_paged(sp, xc, c["c"], i, tables, pos,
                                               live, cfg, rules)
             return xc, {"c": pc}
-        xc, pk, pv = L.attn_layer_decode_paged(sp, xc, c["k"], c["v"],
+        xc, pk, pv = L.attn_layer_decode_paged(sp, xc, c["k"], c["v"], i,
                                                tables, pos, live, cfg, rules)
         return xc, {"k": pk, "v": pv}
 
@@ -571,13 +591,13 @@ def prefill_chunk(params, tokens, pool, table_row, start, valid,
     not once per prompt length."""
     x = embed_tokens(params, tokens, cfg, rules)
 
-    def attend(kind, sp, xc, c):
+    def attend(kind, sp, xc, c, i):
         if kind == MLA:
-            xc, pc = L.mla_layer_prefill_paged(sp, xc, c["c"], table_row,
+            xc, pc = L.mla_layer_prefill_paged(sp, xc, c["c"], i, table_row,
                                                start, valid, cfg, rules)
             return xc, {"c": pc}
         xc, pk, pv = L.attn_layer_prefill_paged(
-            sp, xc, c["k"], c["v"], table_row, start, valid, cfg, rules)
+            sp, xc, c["k"], c["v"], i, table_row, start, valid, cfg, rules)
         return xc, {"k": pk, "v": pv}
 
     count = (jnp.arange(tokens.shape[1]) < valid)[None] \

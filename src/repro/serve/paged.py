@@ -69,7 +69,11 @@ def kv_token_bytes(cfg: ModelConfig) -> int:
     """Cache bytes per token across the whole model, by kind of
     attention: k and v of every attention sublayer instance, the latent
     row (``latent_dim`` values) of every latent-attention one — the unit
-    both engines' resident-bytes metrics are denominated in."""
+    both engines' resident-bytes metrics are denominated in.  It counts
+    the values a token's cache holds, not the lanes past them that pad a
+    paged pool row to whole vector registers (:func:`lm.pool_defs`: the
+    latent's 576 values in 640 lanes), so the dense and paged engines
+    compare on the same data."""
     per = {ATTN: 2 * cfg.n_kv_heads * cfg.head_dim, MLA: cfg.latent_dim}
     values = sum(per.get(kind, 0) * n for _, kinds, n in lm.stacks(cfg)
                  for layer in kinds for kind in layer)
@@ -267,9 +271,11 @@ class PagedServingEngine:
             return lm.prefill_chunk(p, t, pool, row, start, valid, cfg,
                                     rules)
 
+        # the pool is donated: each program writes its new rows into the
+        # buffers it was given, and the engine keeps only what it returns
         self._prefill = jax.jit(prefill)
-        self._step = jax.jit(decode_step_paged)
-        self._chunk = jax.jit(prefill_chunk)
+        self._step = jax.jit(decode_step_paged, donate_argnums=(2,))
+        self._chunk = jax.jit(prefill_chunk, donate_argnums=(2,))
 
     # -- observability -------------------------------------------------------
     @property
@@ -388,7 +394,6 @@ class PagedServingEngine:
         dense engine (identical first token and cache values), then
         scatter the newly-owned blocks of the dense cache into the pool —
         shared blocks already hold identical content and are skipped."""
-        bt = self.scfg.block_tokens
         toks = jnp.asarray(req.prompt, jnp.int32)[None, :]
         req.t_prefill_start = now()
         cache, logits = self._prefill(self.params, toks)
@@ -397,14 +402,7 @@ class PagedServingEngine:
         if new_bids:
             js = jnp.asarray([j for j, _ in new_bids])
             bids = jnp.asarray([b for _, b in new_bids])
-
-            def put(pool_leaf, cache_leaf):
-                P = pool_leaf.shape[0]
-                blocks = cache_leaf[:, 0].reshape(
-                    (P, self.max_blocks, bt) + pool_leaf.shape[3:])
-                return pool_leaf.at[:, bids].set(blocks[:, js])
-
-            self.pool = jax.tree.map(put, self.pool, cache)
+            self.pool = lm.cache_into_pool(self.pool, cache, js, bids)
         self.slot_state[slot] = DECODE
         self.slot_pos[slot] = len(req.prompt)
 

@@ -177,7 +177,7 @@ def test_block_sizing_respects_vreg_budget():
                                               block_tokens=64, n_blocks=4,
                                               chunk=128))
     pk = eng.pool["l0"]["s0_attn"]["k"]
-    assert pk.shape == (1, 5, 64, cfg.n_kv_heads, cfg.head_dim)
+    assert pk.shape == (1, 5, 64, cfg.n_kv_heads * cfg.head_dim)
     assert 64 * cfg.n_kv_heads * cfg.head_dim * 2 == 6 * 65536
     with pytest.raises(ValueError, match="multiple of"):
         PagedServingEngine(cfg, None, rules,
@@ -274,3 +274,196 @@ def test_engine_shutdown_refuses_inflight_then_catches_leak():
         eng.shutdown()
     eng.alloc.release(leaked)
     eng.shutdown()
+
+
+# ---------------------------------------------------------------------------
+# the pool updated in place: a scan carry, donated to the engine's programs
+# ---------------------------------------------------------------------------
+
+def _paged_setup(arch: str):
+    """A paged model's (cfg, rules, params): the 1-layer phi3 cut at its
+    published widths, a tiny config by arch name, or ``wide-heads``, the
+    tiny llama3 with heads of 128 (pool rows keep their heads)."""
+    import dataclasses
+    import jax
+    from repro.configs import get_config, get_smoke_config
+    from repro.models import lm
+    from repro.parallel.sharding import default_rules, init_params
+    if arch == "phi3-mini-3.8b":
+        cfg = dataclasses.replace(get_config(arch), n_layers=1)
+    elif arch == "wide-heads":
+        cfg = dataclasses.replace(get_smoke_config("llama3-8b"), d_head=128)
+    else:
+        cfg = get_smoke_config(arch)
+    params = init_params(lm.model_defs(cfg), jax.random.key(0))
+    return cfg, default_rules(None), params
+
+
+def _zero_pool(cfg, n_blocks: int, bt: int):
+    import jax
+    import jax.numpy as jnp
+    from repro.models import lm
+    return jax.tree.map(lambda pv: jnp.zeros(pv.shape, pv.dtype),
+                        lm.pool_defs(cfg, n_blocks, bt),
+                        is_leaf=lambda x: hasattr(x, "logical"))
+
+
+def _scan_eqns(jaxpr):
+    """Every scan equation of a jaxpr, its sub-jaxprs included."""
+    import jax
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "scan":
+            yield eqn
+        for v in eqn.params.values():
+            for sub in (v if isinstance(v, (tuple, list)) else (v,)):
+                if isinstance(sub, jax.extend.core.ClosedJaxpr):
+                    yield from _scan_eqns(sub.jaxpr)
+                elif isinstance(sub, jax.extend.core.Jaxpr):
+                    yield from _scan_eqns(sub)
+
+
+# the 1-layer phi3 cut at its published widths (rows of 3,072 lanes) and a
+# tiny MLA + MoE model (rows padded to a whole 128-lane register)
+PAGED_ARCHS = ["phi3-mini-3.8b", "deepseek-v2-lite"]
+
+
+@pytest.mark.parametrize("arch", PAGED_ARCHS)
+def test_engine_donates_the_pool_to_its_programs(arch):
+    """One engine step with a live slot consumes the pool it was given
+    (each leaf is deleted: the chunk and decode programs wrote into its
+    buffers), and both compiled programs alias the pool to an output."""
+    import jax
+    import jax.numpy as jnp
+    from repro.serve import PagedServeConfig, PagedServingEngine
+    cfg, rules, params = _paged_setup(arch)
+    eng = PagedServingEngine(cfg, params, rules,
+                             PagedServeConfig(max_batch=2, max_seq=64,
+                                              block_tokens=16, n_blocks=6,
+                                              chunk=32))
+    eng.submit(Request(rid=0, prompt=np.arange(1, 21, dtype=np.int32),
+                       max_new_tokens=4))
+    given = jax.tree.leaves(eng.pool)
+    assert eng.step()
+    assert eng.prefill_chunks == 1 and eng.decode_steps == 1
+    assert all(leaf.is_deleted() for leaf in given)
+    assert not any(leaf.is_deleted() for leaf in jax.tree.leaves(eng.pool))
+    i32 = jnp.int32
+    step = eng._step.lower(params, jnp.zeros((2, 1), i32), eng.pool,
+                           jnp.asarray(eng.tables), jnp.zeros(2, i32),
+                           jnp.zeros(2, bool)).compile()
+    chunk = eng._chunk.lower(params, jnp.zeros((1, 32), i32), eng.pool,
+                             jnp.asarray(eng.tables[0]), i32(0),
+                             i32(1)).compile()
+    for compiled in (step, chunk):
+        assert "input_output_alias" in compiled.as_text().splitlines()[0]
+    eng.run()
+    eng.shutdown()
+
+
+@pytest.mark.parametrize("arch", ["llama3-8b", "wide-heads",
+                                  "deepseek-v2-lite"])
+def test_paged_programs_carry_the_pool_through_the_layer_scan(arch):
+    """Neither paged program scans the pool as a stacked input nor
+    returns it as a stacked output: the pool is in the scan's carry, so
+    each layer writes its rows into the whole stack in place."""
+    import functools
+    import jax
+    import jax.numpy as jnp
+    from repro.models import lm
+    cfg, rules, params = _paged_setup(arch)
+    B, nb, bt, c = 2, 4, 8, 16
+    pool = _zero_pool(cfg, 2 * nb + 1, bt)
+    leaves = {leaf.shape for leaf in jax.tree.leaves(pool)}
+    i32 = jnp.int32
+    programs = {
+        "decode": jax.make_jaxpr(functools.partial(
+            lm.decode_step_paged, cfg=cfg, rules=rules))(
+                params, jnp.zeros((B, 1), i32), pool,
+                jnp.zeros((B, nb), i32), jnp.zeros(B, i32),
+                jnp.ones(B, bool)),
+        "chunk": jax.make_jaxpr(functools.partial(
+            lm.prefill_chunk, cfg=cfg, rules=rules))(
+                params, jnp.zeros((1, c), i32), pool, jnp.zeros(nb, i32),
+                i32(0), i32(c)),
+    }
+    for name, closed in programs.items():
+        scans = list(_scan_eqns(closed.jaxpr))
+        assert scans, name
+        carried = 0
+        for eqn in scans:
+            k, n = eqn.params["num_consts"], eqn.params["num_carry"]
+            xs = [v.aval.shape for v in eqn.invars[k + n:]]
+            ys = [v.aval.shape for v in eqn.outvars[n:]]
+            assert not leaves & set(xs), (name, xs)
+            assert not leaves & set(ys), (name, ys)
+            carried += sum(v.aval.shape in leaves for v in eqn.outvars[:n])
+        assert carried == len(jax.tree.leaves(pool)), name
+
+
+@pytest.mark.parametrize("arch", ["llama3-8b", "wide-heads",
+                                  "deepseek-v2-lite"])
+def test_decode_writes_each_live_row_at_its_block_and_offset(arch):
+    """A few donated decode steps over a batch with a dead slot: each
+    live slot's rows land at (its table's block, offset) of every layer,
+    equal to the rows the dense decode writes at those positions, the
+    logits agree, and block 0 and the lanes past each row stay zero.
+    Equal bit for bit without experts; with them, to float32 rounding,
+    since the dense step runs each layer's expert slice and the paged
+    one the whole stack at the layer's index."""
+    import functools
+    import jax
+    import jax.numpy as jnp
+    from repro.configs.base import ATTN
+    from repro.models import lm
+    cfg, rules, params = _paged_setup(arch)
+    B, S, bt = 3, 32, 8
+    nb = S // bt
+    pool = _zero_pool(cfg, 2 * nb + 1, bt)
+    cache = jax.tree.map(lambda pv: jnp.zeros(pv.shape, pv.dtype),
+                         lm.cache_defs(cfg, B, S),
+                         is_leaf=lambda x: hasattr(x, "logical"))
+    tables = np.zeros((B, nb), np.int32)
+    tables[0] = np.arange(1, nb + 1)
+    tables[2] = np.arange(nb + 1, 2 * nb + 1)      # slot 1 is dead
+    live = np.array([True, False, True])
+    start = np.array([0, 0, 5], np.int32)           # slot 2 crosses a block
+    paged = jax.jit(functools.partial(lm.decode_step_paged, cfg=cfg,
+                                      rules=rules), donate_argnums=(2,))
+    dense = jax.jit(functools.partial(lm.decode_step, cfg=cfg, rules=rules))
+    tol = 1e-5 if cfg.n_experts else 0.0
+    rng = np.random.default_rng(0)
+    steps = 6
+    for t in range(steps):
+        tok = jnp.asarray(rng.integers(1, cfg.vocab_size, (B, 1)), jnp.int32)
+        pos = jnp.asarray(start + t * live)
+        lg_p, pool = paged(params, tok, pool, jnp.asarray(tables), pos,
+                           jnp.asarray(live))
+        lg_d, cache = dense(params, tok, cache, pos)
+        np.testing.assert_allclose(np.asarray(lg_p)[live],
+                                   np.asarray(lg_d)[live], rtol=tol, atol=tol)
+    written = [(b, p) for b in np.flatnonzero(live)
+               for p in range(start[b], start[b] + steps)]
+    for name, kinds, _ in lm.stacks(cfg):
+        parts = (pool[name], cache[name]) if cfg.first_dense \
+            else (pool, cache)
+        for lk, layer in enumerate(kinds):
+            for si, kind in enumerate(layer):
+                key = f"s{si}_{kind}"
+                if key not in parts[0][f"l{lk}"]:
+                    continue
+                for leaf, pl in parts[0][f"l{lk}"][key].items():
+                    assert pl.shape[-1] % 128 == 0
+                    pl = np.asarray(pl, np.float32)
+                    pl = pl.reshape(pl.shape[:3] + (-1,))
+                    dl = np.asarray(parts[1][f"l{lk}"][key][leaf],
+                                    np.float32)
+                    w = dl[0, 0, 0].size
+                    assert w <= pl.shape[-1]
+                    assert not pl[:, 0].any(), "zero block written"
+                    assert not pl[..., w:].any(), "lanes past a row"
+                    for b, p in written:
+                        np.testing.assert_allclose(
+                            pl[:, tables[b, p // bt], p % bt, :w],
+                            dl[:, b, p].reshape(len(pl), w),
+                            rtol=tol, atol=tol)
+                    assert (kind == ATTN) == (leaf in ("k", "v"))

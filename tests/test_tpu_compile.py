@@ -11,6 +11,7 @@ is off around these compiles — an entry written for a described chip
 cannot be read back without one.
 """
 import dataclasses
+import math
 import re
 
 import jax
@@ -249,6 +250,85 @@ def test_two_layer_paged_decode_reads_weights_in_place(one_chip, on_tpu,
     assert outs, "no instruction found in the compiled program"
     assert not outs & _weights(cfg)
     assert _no_projection_kernel(compiled, cfg)
+
+
+def _copies(compiled) -> list[tuple[int, ...]]:
+    """Output dims of every copy outside fusions."""
+    return [tuple(int(d) for d in m.group(1).split(",") if d)
+            for m in re.finditer(r"^\s*(?:ROOT\s+)?%?[\w.\-]+\s*=\s*\w+"
+                                 r"\[([\d,]*)\]\S*\s+copy\(",
+                                 compiled.as_text(), re.M)]
+
+
+def _pool_writers(compiled, shapes) -> set[str]:
+    """Each instruction outside fusions that outputs an array of one of
+    ``shapes``, as its opcode, a fusion as the opcodes of what it calls
+    (buffers passed on whole are left out)."""
+    text = compiled.as_text()
+    out = set()
+    for m in re.finditer(r"^\s*(?:ROOT\s+)?%?[\w.\-]+\s*=\s*\w+\[([\d,]*)\]"
+                         r"\S*\s+([a-z\-]+)\((.*)$", text, re.M):
+        dims, op = tuple(int(d) for d in m.group(1).split(",") if d), \
+            m.group(2)
+        if dims not in shapes or op in ("parameter", "get-tuple-element",
+                                        "tuple", "bitcast"):
+            continue
+        if op == "fusion":
+            body = re.search(r"\n%" + re.escape(
+                re.search(r"calls=%?([\w.\-]+)", m.group(3)).group(1))
+                + r" .*?\n}", text, re.S).group(0)
+            op = "fusion of " + ",".join(sorted(
+                set(re.findall(r"\s([a-z\-]+)\(", body)) - {"parameter"}))
+        out.add(op)
+    return out
+
+
+@pytest.mark.parametrize("arch,layers,B,S,pool_tokens",
+                         [("phi3-mini-3.8b", 2, 8, 1024, 6144),
+                          ("deepseek-7b", 2, 8, 2048, 10240),
+                          ("deepseek-v2-lite", 3, 32, 5120, 163840)],
+                         ids=["phi3", "deepseek", "deepseek_v2_lite"])
+def test_paged_programs_update_the_pool_in_place(one_chip, on_tpu, arch,
+                                                  layers, B, S, pool_tokens):
+    """The decode and chunk programs as the engine jits them, the pool
+    donated, over a benchmark cell's whole pool: the pool is aliased to
+    an output, and the one kind of instruction that outputs a pool leaf
+    or one layer of one is the scatter of the new rows into it.  Rows of
+    phi3's 32 x 96 keys and of the 576-value latent are what the TPU
+    would keep block axis minor-most, copied into row order and back by
+    every program, were they not padded to whole 128-lane registers.
+    Nor is the gathered context copied into attention's layout: deepseek's
+    heads of 128 stay heads in the pool (``layers.kv_row``)."""
+    cfg = dataclasses.replace(get_config(arch), n_layers=layers)
+    rules = default_rules(None)
+    bt, chunk = 16, 512
+    pool = _tree_sds(lm.pool_defs(cfg, pool_tokens // bt + 1, bt), one_chip)
+    shapes = {leaf.shape for leaf in jax.tree.leaves(pool)}
+    shapes |= {shape[1:] for shape in shapes}
+    i32 = jnp.int32
+    step = jax.jit(lambda p, t, pool, tab, pos, lv: lm.decode_step_paged(
+        p, t, pool, tab, pos, lv, cfg, rules), donate_argnums=(2,))
+    fill = jax.jit(lambda p, t, pool, row, start, valid: lm.prefill_chunk(
+        p, t, pool, row, start, valid, cfg, rules), donate_argnums=(2,))
+    params = _tree_sds(lm.model_defs(cfg), one_chip)
+    programs = [
+        step.lower(params, _sds((B, 1), i32, one_chip), pool,
+                   _sds((B, S // bt), i32, one_chip),
+                   _sds((B,), i32, one_chip),
+                   _sds((B,), jnp.bool_, one_chip)).compile(),
+        fill.lower(params, _sds((1, chunk), i32, one_chip), pool,
+                   _sds((S // bt,), i32, one_chip), _sds((), i32, one_chip),
+                   _sds((), i32, one_chip)).compile()]
+    for compiled in programs:
+        assert "input_output_alias" in compiled.as_text().splitlines()[0]
+        writers = _pool_writers(compiled, shapes)
+        assert writers and all("scatter" in op and "copy" not in op
+                               and "dynamic-slice" not in op
+                               for op in writers), writers
+    if not cfg.kv_lora_rank:
+        context = B * S * cfg.n_kv_heads * cfg.head_dim
+        assert all(math.prod(dims) != context
+                   for dims in _copies(programs[0])), _copies(programs[0])
 
 
 def test_one_layer_mesh_decode_compiles_for_v5e_2x2(topo, on_tpu):
