@@ -10,10 +10,8 @@ the AraXL interconnects as described in DESIGN.md §2.
 """
 from __future__ import annotations
 
-import dataclasses
-import functools
 import math
-from typing import Any, NamedTuple
+from typing import Any, Callable, NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -114,7 +112,7 @@ def silu(x):
 # attention
 # ---------------------------------------------------------------------------
 
-def attn_defs(cfg: ModelConfig, cross: bool = False) -> dict:
+def attn_defs(cfg: ModelConfig) -> dict:
     d, hd = cfg.d_model, cfg.head_dim
     dt = cfg.dtype
     return {
@@ -222,24 +220,40 @@ def attn_layer(p, x, cfg: ModelConfig, rules: ShardingRules, positions,
         return x + o.astype(x.dtype)
 
 
-class AttnCache(NamedTuple):
-    k: jax.Array          # (B, W, Hkv, Dh) — pre-rotated keys
-    v: jax.Array
-
-
 def attn_cache_len(cfg: ModelConfig, seq_len: int) -> int:
     return min(seq_len, cfg.window) if cfg.window else seq_len
 
 
-def attn_cache_defs(cfg: ModelConfig, batch: int, seq_len: int) -> AttnCache:
+def attn_cache_defs(cfg: ModelConfig, batch: int, seq_len: int) -> dict:
+    """{"k", "v": (B, W, Hkv, Dh)}, the keys pre-rotated."""
     W = attn_cache_len(cfg, seq_len)
     shp = (batch, W, cfg.n_kv_heads, cfg.head_dim)
-    return AttnCache(
-        PV(shp, cfg.dtype, ("batch", "cache_seq", "kv", ""), "zeros"),
-        PV(shp, cfg.dtype, ("batch", "cache_seq", "kv", ""), "zeros"))
+    return {n: PV(shp, cfg.dtype, ("batch", "cache_seq", "kv", ""), "zeros")
+            for n in ("k", "v")}
 
 
-def attn_layer_decode(p, x, cache: AttnCache, pos, cfg: ModelConfig,
+def _scores(qg, k):
+    """Float32 scores of grouped queries qg (B, S, Hkv, G, hd) against
+    keys k (B, T, Hkv, hd), over sqrt(hd) -> (B, Hkv, G, S, T)."""
+    return jnp.einsum("bqhgd,bthd->bhgqt", qg.astype(jnp.float32),
+                      k.astype(jnp.float32)) / math.sqrt(qg.shape[-1])
+
+
+def _attend(qg, k, v, mask=None):
+    """Softmax attention of grouped queries qg (B, S, Hkv, G, hd) over
+    keys and values k, v (B, T, Hkv, hd) -> (B, S, Hkv, G, hd) float32:
+    the one MHA core of dense decode, paged decode, the paged chunk and
+    cross-attention decode, so that their streams agree bit for bit.
+    ``mask()``, called once the scores are taken, gives the entries each
+    query sees, broadcast to the scores' rank."""
+    s = _scores(qg, k)
+    if mask is not None:
+        s = jnp.where(mask(), s, -1e30)
+    pr = jax.nn.softmax(s, axis=-1)
+    return jnp.einsum("bhgqt,bthd->bqhgd", pr, v.astype(jnp.float32))
+
+
+def attn_layer_decode(p, x, cache: dict, pos, cfg: ModelConfig,
                       rules: ShardingRules):
     """One-token step. pos: scalar int32 (shared position) or (B,) int32
     (per-slot true positions — the serving engine's continuous batch, where
@@ -251,7 +265,7 @@ def attn_layer_decode(p, x, cache: AttnCache, pos, cfg: ModelConfig,
     bit-identical to the scalar path (same writes, same masks, same
     reduction order)."""
     B, S1, d = x.shape                      # S1 == 1
-    W = cache.k.shape[1]
+    W = cache["k"].shape[1]
     hd = cfg.head_dim
     pos = jnp.asarray(pos, jnp.int32)
     per_slot = pos.ndim == 1
@@ -271,38 +285,26 @@ def attn_layer_decode(p, x, cache: AttnCache, pos, cfg: ModelConfig,
         if per_slot:
             # scatter each batch row at its own ring slot (rows distinct
             # by construction: one write per batch element)
-            ck = cache.k.at[jnp.arange(B), slot].set(
-                k[:, 0].astype(cache.k.dtype))
-            cv = cache.v.at[jnp.arange(B), slot].set(
-                v[:, 0].astype(cache.v.dtype))
+            ck = cache["k"].at[jnp.arange(B), slot].set(
+                k[:, 0].astype(cache["k"].dtype))
+            cv = cache["v"].at[jnp.arange(B), slot].set(
+                v[:, 0].astype(cache["v"].dtype))
         else:
-            ck = jax.lax.dynamic_update_slice(cache.k, k, (0, slot, 0, 0))
-            cv = jax.lax.dynamic_update_slice(cache.v, v, (0, slot, 0, 0))
+            ck = jax.lax.dynamic_update_slice(cache["k"], k, (0, slot, 0, 0))
+            cv = jax.lax.dynamic_update_slice(cache["v"], v, (0, slot, 0, 0))
         ck = constraint(ck, rules, "batch", "cache_seq", "kv", None)
         cv = constraint(cv, rules, "batch", "cache_seq", "kv", None)
 
-    def _scores_out(qg, ckb, cvb, idx, pos_):
-        """Local masked scores + (m, l, o) partials for index slice idx.
-
-        pos_ may be a scalar (mask over (W,)) or a (B,) vector (per-slot
-        mask over (B, W))."""
+    def mask(idx, pos_):
+        """Which cache entries ``idx`` each query sees, at the scores'
+        rank; pos_ a scalar (one mask) or (B,) (one per slot)."""
         pos_c = pos_[:, None] if pos_.ndim == 1 else pos_
-        if cfg.window:
-            k_pos = pos_c - ((pos_c - idx) % W)  # newest position ≡ i (mod W)
-            valid = k_pos >= 0
+        if cfg.window:                      # newest position ≡ idx (mod W)
+            seen = pos_c - (pos_c - idx) % W >= 0
         else:
-            k_pos = idx
-            valid = k_pos <= pos_c
-        s = jnp.einsum("bqhgd,bthd->bhgqt", qg.astype(jnp.float32),
-                       ckb.astype(jnp.float32)) / math.sqrt(hd)
-        mask = valid & (k_pos <= pos_c)
-        if cfg.window:
-            mask &= (pos_c - k_pos) < cfg.window
-        if mask.ndim == 2:                  # (B, W) per-slot mask
-            s = jnp.where(mask[:, None, None, None, :], s, -1e30)
-        else:
-            s = jnp.where(mask[None, None, None, None, :], s, -1e30)
-        return s, cvb.astype(jnp.float32)
+            seen = idx <= pos_c
+        return seen[:, None, None, None, :] if seen.ndim == 2 \
+            else seen[None, None, None, None, :]
 
     G = cfg.n_heads // cfg.n_kv_heads
     qg = q.reshape(B, S1, cfg.n_kv_heads, G, hd)
@@ -326,13 +328,13 @@ def attn_layer_decode(p, x, cache: AttnCache, pos, cfg: ModelConfig,
             cv_new = jnp.where(
                 inrange,
                 jax.lax.dynamic_update_slice(cvb, vb, (0, ls, 0, 0)), cvb)
-            idx = base + jnp.arange(W_loc)
-            s, cvf = _scores_out(qg_, ck_new, cv_new, idx, pos_)
+            s = jnp.where(mask(base + jnp.arange(W_loc), pos_),
+                          _scores(qg_, ck_new), -1e30)
             m = jax.lax.pmax(jnp.max(s, axis=-1, keepdims=True), "model")
             pr = jnp.exp(s - m)
             l = jax.lax.psum(jnp.sum(pr, axis=-1, keepdims=True), "model")
-            o = jax.lax.psum(
-                jnp.einsum("bhgqt,bthd->bqhgd", pr, cvf), "model")
+            o = jax.lax.psum(jnp.einsum("bhgqt,bthd->bqhgd", pr,
+                                        cv_new.astype(jnp.float32)), "model")
             ln = jnp.maximum(l, 1e-20).squeeze(-1).transpose(0, 3, 1, 2)
             return o / ln[..., None], ck_new, cv_new
 
@@ -342,15 +344,13 @@ def attn_layer_decode(p, x, cache: AttnCache, pos, cfg: ModelConfig,
             body, mesh=mesh,
             in_specs=(bq, cspec, cspec, bk, bk, P()),
             out_specs=(bq, cspec, cspec))(
-                qg, cache.k, cache.v, k.astype(cache.k.dtype),
-                v.astype(cache.v.dtype), jnp.asarray(pos, jnp.int32))
+                qg, cache["k"], cache["v"], k.astype(cache["k"].dtype),
+                v.astype(cache["v"].dtype), jnp.asarray(pos, jnp.int32))
     else:
-        s, cvf = _scores_out(qg, ck, cv, jnp.arange(W), pos)
-        pr = jax.nn.softmax(s, axis=-1)
-        o = jnp.einsum("bhgqt,bthd->bqhgd", pr, cvf)
+        o = _attend(qg, ck, cv, lambda: mask(jnp.arange(W), pos))
     o = kops.dense(o.reshape(B, S1, cfg.n_heads * hd).astype(x.dtype),
                    p["wo"])
-    return x + o.astype(x.dtype), AttnCache(ck, cv)
+    return x + o.astype(x.dtype), {"k": ck, "v": cv}
 
 
 def attn_layer_prefill(p, x, cfg: ModelConfig, rules, positions, cache_len):
@@ -372,7 +372,7 @@ def attn_layer_prefill(p, x, cfg: ModelConfig, rules, positions, cache_len):
             ck = jnp.roll(tail_k, shift=roll, axis=1)
             cv = jnp.roll(tail_v, shift=roll, axis=1)
     with jax.named_scope("attn.out"):
-        return x + o.astype(x.dtype), AttnCache(ck, cv)
+        return x + o.astype(x.dtype), {"k": ck, "v": cv}
 
 
 # -- paged attention (block-table KV pool) -----------------------------------
@@ -382,8 +382,8 @@ def attn_layer_prefill(p, x, cfg: ModelConfig, rules, positions, cache_len):
 # attention gathers through the table.  Block 0 is a permanent zero block —
 # unallocated table entries gather exact zeros, which is what the dense
 # cache's unwritten rows hold, so paged decode is bit-identical to the
-# dense engine.  Full attention only (no SWA ring) — the paged engine
-# rejects windowed configs.
+# dense engine.  Full attention only (no SWA ring) — ``lm.pool_defs``
+# refuses windowed configs.
 #
 # Each pool leaf is (n, NB, bt, *row): a stack's n layers, and in each
 # block bt token rows, each a token's keys (or values, or latent) in
@@ -416,6 +416,14 @@ def kv_row(cfg: ModelConfig) -> tuple[int, ...]:
     return (pool_row(cfg.n_kv_heads * cfg.head_dim),)
 
 
+def attn_pool_defs(cfg: ModelConfig, blocks: tuple[int, int]) -> dict:
+    """{"k", "v": (n_blocks, block_tokens, *row)}: a token's Hkv x Dh
+    keys or values in a row of :func:`kv_row`."""
+    row = kv_row(cfg)
+    axes = ("", "") + ("kv", "")[:len(row)]
+    return {n: PV(blocks + row, cfg.dtype, axes, "zeros") for n in ("k", "v")}
+
+
 def whole_rows(x, pool, lead: int):
     """Values x (*lead, ...) as whole rows of the pool leaf ``pool``
     (n, NB, bt, *row): (*lead, *row), zeros in the lanes past x's."""
@@ -438,12 +446,12 @@ def _context(rows, lead: tuple[int, ...], values: tuple[int, ...]):
     return rows.reshape(lead + values)
 
 
-def attn_layer_decode_paged(p, x, pk, pv, i, tables, pos, live,
+def attn_layer_decode_paged(p, x, leaf, i, tables, pos, live,
                             cfg: ModelConfig, rules: ShardingRules):
     """One-token decode against a block-table paged KV pool.
 
-    pk/pv (n, NB, bt, *row) — the stack's shared block pool, of which
-    this layer owns ``pk[i]``, a token's Hkv x Dh keys in its row
+    leaf {"k", "v": (n, NB, bt, *row)} — the stack's shared block pool,
+    of which this layer owns ``k[i]``, a token's Hkv x Dh keys in its row
     (:func:`kv_row`; block 0 is the reserved zero block, never written by
     a live slot); tables (B, max_blocks) int32; pos (B,) per-slot
     positions; live (B,) bool.  The new rows go in with one scatter into
@@ -453,9 +461,10 @@ def attn_layer_decode_paged(p, x, pk, pv, i, tables, pos, live,
     zero block's current value) so the batched step stays shape-stable.
     The gathered view ``_context(pk[i, tables], ...)`` is elementwise
     identical to the dense cache rows, and the math below is the same
-    expression as :func:`attn_layer_decode`'s vector-pos path —
+    attention core as :func:`attn_layer_decode`'s (:func:`_attend`) —
     bit-identical streams."""
     B, S1, d = x.shape                      # S1 == 1
+    pk, pv = leaf["k"], leaf["v"]
     bt = pk.shape[2]
     Hkv, hd = cfg.n_kv_heads, cfg.head_dim
     W = tables.shape[1] * bt
@@ -480,24 +489,20 @@ def attn_layer_decode_paged(p, x, pk, pv, i, tables, pos, live,
         G = cfg.n_heads // cfg.n_kv_heads
         qg = q.reshape(B, S1, Hkv, G, hd)
         idx = jnp.arange(W)
-        s = jnp.einsum("bqhgd,bthd->bhgqt", qg.astype(jnp.float32),
-                       ck.astype(jnp.float32)) / math.sqrt(hd)
-        mask = idx <= pos[:, None]                     # (B, W) causal
-        s = jnp.where(mask[:, None, None, None, :], s, -1e30)
-        pr = jax.nn.softmax(s, axis=-1)
-        o = jnp.einsum("bhgqt,bthd->bqhgd", pr, cv.astype(jnp.float32))
+        o = _attend(qg, ck, cv,                        # (B, W) causal
+                    lambda: (idx <= pos[:, None])[:, None, None, None, :])
     with jax.named_scope("attn.out"):
         o = kops.dense(o.reshape(B, S1, cfg.n_heads * hd).astype(x.dtype),
                        p["wo"])
-        return x + o.astype(x.dtype), pk, pv
+        return x + o.astype(x.dtype), {"k": pk, "v": pv}
 
 
-def attn_layer_prefill_paged(p, x, pk, pv, i, table_row, start, valid,
+def attn_layer_prefill_paged(p, x, leaf, i, table_row, start, valid,
                              cfg: ModelConfig, rules: ShardingRules):
     """One prefill *chunk* (B == 1) against the paged pool.
 
     x (1, c, d) is the embedded chunk, padded to the fixed chunk length c;
-    pk/pv the stacked pool leaves and ``i`` this layer's index in them, as
+    leaf the stacked pool leaves and ``i`` this layer's index in them, as
     in :func:`attn_layer_decode_paged`; ``valid`` counts real tokens,
     ``start`` is the chunk's base position (a multiple of the block
     size).  The chunk's K/V are scattered whole blocks at a time into the
@@ -507,6 +512,7 @@ def attn_layer_prefill_paged(p, x, pk, pv, i, table_row, start, valid,
     gathered view — earlier chunks' blocks are already resident, which is
     what makes chunked prefill exact."""
     B, c, d = x.shape                       # B == 1
+    pk, pv = leaf["k"], leaf["v"]
     bt = pk.shape[2]
     Hkv, hd = cfg.n_kv_heads, cfg.head_dim
     W = table_row.shape[0] * bt
@@ -530,15 +536,11 @@ def attn_layer_prefill_paged(p, x, pk, pv, i, table_row, start, valid,
         G = cfg.n_heads // cfg.n_kv_heads
         qg = q.reshape(B, c, Hkv, G, hd)
         mask = jnp.arange(W)[None, :] <= positions[:, None]  # (c, W) causal
-        s = jnp.einsum("bqhgd,bthd->bhgqt", qg.astype(jnp.float32),
-                       ck.astype(jnp.float32)) / math.sqrt(hd)
-        s = jnp.where(mask[None, None, None], s, -1e30)
-        pr = jax.nn.softmax(s, axis=-1)
-        o = jnp.einsum("bhgqt,bthd->bqhgd", pr, cv.astype(jnp.float32))
+        o = _attend(qg, ck, cv, lambda: mask[None, None, None])
     with jax.named_scope("attn.out"):
         o = kops.dense(o.reshape(B, c, cfg.n_heads * hd).astype(x.dtype),
                        p["wo"])
-        return x + o.astype(x.dtype), pk, pv
+        return x + o.astype(x.dtype), {"k": pk, "v": pv}
 
 
 # -- multi-head latent attention (DeepSeek-V2) -------------------------------
@@ -635,6 +637,13 @@ def mla_cache_defs(cfg: ModelConfig, batch: int, seq_len: int) -> dict:
                     ("batch", "cache_seq", ""), "zeros")}
 
 
+def mla_pool_defs(cfg: ModelConfig, blocks: tuple[int, int]) -> dict:
+    """{"c": (n_blocks, block_tokens, R)}: a token's latent_dim values in
+    the first of R lanes (:func:`pool_row`)."""
+    return {"c": PV(blocks + (pool_row(cfg.latent_dim),), cfg.dtype,
+                    ("", "", ""), "zeros")}
+
+
 def mla_layer_prefill(p, x, cfg: ModelConfig, rules, positions,
                       cache_len: int):
     """Prefill: latent attention and the cache (B, cache_len, C)."""
@@ -663,15 +672,16 @@ def mla_layer_decode(p, x, cache: dict, pos, cfg: ModelConfig,
     return _mla_out(p, x, o, cfg), {"c": c}
 
 
-def mla_layer_decode_paged(p, x, pool, i, tables, pos, live,
+def mla_layer_decode_paged(p, x, leaf, i, tables, pos, live,
                            cfg: ModelConfig, rules: ShardingRules):
-    """One-token decode against a paged latent pool (n, NB, bt, R), of
-    which this layer owns ``pool[i]``, a token's C latent values in the
+    """One-token decode against a paged latent pool {"c": (n, NB, bt, R)},
+    of which this layer owns ``c[i]``, a token's C latent values in the
     first lanes of its row: the counterpart of
     :func:`attn_layer_decode_paged` (one scatter and one gather into the
     stacked leaf, block 0 the zero block, dead slots rewrite what they
     read)."""
     B = x.shape[0]
+    pool = leaf["c"]
     bt, C = pool.shape[2], cfg.latent_dim
     W = tables.shape[1] * bt
     qa, rows = _mla_qkv(p, x, cfg, pos[:, None])
@@ -686,14 +696,15 @@ def mla_layer_decode_paged(p, x, pool, i, tables, pos, live,
         ctx = _context(pool[i, tables], (B, W), (C,))
         mask = (jnp.arange(W)[None, :] <= pos[:, None])[:, None, :]
         o = _mla_attend(qa, ctx, mask, cfg)
-    return _mla_out(p, x, o, cfg), pool
+    return _mla_out(p, x, o, cfg), {"c": pool}
 
 
-def mla_layer_prefill_paged(p, x, pool, i, table_row, start, valid,
+def mla_layer_prefill_paged(p, x, leaf, i, table_row, start, valid,
                             cfg: ModelConfig, rules: ShardingRules):
     """One prefill chunk (B == 1) against the stacked paged latent pool
     at layer ``i``: the counterpart of :func:`attn_layer_prefill_paged`."""
     _, c, _ = x.shape
+    pool = leaf["c"]
     bt, C = pool.shape[2], cfg.latent_dim
     W = table_row.shape[0] * bt
     positions = start + jnp.arange(c)
@@ -709,14 +720,10 @@ def mla_layer_prefill_paged(p, x, pool, i, table_row, start, valid,
         ctx = _context(pool[i, table_row], (1, W), (C,))
         mask = (jnp.arange(W)[None, :] <= positions[:, None])[None]
         o = _mla_attend(qa, ctx, mask, cfg)
-    return _mla_out(p, x, o, cfg), pool
+    return _mla_out(p, x, o, cfg), {"c": pool}
 
 
 # -- cross attention ---------------------------------------------------------
-
-def xattn_defs(cfg: ModelConfig) -> dict:
-    return attn_defs(cfg, cross=True)
-
 
 def xattn_layer(p, x, ctx, cfg: ModelConfig, rules: ShardingRules):
     """Cross-attention to a context (encoder output / image embeddings).
@@ -732,26 +739,24 @@ def xattn_layer(p, x, ctx, cfg: ModelConfig, rules: ShardingRules):
     return x + o.astype(x.dtype)
 
 
-class XAttnCache(NamedTuple):
-    k: jax.Array          # (B, T, Hkv, Dh) — projected context, fixed
-    v: jax.Array
-
-
-def xattn_cache_defs(cfg: ModelConfig, batch: int) -> XAttnCache:
+def xattn_cache_defs(cfg: ModelConfig, batch: int, seq_len: int) -> dict:
+    """{"k", "v": (B, T, Hkv, Dh)}: the projected context, fixed."""
     shp = (batch, cfg.n_ctx_tokens, cfg.n_kv_heads, cfg.head_dim)
-    return XAttnCache(PV(shp, cfg.dtype, ("batch", "", "kv", ""), "zeros"),
-                      PV(shp, cfg.dtype, ("batch", "", "kv", ""), "zeros"))
+    return {n: PV(shp, cfg.dtype, ("batch", "", "kv", ""), "zeros")
+            for n in ("k", "v")}
 
 
-def xattn_prefill_cache(p, ctx, cfg: ModelConfig) -> XAttnCache:
+def xattn_layer_prefill(p, x, ctx, cfg: ModelConfig, rules: ShardingRules):
+    """Cross-attention and its cache, the context's keys and values."""
+    x = xattn_layer(p, x, ctx, cfg, rules)
     B, T, _ = ctx.shape
     hd = cfg.head_dim
     k = kops.dense(ctx, p["wk"]).reshape(B, T, cfg.n_kv_heads, hd)
     v = kops.dense(ctx, p["wv"]).reshape(B, T, cfg.n_kv_heads, hd)
-    return XAttnCache(k, v)
+    return x, {"k": k, "v": v}
 
 
-def xattn_layer_decode(p, x, cache: XAttnCache, cfg: ModelConfig,
+def xattn_layer_decode(p, x, cache: dict, cfg: ModelConfig,
                        rules: ShardingRules):
     B, S1, d = x.shape
     hd = cfg.head_dim
@@ -759,10 +764,7 @@ def xattn_layer_decode(p, x, cache: XAttnCache, cfg: ModelConfig,
     q = kops.dense(xn, p["wq"]).reshape(B, S1, cfg.n_heads, hd)
     G = cfg.n_heads // cfg.n_kv_heads
     qg = q.reshape(B, S1, cfg.n_kv_heads, G, hd)
-    s = jnp.einsum("bqhgd,bthd->bhgqt", qg.astype(jnp.float32),
-                   cache.k.astype(jnp.float32)) / math.sqrt(hd)
-    pr = jax.nn.softmax(s, axis=-1)
-    o = jnp.einsum("bhgqt,bthd->bqhgd", pr, cache.v.astype(jnp.float32))
+    o = _attend(qg, cache["k"], cache["v"])
     o = kops.dense(o.reshape(B, S1, cfg.n_heads * hd).astype(x.dtype),
                    p["wo"])
     return x + o.astype(x.dtype), cache
@@ -1077,6 +1079,26 @@ def moe_layer(p, x, cfg: ModelConfig, rules: ShardingRules, topology=None):
     return x + y.astype(x.dtype)
 
 
+def moe_layer_paged(p, x, i, cfg: ModelConfig, rules: ShardingRules,
+                    count_rows=None):
+    """A dropless MoE layer in the paged programs, on one device: ``p``'s
+    expert stacks hold every layer's experts, of which this layer's are
+    at ``i`` (:func:`routed_experts`), so they are read in place.  ->
+    (x, None), or with ``count_rows``, a mask over x's rows, (x, how many
+    experts those rows chose)."""
+    if moe_mode(cfg, rules) != "local":
+        raise ValueError("paged serving reads MoE experts in place on one "
+                         f"device; MoE mode {moe_mode(cfg, rules)!r} "
+                         "shards them")
+    with jax.named_scope("mlp"):
+        xn = rmsnorm(x, p["norm"], cfg.norm_eps)
+        y = moe_local(p, xn, cfg, layer=i, count_rows=count_rows)
+        n = None
+        if count_rows is not None:
+            y, n = y
+        return x + y.astype(x.dtype), n
+
+
 def _a2a_stages(rules: ShardingRules, topology) -> list:
     """The expert-dispatch exchange as (axes, size) stages, innermost
     first.
@@ -1227,7 +1249,7 @@ def mamba_defs(cfg: ModelConfig) -> dict:
     }
 
 
-def _ssd_chunked(xh, dtv, Bm, Cm, A, chunk: int, state_in=None):
+def _ssd_chunked(xh, dtv, Bm, Cm, A, chunk: int):
     """Chunked state-space dual form.
 
     xh (B,S,H,P) f32; dtv (B,S,H); Bm/Cm (B,S,N); A (H,) negative.
@@ -1264,10 +1286,8 @@ def _ssd_chunked(xh, dtv, Bm, Cm, A, chunk: int, state_in=None):
         s_next = s_c + dec[:, :, None, None] * s_prev
         return s_next, s_in
 
-    init = (jnp.zeros((Bsz, H, Pd, N), jnp.float32) if state_in is None
-            else state_in)
     s_final, s_ins = jax.lax.scan(
-        scan_fn, init,
+        scan_fn, jnp.zeros((Bsz, H, Pd, N), jnp.float32),
         (S_c.transpose(1, 0, 2, 3, 4), chunk_decay.transpose(1, 0, 2)))
     s_ins = s_ins.transpose(1, 0, 2, 3, 4)            # (B,nc,H,P,N)
     y_off = jnp.einsum("bcqn,bchpn,bcqh->bcqhp", Cc, s_ins, jnp.exp(dA_cs))
@@ -1285,15 +1305,15 @@ def _mamba_project(p, x, cfg: ModelConfig):
 
 
 def mamba_layer(p, x, cfg: ModelConfig, rules: ShardingRules,
-                conv_state=None, ssm_state=None, return_state: bool = False):
-    """Train/prefill Mamba2 block (full sequence, chunked SSD)."""
+                return_state: bool = False):
+    """Train/prefill Mamba2 block (full sequence, chunked SSD); with
+    ``return_state`` also its decode cache."""
     B, S, d = x.shape
     di, N, H = cfg.d_inner_ssm, cfg.ssm_state, cfg.n_ssm_heads
     kc = cfg.ssm_conv
     z, xbc, dtv = _mamba_project(p, x, cfg)
     # depthwise causal conv over (x, B, C)
-    pad = jnp.zeros((B, kc - 1, xbc.shape[-1]), xbc.dtype) \
-        if conv_state is None else conv_state
+    pad = jnp.zeros((B, kc - 1, xbc.shape[-1]), xbc.dtype)
     xbc_p = jnp.concatenate([pad, xbc], axis=1)
     conv = sum(xbc_p[:, i:i + S] * p["conv_w"][i][None, None]
                for i in range(kc)) + p["conv_b"][None, None]
@@ -1303,8 +1323,7 @@ def mamba_layer(p, x, cfg: ModelConfig, rules: ShardingRules,
     dtb = jax.nn.softplus(dtv.astype(jnp.float32) + p["dt_bias"][None, None])
     A = -jnp.exp(p["A_log"])
     y, s_final = _ssd_chunked(xh, dtb, Bm.astype(jnp.float32),
-                              Cm.astype(jnp.float32), A, cfg.ssm_chunk,
-                              ssm_state)
+                              Cm.astype(jnp.float32), A, cfg.ssm_chunk)
     y = y + p["D"][None, None, :, None] * xh          # skip
     y = y.reshape(B, S, di)
     y = rmsnorm(y.astype(x.dtype) * silu(z), p["gnorm"], cfg.norm_eps)
@@ -1312,32 +1331,28 @@ def mamba_layer(p, x, cfg: ModelConfig, rules: ShardingRules,
     res = x + out.astype(x.dtype)
     if return_state:
         new_conv = xbc_p[:, S:S + kc - 1] if kc > 1 else pad
-        return res, (new_conv, s_final.astype(jnp.float32))
+        return res, {"conv": new_conv.astype(cfg.dtype),
+                     "state": s_final.astype(jnp.float32)}
     return res
 
 
-class MambaCache(NamedTuple):
-    conv: jax.Array       # (B, kc-1, di+2N)
-    state: jax.Array      # (B, H, P, N) f32
-
-
-def mamba_cache_defs(cfg: ModelConfig, batch: int) -> MambaCache:
+def mamba_cache_defs(cfg: ModelConfig, batch: int, seq_len: int) -> dict:
+    """{"conv": (B, kc-1, di+2N), "state": (B, H, P, N) float32}."""
     di, N, H = cfg.d_inner_ssm, cfg.ssm_state, cfg.n_ssm_heads
-    return MambaCache(
-        PV((batch, cfg.ssm_conv - 1, di + 2 * N), cfg.dtype,
-           ("batch", "", "model"), "zeros"),
-        PV((batch, H, cfg.ssm_head_dim, N), jnp.float32,
-           ("batch", "model", "", ""), "zeros"))
+    return {"conv": PV((batch, cfg.ssm_conv - 1, di + 2 * N), cfg.dtype,
+                       ("batch", "", "model"), "zeros"),
+            "state": PV((batch, H, cfg.ssm_head_dim, N), jnp.float32,
+                        ("batch", "model", "", ""), "zeros")}
 
 
-def mamba_layer_decode(p, x, cache: MambaCache, cfg: ModelConfig,
+def mamba_layer_decode(p, x, cache: dict, cfg: ModelConfig,
                        rules: ShardingRules):
     """Single-token recurrent step: state <- dA*state + dt*B (x) ; y = C.state."""
     B, S1, d = x.shape
     di, N, H = cfg.d_inner_ssm, cfg.ssm_state, cfg.n_ssm_heads
     kc = cfg.ssm_conv
     z, xbc, dtv = _mamba_project(p, x, cfg)
-    window = jnp.concatenate([cache.conv, xbc], axis=1)       # (B, kc, ch)
+    window = jnp.concatenate([cache["conv"], xbc], axis=1)       # (B, kc, ch)
     conv = jnp.einsum("bkc,kc->bc", window, p["conv_w"]) + p["conv_b"]
     conv = silu(conv)[:, None, :]
     xc, Bm, Cm = jnp.split(conv, [di, di + N], axis=-1)
@@ -1348,10 +1363,106 @@ def mamba_layer_decode(p, x, cache: MambaCache, cfg: ModelConfig,
     Bv = Bm[:, 0].astype(jnp.float32)                         # (B,N)
     Cv = Cm[:, 0].astype(jnp.float32)
     upd = jnp.einsum("bh,bhp,bn->bhpn", dtb, xh, Bv)
-    state = cache.state * dA[:, :, None, None] + upd
+    state = cache["state"] * dA[:, :, None, None] + upd
     y = jnp.einsum("bhpn,bn->bhp", state, Cv) + p["D"][None, :, None] * xh
     y = y.reshape(B, 1, di)
     y = rmsnorm(y.astype(x.dtype) * silu(z), p["gnorm"], cfg.norm_eps)
     out = kops.dense(y, p["out_proj"])
-    new_conv = window[:, 1:] if kc > 1 else cache.conv
-    return x + out.astype(x.dtype), MambaCache(new_conv, state)
+    new_conv = window[:, 1:] if kc > 1 else cache["conv"]
+    return x + out.astype(x.dtype), {"conv": new_conv, "state": state}
+
+
+# ---------------------------------------------------------------------------
+# One record per sublayer kind
+# ---------------------------------------------------------------------------
+
+class Sublayer(NamedTuple):
+    """What the model step does with one sublayer kind (:data:`SUBLAYERS`).
+
+    ``p`` is the sublayer's parameters and ``x`` (B, S, d) its input.  Each
+    form returns x with the residual added; a stateful form also returns
+    the new state, a dict of arrays: a dense cache, or a leaf of the paged
+    pool holding every layer of a stack (this one at ``i``).  A form left
+    None is one the kind does not have.
+
+    defs(cfg)                                    parameter defs
+    forward(p, x, cfg, rules, positions, ctx)    whole sequence, no state
+    cache_defs(cfg, batch, seq_len)              dense-cache defs
+    prefill(p, x, cfg, rules, positions, cache_len, ctx) -> (x, cache)
+    decode(p, x, cache, pos, cfg, rules) -> (x, cache)
+    pool_defs(cfg, (n_blocks, block_tokens))     paged-pool leaf defs
+    decode_paged(p, x, leaf, i, tables, pos, live, cfg, rules) -> (x, leaf)
+    prefill_paged(p, x, leaf, i, table_row, start, valid, cfg, rules)
+    token_values(cfg)        cache values a token adds to one layer
+    whole                    weights the paged programs pass whole (every
+                             layer's stack), not sliced per layer
+    paged(p, x, i, cfg, rules, count_rows) -> (x, count or None): a
+        stateless kind's body in the paged programs, where not ``forward``
+    """
+    defs: Callable
+    forward: Callable
+    cache_defs: Callable | None = None
+    prefill: Callable | None = None
+    decode: Callable | None = None
+    pool_defs: Callable | None = None
+    decode_paged: Callable | None = None
+    prefill_paged: Callable | None = None
+    token_values: Callable = lambda cfg: 0
+    whole: tuple[str, ...] = ()
+    paged: Callable | None = None
+
+
+SUBLAYERS: dict[str, Sublayer] = {
+    ATTN: Sublayer(
+        defs=attn_defs,
+        forward=lambda p, x, cfg, rules, positions, ctx: attn_layer(
+            p, x, cfg, rules, positions),
+        cache_defs=attn_cache_defs,
+        prefill=lambda p, x, cfg, rules, positions, W, ctx:
+            attn_layer_prefill(p, x, cfg, rules, positions, W),
+        decode=attn_layer_decode,
+        pool_defs=attn_pool_defs,
+        decode_paged=attn_layer_decode_paged,
+        prefill_paged=attn_layer_prefill_paged,
+        token_values=lambda cfg: 2 * cfg.n_kv_heads * cfg.head_dim),
+    MLA: Sublayer(
+        defs=mla_defs,
+        forward=lambda p, x, cfg, rules, positions, ctx: mla_layer(
+            p, x, cfg, rules, positions),
+        cache_defs=mla_cache_defs,
+        prefill=lambda p, x, cfg, rules, positions, W, ctx:
+            mla_layer_prefill(p, x, cfg, rules, positions, W),
+        decode=mla_layer_decode,
+        pool_defs=mla_pool_defs,
+        decode_paged=mla_layer_decode_paged,
+        prefill_paged=mla_layer_prefill_paged,
+        token_values=lambda cfg: cfg.latent_dim),
+    XATTN: Sublayer(
+        defs=attn_defs,
+        forward=lambda p, x, cfg, rules, positions, ctx: xattn_layer(
+            p, x, ctx, cfg, rules),
+        cache_defs=xattn_cache_defs,
+        prefill=lambda p, x, cfg, rules, positions, W, ctx:
+            xattn_layer_prefill(p, x, ctx, cfg, rules),
+        decode=lambda p, x, cache, pos, cfg, rules: xattn_layer_decode(
+            p, x, cache, cfg, rules)),
+    MAMBA: Sublayer(
+        defs=mamba_defs,
+        forward=lambda p, x, cfg, rules, positions, ctx: mamba_layer(
+            p, x, cfg, rules),
+        cache_defs=mamba_cache_defs,
+        prefill=lambda p, x, cfg, rules, positions, W, ctx: mamba_layer(
+            p, x, cfg, rules, return_state=True),
+        decode=lambda p, x, cache, pos, cfg, rules: mamba_layer_decode(
+            p, x, cache, cfg, rules)),
+    MLP: Sublayer(
+        defs=mlp_defs,
+        forward=lambda p, x, cfg, rules, positions, ctx: mlp_layer(
+            p, x, cfg, rules)),
+    MOE: Sublayer(
+        defs=lambda cfg: moe_defs_tp(cfg) if cfg.moe_tp else moe_defs(cfg),
+        forward=lambda p, x, cfg, rules, positions, ctx: moe_layer(
+            p, x, cfg, rules),
+        whole=("wg", "wi", "wo"),
+        paged=moe_layer_paged),
+}

@@ -16,16 +16,14 @@ Three entry points per model: ``forward_train`` (loss), ``prefill``
 """
 from __future__ import annotations
 
-import dataclasses
 import functools
-import math
-from typing import Any, NamedTuple
+from typing import Any
 
 import jax
 import jax.numpy as jnp
 
 from repro import substrate
-from repro.configs.base import ATTN, MAMBA, MLA, MLP, MOE, XATTN, ModelConfig
+from repro.configs.base import ModelConfig
 from repro.parallel.sharding import PV, ShardingRules, constraint
 from . import layers as L
 
@@ -39,22 +37,6 @@ def _stack(defs, n: int):
         lambda pv: PV((n,) + pv.shape, pv.dtype, ("",) + pv.logical, pv.init,
                       pv.scale),
         defs, is_leaf=lambda x: isinstance(x, PV))
-
-
-def _sublayer_defs(kind: str, cfg: ModelConfig):
-    if kind == ATTN:
-        return L.attn_defs(cfg)
-    if kind == MLA:
-        return L.mla_defs(cfg)
-    if kind == XATTN:
-        return L.xattn_defs(cfg)
-    if kind == MAMBA:
-        return L.mamba_defs(cfg)
-    if kind == MLP:
-        return L.mlp_defs(cfg)
-    if kind == MOE:
-        return L.moe_defs_tp(cfg) if cfg.moe_tp else L.moe_defs(cfg)
-    raise ValueError(kind)
 
 
 def stacks(cfg: ModelConfig) -> list[tuple[str, tuple, int]]:
@@ -82,18 +64,38 @@ def join_state(cfg: ModelConfig, parts: list):
     return parts[0]
 
 
+def _slots(kinds):
+    """(layer key, slot key, :class:`repro.models.layers.Sublayer`) of
+    each sublayer of a stack's layers ``kinds``, in the order they run."""
+    for li, layer in enumerate(kinds):
+        for si, kind in enumerate(layer):
+            yield f"l{li}", f"s{si}_{kind}", L.SUBLAYERS[kind]
+
+
 def _stack_defs(kinds, n: int, leaf_defs) -> dict:
     """{"l<i>": {"s<j>_<kind>": stacked defs}} over the kinds that
-    ``leaf_defs(kind)`` gives defs for (None: no entry)."""
-    out = {}
+    ``leaf_defs(kind, record)`` gives defs for (None: no entry)."""
+    out = {f"l{li}": {} for li in range(len(kinds))}
     for li, layer in enumerate(kinds):
-        slots = {}
         for si, kind in enumerate(layer):
-            d = leaf_defs(kind)
+            d = leaf_defs(kind, L.SUBLAYERS[kind])
             if d is not None:
-                slots[f"s{si}_{kind}"] = _stack(d, n)
-        out[f"l{li}"] = slots
+                out[f"l{li}"][f"s{si}_{kind}"] = _stack(d, n)
     return out
+
+
+def _layers(body, x, xs, n: int, cfg: ModelConfig):
+    """``body(x, xs_i) -> (x, y_i)`` over a stack's n layers -> (x, the
+    y_i stacked): a scan, or with ``cfg.unroll_layers`` a Python loop —
+    XLA's cost analysis counts a while body once, so the cost-analysis
+    variants of :mod:`repro.launch.dryrun` unroll."""
+    if not cfg.unroll_layers:
+        return jax.lax.scan(body, x, xs)
+    ys = []
+    for i in range(n):
+        x, y = body(x, jax.tree.map(lambda t: t[i], xs))
+        ys.append(y)
+    return x, jax.tree.map(lambda *y: jnp.stack(y), *ys)
 
 
 def model_defs(cfg: ModelConfig) -> dict:
@@ -109,7 +111,7 @@ def model_defs(cfg: ModelConfig) -> dict:
     if not cfg.tie_embeddings:
         defs["head"] = PV((d, Vp), dt, ("", "model"))
     for name, kinds, n in stacks(cfg):
-        defs[name] = _stack_defs(kinds, n, lambda k: _sublayer_defs(k, cfg))
+        defs[name] = _stack_defs(kinds, n, lambda _, rec: rec.defs(cfg))
     if cfg.family == "encdec":
         enc_layer = {"attn": L.attn_defs(cfg), "mlp": L.mlp_defs(cfg)}
         defs["encoder"] = {"layers": _stack(enc_layer, cfg.n_enc_layers),
@@ -124,16 +126,8 @@ def model_defs(cfg: ModelConfig) -> dict:
 # ---------------------------------------------------------------------------
 
 def cache_defs(cfg: ModelConfig, batch: int, seq_len: int) -> dict:
-    def leaf(kind):
-        if kind == ATTN:
-            return L.attn_cache_defs(cfg, batch, seq_len)._asdict()
-        if kind == MLA:
-            return L.mla_cache_defs(cfg, batch, seq_len)
-        if kind == XATTN:
-            return L.xattn_cache_defs(cfg, batch)._asdict()
-        if kind == MAMBA:
-            return L.mamba_cache_defs(cfg, batch)._asdict()
-        return None
+    def leaf(kind, rec):
+        return rec.cache_defs(cfg, batch, seq_len) if rec.cache_defs else None
 
     return join_state(cfg, [_stack_defs(kinds, n, leaf)
                             for _, kinds, n in stacks(cfg)])
@@ -141,31 +135,21 @@ def cache_defs(cfg: ModelConfig, batch: int, seq_len: int) -> dict:
 
 def pool_defs(cfg: ModelConfig, n_blocks: int, block_tokens: int) -> dict:
     """Paged-KV block pool defs: same tree shape as :func:`cache_defs` but
-    each ATTN leaf is ``{"k", "v": (n, n_blocks, block_tokens, *row)}``,
-    a token's Hkv x Dh keys or values in a row of
-    :func:`repro.models.layers.kv_row`, and each MLA leaf ``{"c": (n,
-    n_blocks, block_tokens, R)}``, its latent_dim values in the first of
-    R lanes (:func:`repro.models.layers.pool_row`) — a
-    shared pool of fixed-size token blocks indexed by per-request block
-    tables (block 0 is the reserved zero block).  Paged serving supports
-    pure-attention caches only (no SSM/xattn state) and full attention
-    (no SWA ring), which the serving engine validates."""
+    each leaf is the sublayer's ``pool_defs`` (the attention kinds': a
+    token's keys and values, or latent, in rows of whole vector registers)
+    over (n, n_blocks, block_tokens) — a shared pool of fixed-size token
+    blocks indexed by per-request block tables (block 0 is the reserved
+    zero block).  The one place that refuses what the pool cannot hold:
+    a kind with state but no pool form (SSM, cross-attention) and a
+    sliding window (a ring, not blocks)."""
     if cfg.window:
         raise ValueError("paged KV supports full attention only "
                          f"(cfg.window={cfg.window})")
 
-    blocks = (n_blocks, block_tokens)
-
-    def leaf(kind):
-        if kind == ATTN:
-            row = L.kv_row(cfg)
-            axes = ("", "") + ("kv", "")[:len(row)]
-            return {"k": PV(blocks + row, cfg.dtype, axes, "zeros"),
-                    "v": PV(blocks + row, cfg.dtype, axes, "zeros")}
-        if kind == MLA:
-            return {"c": PV(blocks + (L.pool_row(cfg.latent_dim),),
-                            cfg.dtype, ("", "", ""), "zeros")}
-        if kind in (XATTN, MAMBA):
+    def leaf(kind, rec):
+        if rec.pool_defs:
+            return rec.pool_defs(cfg, (n_blocks, block_tokens))
+        if rec.cache_defs:
             raise ValueError(
                 f"paged KV serving supports attention caches only, "
                 f"layer period has {kind}")
@@ -228,40 +212,18 @@ def encode_context(params, ctx_embeds, cfg: ModelConfig, rules: ShardingRules):
 # Decoder trunk
 # ---------------------------------------------------------------------------
 
-def _apply_slot(kind, sp, x, cfg, rules, positions, ctx):
-    if kind == ATTN:
-        return L.attn_layer(sp, x, cfg, rules, positions, causal=True)
-    if kind == MLA:
-        return L.mla_layer(sp, x, cfg, rules, positions)
-    if kind == XATTN:
-        return L.xattn_layer(sp, x, ctx, cfg, rules)
-    if kind == MAMBA:
-        return L.mamba_layer(sp, x, cfg, rules)
-    if kind == MLP:
-        return L.mlp_layer(sp, x, cfg, rules)
-    if kind == MOE:
-        return L.moe_layer(sp, x, cfg, rules)
-    raise ValueError(kind)
-
-
 def trunk(params, x, cfg: ModelConfig, rules: ShardingRules, positions,
           ctx=None):
     for name, kinds, n in stacks(cfg):
         def body(xc, pp, kinds=kinds):
-            for li, layer in enumerate(kinds):
-                for si, kind in enumerate(layer):
-                    sp = pp[f"l{li}"][f"s{si}_{kind}"]
-                    xc = _apply_slot(kind, sp, xc, cfg, rules, positions, ctx)
-                    xc = constraint(xc, rules, "batch", "act_seq", None)
+            for lk, key, rec in _slots(kinds):
+                xc = rec.forward(pp[lk][key], xc, cfg, rules, positions, ctx)
+                xc = constraint(xc, rules, "batch", "act_seq", None)
             return xc, None
 
         if cfg.remat:
             body = jax.checkpoint(body)
-        if cfg.unroll_layers:
-            for i in range(n):
-                x, _ = body(x, jax.tree.map(lambda t: t[i], params[name]))
-            continue
-        x, _ = jax.lax.scan(body, x, params[name])
+        x, _ = _layers(body, x, params[name], n, cfg)
     return x
 
 
@@ -382,30 +344,13 @@ def prefill(params, tokens, cfg: ModelConfig, rules: ShardingRules,
     W = L.attn_cache_len(cfg, cache_seq_len)
 
     def body(xc, pp, kinds):
-        caches = {}
-        for li, layer in enumerate(kinds):
-            lcaches = {}
-            for si, kind in enumerate(layer):
-                key = f"s{si}_{kind}"
-                sp = pp[f"l{li}"][key]
-                if kind == ATTN:
-                    xc, c = L.attn_layer_prefill(sp, xc, cfg, rules,
-                                                 positions, W)
-                    lcaches[key] = c._asdict()
-                elif kind == MLA:
-                    xc, lcaches[key] = L.mla_layer_prefill(
-                        sp, xc, cfg, rules, positions, W)
-                elif kind == XATTN:
-                    xc = L.xattn_layer(sp, xc, ctx, cfg, rules)
-                    lcaches[key] = L.xattn_prefill_cache(sp, ctx, cfg)._asdict()
-                elif kind == MAMBA:
-                    xc, (conv, state) = L.mamba_layer(sp, xc, cfg, rules,
-                                                      return_state=True)
-                    lcaches[key] = {"conv": conv.astype(cfg.dtype),
-                                    "state": state}
-                else:
-                    xc = _apply_slot(kind, sp, xc, cfg, rules, positions, ctx)
-            caches[f"l{li}"] = lcaches
+        caches = {f"l{li}": {} for li in range(len(kinds))}
+        for lk, key, rec in _slots(kinds):
+            if rec.prefill:
+                xc, caches[lk][key] = rec.prefill(pp[lk][key], xc, cfg, rules,
+                                                  positions, W, ctx)
+            else:
+                xc = rec.forward(pp[lk][key], xc, cfg, rules, positions, ctx)
         xc = constraint(xc, rules, "batch", None, None)
         return xc, caches
 
@@ -414,15 +359,8 @@ def prefill(params, tokens, cfg: ModelConfig, rules: ShardingRules,
         fn = functools.partial(body, kinds=kinds)
         if cfg.remat:
             fn = jax.checkpoint(fn)
-        if cfg.unroll_layers:                  # cost-analysis variants
-            caches = []
-            for i in range(n):
-                x, c = fn(x, jax.tree.map(lambda t: t[i], params[name]))
-                caches.append(c)
-            parts.append(jax.tree.map(lambda *xs: jnp.stack(xs), *caches))
-        else:
-            x, c = jax.lax.scan(fn, x, params[name])
-            parts.append(c)
+        x, c = _layers(fn, x, params[name], n, cfg)
+        parts.append(c)
     logits = logits_fn(params, x[:, -1:], cfg, rules)
     return join_state(cfg, parts), logits
 
@@ -437,95 +375,52 @@ def decode_step(params, token, cache, pos, cfg: ModelConfig,
 
     def body(xc, pc, kinds):
         pp, cc = pc
-        new_caches = {}
-        for li, layer in enumerate(kinds):
-            lcaches = {}
-            for si, kind in enumerate(layer):
-                key = f"s{si}_{kind}"
-                sp = pp[f"l{li}"][key]
-                if kind == ATTN:
-                    c = L.AttnCache(**cc[f"l{li}"][key])
-                    xc, c = L.attn_layer_decode(sp, xc, c, pos, cfg, rules)
-                    lcaches[key] = c._asdict()
-                elif kind == MLA:
-                    xc, lcaches[key] = L.mla_layer_decode(
-                        sp, xc, cc[f"l{li}"][key], pos, cfg, rules)
-                elif kind == XATTN:
-                    c = L.XAttnCache(**cc[f"l{li}"][key])
-                    xc, c = L.xattn_layer_decode(sp, xc, c, cfg, rules)
-                    lcaches[key] = c._asdict()
-                elif kind == MAMBA:
-                    c = L.MambaCache(**cc[f"l{li}"][key])
-                    xc, c = L.mamba_layer_decode(sp, xc, c, cfg, rules)
-                    lcaches[key] = c._asdict()
-                else:
-                    xc = _apply_slot(kind, sp, xc, cfg, rules, None, None)
-            new_caches[f"l{li}"] = lcaches
-        return xc, new_caches
+        new = {f"l{li}": {} for li in range(len(kinds))}
+        for lk, key, rec in _slots(kinds):
+            if rec.decode:
+                xc, new[lk][key] = rec.decode(pp[lk][key], xc, cc[lk][key],
+                                              pos, cfg, rules)
+            else:
+                xc = rec.forward(pp[lk][key], xc, cfg, rules, None, None)
+        return xc, new
 
     parts = []
     for (name, kinds, n), part in zip(stacks(cfg), split_state(cfg, cache)):
-        fn = functools.partial(body, kinds=kinds)
-        if cfg.unroll_layers:                  # cost-analysis variants
-            caches = []
-            for i in range(n):
-                x, c = fn(x, jax.tree.map(lambda t: t[i],
-                                          (params[name], part)))
-                caches.append(c)
-            parts.append(jax.tree.map(lambda *xs: jnp.stack(xs), *caches))
-        else:
-            x, c = jax.lax.scan(fn, x, (params[name], part))
-            parts.append(c)
+        x, c = _layers(functools.partial(body, kinds=kinds), x,
+                       (params[name], part), n, cfg)
+        parts.append(c)
     logits = logits_fn(params, x, cfg, rules)
     return logits, join_state(cfg, parts)
 
 
-#: the MoE weights the paged programs pass whole, not per layer
-EXPERT_STACKS = ("wg", "wi", "wo")
-
-
 def _paged_layers(params, x, pool, cfg: ModelConfig, rules: ShardingRules,
-                  attend, count_rows=None):
+                  step: str, args: tuple, count_rows=None):
     """Scan every stack of layers with its share of the paged pool in the
-    carry: ``attend(kind, sp, x, pool_leaf, i) -> (x, pool_leaf)`` runs
-    each attention sublayer on the stack's whole pool leaf and the layer's
-    index ``i``, so its rows are written in place (with the pool donated
-    to the program, no layer's pool is sliced, copied or written back).
-    MoE sublayers route through :func:`repro.models.layers.moe_local`, on
-    one device only.  Their expert stacks stay out of the scan: each
-    layer's grouped matmuls take the whole stack and ``i``, and read its
-    experts in place.  Returns (x, new pool, reached): with
-    ``count_rows``, a mask over x's rows, ``reached`` holds per MoE layer,
-    in order, how many experts those rows chose; else None."""
-    if cfg.n_experts and L.moe_mode(cfg, rules) != "local":
-        raise ValueError("paged serving reads MoE experts in place on one "
-                         f"device; MoE mode {L.moe_mode(cfg, rules)!r} "
-                         "shards them")
-
-    def body(carry, xs, kinds, experts):
+    carry: each sublayer whose kind the pool holds runs its paged form
+    ``step`` (``"decode_paged"`` or ``"prefill_paged"``, given ``args``)
+    on the stack's whole pool leaf and the layer's index ``i``, so its
+    rows are written in place (with the pool donated to the program, no
+    layer's pool is sliced, copied or written back).  A sublayer's
+    ``whole`` weights stay out of the scan: MoE's grouped matmuls take
+    every layer's expert stack and ``i``, and read its experts in place.
+    Returns (x, new pool, reached): with ``count_rows``, a mask over x's
+    rows, ``reached`` holds per MoE layer, in order, how many experts
+    those rows chose; else None."""
+    def body(carry, xs, kinds, whole):
         xc, cc = carry
         pp, i = xs
-        new_pool, reached = {}, []
-        for li, layer in enumerate(kinds):
-            lpool = dict(cc[f"l{li}"])
-            for si, kind in enumerate(layer):
-                key = f"s{si}_{kind}"
-                sp = pp[f"l{li}"][key]
-                if kind in (ATTN, MLA):
-                    xc, lpool[key] = attend(kind, sp, xc, lpool[key], i)
-                elif kind == MOE:
-                    sp = dict(sp, **experts[f"l{li}"][key])
-                    with jax.named_scope("mlp"):
-                        xn = L.rmsnorm(xc, sp["norm"], cfg.norm_eps)
-                        y = L.moe_local(sp, xn, cfg, layer=i,
-                                        count_rows=count_rows)
-                        if count_rows is not None:
-                            y, n = y
-                            reached.append(n)
-                        xc = xc + y.astype(xc.dtype)
-                else:
-                    xc = _apply_slot(kind, sp, xc, cfg, rules, None, None)
-            new_pool[f"l{li}"] = lpool
+        new_pool, reached = {lk: dict(sub) for lk, sub in cc.items()}, []
+        for lk, key, rec in _slots(kinds):
+            sp = dict(pp[lk][key], **whole[lk][key])
+            if rec.pool_defs:
+                xc, new_pool[lk][key] = getattr(rec, step)(
+                    sp, xc, new_pool[lk][key], i, *args, cfg, rules)
+            elif rec.paged:
+                xc, n = rec.paged(sp, xc, i, cfg, rules, count_rows)
+                if n is not None:
+                    reached.append(n)
+            else:
+                xc = rec.forward(sp, xc, cfg, rules, None, None)
         if count_rows is None:
             return (xc, new_pool), None
         return (xc, new_pool), (jnp.stack(reached) if reached
@@ -533,17 +428,15 @@ def _paged_layers(params, x, pool, cfg: ModelConfig, rules: ShardingRules,
 
     parts, counts = [], []
     for (name, kinds, n), part in zip(stacks(cfg), split_state(cfg, pool)):
-        scanned = {lk: {key: {w: v for w, v in sp.items()
-                              if not key.endswith(f"_{MOE}")
-                              or w not in EXPERT_STACKS}
-                        for key, sp in slots.items()}
-                   for lk, slots in params[name].items()}
-        experts = {lk: {key: {w: sp[w] for w in EXPERT_STACKS}
-                        for key, sp in slots.items()
-                        if key.endswith(f"_{MOE}")}
-                   for lk, slots in params[name].items()}
+        scanned = {lk: {} for lk in params[name]}
+        whole = {lk: {} for lk in params[name]}
+        for lk, key, rec in _slots(kinds):
+            sp = params[name][lk][key]
+            scanned[lk][key] = {w: v for w, v in sp.items()
+                                if w not in rec.whole}
+            whole[lk][key] = {w: sp[w] for w in rec.whole}
         (x, part), r = jax.lax.scan(
-            functools.partial(body, kinds=kinds, experts=experts),
+            functools.partial(body, kinds=kinds, whole=whole),
             (x, part), (scanned, jnp.arange(n)))
         if count_rows is not None:
             counts.append(r.reshape(-1))
@@ -562,17 +455,8 @@ def decode_step_paged(params, token, pool, tables, pos, live,
     :func:`decode_step` given tables whose gathered view equals the dense
     cache (zero block 0 ≡ unwritten dense rows)."""
     x = embed_tokens(params, token, cfg, rules)
-
-    def attend(kind, sp, xc, c, i):
-        if kind == MLA:
-            xc, pc = L.mla_layer_decode_paged(sp, xc, c["c"], i, tables, pos,
-                                              live, cfg, rules)
-            return xc, {"c": pc}
-        xc, pk, pv = L.attn_layer_decode_paged(sp, xc, c["k"], c["v"], i,
-                                               tables, pos, live, cfg, rules)
-        return xc, {"k": pk, "v": pv}
-
-    x, new_pool, _ = _paged_layers(params, x, pool, cfg, rules, attend)
+    x, new_pool, _ = _paged_layers(params, x, pool, cfg, rules,
+                                   "decode_paged", (tables, pos, live))
     logits = logits_fn(params, x, cfg, rules)
     return logits, new_pool
 
@@ -590,20 +474,11 @@ def prefill_chunk(params, tokens, pool, table_row, start, valid,
     experts the chunk's valid rows chose.  Compiles once per chunk shape,
     not once per prompt length."""
     x = embed_tokens(params, tokens, cfg, rules)
-
-    def attend(kind, sp, xc, c, i):
-        if kind == MLA:
-            xc, pc = L.mla_layer_prefill_paged(sp, xc, c["c"], i, table_row,
-                                               start, valid, cfg, rules)
-            return xc, {"c": pc}
-        xc, pk, pv = L.attn_layer_prefill_paged(
-            sp, xc, c["k"], c["v"], i, table_row, start, valid, cfg, rules)
-        return xc, {"k": pk, "v": pv}
-
     count = (jnp.arange(tokens.shape[1]) < valid)[None] \
         if cfg.n_experts else None
-    x, new_pool, reached = _paged_layers(params, x, pool, cfg, rules, attend,
-                                         count)
+    x, new_pool, reached = _paged_layers(params, x, pool, cfg, rules,
+                                         "prefill_paged",
+                                         (table_row, start, valid), count)
     logits = logits_fn(params, x, cfg, rules)
     if reached is None:
         return logits, new_pool

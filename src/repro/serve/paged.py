@@ -55,8 +55,9 @@ import jax.numpy as jnp
 import numpy as np
 from jax.profiler import TraceAnnotation
 
-from repro.configs.base import ATTN, MLA, ModelConfig
+from repro.configs.base import ModelConfig
 from repro.models import lm
+from repro.models.layers import SUBLAYERS
 from repro.parallel.sharding import ShardingRules
 from repro.testing.timing import now
 from .engine import Request, validate_prompt
@@ -66,16 +67,16 @@ PREFILL, DECODE = 0, 1
 
 
 def kv_token_bytes(cfg: ModelConfig) -> int:
-    """Cache bytes per token across the whole model, by kind of
-    attention: k and v of every attention sublayer instance, the latent
-    row (``latent_dim`` values) of every latent-attention one — the unit
-    both engines' resident-bytes metrics are denominated in.  It counts
-    the values a token's cache holds, not the lanes past them that pad a
-    paged pool row to whole vector registers (:func:`lm.pool_defs`: the
-    latent's 576 values in 640 lanes), so the dense and paged engines
-    compare on the same data."""
-    per = {ATTN: 2 * cfg.n_kv_heads * cfg.head_dim, MLA: cfg.latent_dim}
-    values = sum(per.get(kind, 0) * n for _, kinds, n in lm.stacks(cfg)
+    """Cache bytes per token across the whole model: each sublayer
+    instance's ``token_values`` (:data:`repro.models.layers.SUBLAYERS`;
+    k and v of an attention sublayer, the latent row of a latent-attention
+    one) — the unit both engines' resident-bytes metrics are denominated
+    in.  It counts the values a token's cache holds, not the lanes past
+    them that pad a paged pool row to whole vector registers
+    (:func:`lm.pool_defs`: the latent's 576 values in 640 lanes), so the
+    dense and paged engines compare on the same data."""
+    values = sum(SUBLAYERS[kind].token_values(cfg) * n
+                 for _, kinds, n in lm.stacks(cfg)
                  for layer in kinds for kind in layer)
     return values * jnp.dtype(cfg.dtype).itemsize
 
@@ -224,8 +225,6 @@ class PagedServingEngine:
 
     def __init__(self, cfg: ModelConfig, params, rules: ShardingRules,
                  scfg: PagedServeConfig):
-        if cfg.window:
-            raise ValueError("paged serving supports full attention only")
         B, S, bt = scfg.max_batch, scfg.max_seq, scfg.block_tokens
         if S % bt:
             raise ValueError(f"max_seq {S} not a multiple of "

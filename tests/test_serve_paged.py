@@ -467,3 +467,46 @@ def test_decode_writes_each_live_row_at_its_block_and_offset(arch):
                             dl[:, b, p].reshape(len(pl), w),
                             rtol=tol, atol=tol)
                     assert (kind == ATTN) == (leaf in ("k", "v"))
+
+
+#: a one-layer smoke config whose layer is the one sublayer of each kind
+KIND_ARCH = {"attn": "llama3-8b", "mla": "deepseek-v2-lite",
+             "xattn": "llama-3.2-vision-11b", "mamba": "mamba2-370m",
+             "mlp": "llama3-8b", "moe": "deepseek-v2-lite"}
+
+
+@pytest.mark.parametrize("kind", sorted(KIND_ARCH))
+def test_sublayer_record_drives_cache_pool_and_token_bytes(kind):
+    """Each kind's record decides, on its own: whether the dense cache
+    and the paged pool hold a leaf for it, the bytes a token adds to the
+    cache, and whether the paged engine refuses it (a kind with state
+    the pool cannot hold is named in the refusal)."""
+    import dataclasses
+    import jax.numpy as jnp
+    from repro.configs import get_smoke_config
+    from repro.models import lm
+    from repro.models.layers import SUBLAYERS
+    from repro.parallel.sharding import default_rules
+    from repro.serve import PagedServeConfig, PagedServingEngine
+    rec = SUBLAYERS[kind]
+    assert set(SUBLAYERS) == set(KIND_ARCH)
+    cfg = dataclasses.replace(get_smoke_config(KIND_ARCH[kind]),
+                              period=((kind,),), n_layers=1, first_dense=0)
+    key = f"s0_{kind}"
+    assert (key in lm.cache_defs(cfg, 2, 32)["l0"]) == \
+        (rec.cache_defs is not None)
+    assert kv_token_bytes(cfg) == \
+        rec.token_values(cfg) * 1 * jnp.dtype(cfg.dtype).itemsize
+    scfg = PagedServeConfig(max_batch=2, max_seq=32, block_tokens=8,
+                            n_blocks=4, chunk=16)
+    if rec.cache_defs is not None and rec.pool_defs is None:
+        with pytest.raises(ValueError, match=f"has {kind}"):
+            lm.pool_defs(cfg, 5, 8)
+        with pytest.raises(ValueError, match=f"has {kind}"):
+            PagedServingEngine(cfg, None, default_rules(None), scfg)
+        assert kind in ("xattn", "mamba")
+        return
+    assert (key in lm.pool_defs(cfg, 5, 8)["l0"]) == \
+        (rec.pool_defs is not None)
+    eng = PagedServingEngine(cfg, None, default_rules(None), scfg)
+    assert (key in eng.pool["l0"]) == (rec.pool_defs is not None)
