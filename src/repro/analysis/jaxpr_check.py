@@ -382,10 +382,11 @@ def _kernel_entries():
             lambda q, k, v: fa.flash_attention(q, k, v, interpret=True))(
                 z(1, 4, 256, 64), z(1, 2, 256, 64), z(1, 2, 256, 64))),
         ("paged_attention[T256,bt16,D64]", lambda: jax.make_jaxpr(
-            lambda q, kp, vp, tb, ln: pa.paged_attention(
-                q, kp, vp, tb, ln, interpret=True))(
-                z(1, 2, 2, 64), z(2, 17, 16, 64), z(2, 17, 16, 64),
-                jnp.zeros((1, 16), jnp.int32), jnp.zeros((1,), jnp.int32))),
+            lambda q, kp, vp, i, tb, ln: pa.paged_attention(
+                q, kp, vp, i, tb, ln, interpret=True))(
+                z(1, 2, 2, 64), z(2, 17, 16, 128), z(2, 17, 16, 128),
+                jnp.int32(1), jnp.zeros((1, 16), jnp.int32),
+                jnp.zeros((1,), jnp.int32))),
         ("rmsnorm[D4096]", lambda: jax.make_jaxpr(
             lambda x, g: rn.rmsnorm(x, g, interpret=True))(
                 z(64, 4096), z(4096))),

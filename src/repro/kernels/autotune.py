@@ -43,7 +43,7 @@ from .vrf import VREG_GROUP_BYTES, VRF_BYTES
 DEFAULTS: dict[str, dict[str, int]] = {
     "matmul": {"bm": 128, "bn": 128, "bk": 128},
     "flash_attention": {"bq": 128, "bk": 128},
-    "paged_attention": {"bt": 16},
+    "paged_attention": {"bt": 16, "pages": 8},
     "rmsnorm": {"bm": 8},
     "reduction": {"block": 2048},
     "stencil": {"bh": 8, "bw": 256},
@@ -53,7 +53,8 @@ KERNELS = tuple(DEFAULTS)
 #: problem-shape conventions, documented once:
 #:   matmul           (M, K, N)
 #:   flash_attention  (B, Hq, Hkv, S, Sk, D)
-#:   paged_attention  (B, Hq, Hkv, T, D)  — T = max tokens (nblk * bt)
+#:   paged_attention  (B, Hq, Hkv, T, D)  — T = max tokens (nblk * bt);
+#:                    bt sizes the pool, pages (blocks per copy) the call
 #:   rmsnorm          (R, D)
 #:   reduction        (n,)
 #:   stencil          (H, W)  — interior grid, before halo padding
@@ -98,11 +99,12 @@ def candidate_buffers(kernel: str, shape, dtype: str, cfg: dict
                 ("m", bq * 4), ("l", bq * 4), ("acc", bq * D * 4)]
     if kernel == "paged_attention":
         _, Hq, Hkv, _, D = shape
-        gq = Hq // Hkv
-        bt = cfg["bt"]
-        return [("q", gq * D * isz), ("k", bt * D * isz),
-                ("v", bt * D * isz), ("out", gq * D * isz),
-                ("m", gq * 4), ("l", gq * 4), ("acc", gq * D * 4)]
+        span = cfg["bt"] * cfg["pages"]
+        # one slot's query and output rows, the double-buffered K and V
+        # copies of `pages` blocks, and the per-head softmax state
+        return [("q", Hq * D * isz), ("k", 2 * span * Hkv * D * isz),
+                ("v", 2 * span * Hkv * D * isz), ("out", Hq * D * 4),
+                ("m", Hq * 4), ("l", Hq * 4), ("acc", Hq * D * 4)]
     if kernel == "rmsnorm":
         D = shape[1]
         bm = cfg["bm"]
@@ -132,8 +134,8 @@ def grid_steps(kernel: str, shape, cfg: dict) -> int:
         B, Hq, _, S, Sk, _ = shape
         return B * Hq * (S // cfg["bq"]) * (Sk // cfg["bk"])
     if kernel == "paged_attention":
-        B, _, Hkv, T, _ = shape
-        return B * Hkv * (T // cfg["bt"])
+        B, _, _, T, _ = shape
+        return B * (T // (cfg["bt"] * cfg["pages"]))
     if kernel == "rmsnorm":
         return shape[0] // cfg["bm"]
     if kernel == "reduction":
@@ -167,7 +169,9 @@ def enumerate_candidates(kernel: str, shape, dtype: str = "float32", *,
     elif kernel == "paged_attention":
         T = shape[3]
         lo = min_block or 8
-        cands = [{"bt": bt} for bt in _pow2_divisors(T, lo, 256)]
+        cands = [{"bt": bt, "pages": pages}
+                 for bt in _pow2_divisors(T, lo, 256)
+                 for pages in _pow2_divisors(T // bt, 1, 16)]
     elif kernel == "rmsnorm":
         R = shape[0]
         # the kernel rounds a row block up to the TPU sublane tile (8)
@@ -247,19 +251,18 @@ def model_cost(kernel: str, shape, dtype: str, cfg: dict, *,
         stream_bytes = G * (bq * D + 2 * bk * D) * isz + B * Hq * S * D * isz
     elif kernel == "paged_attention":
         B, Hq, Hkv, T, D = shape
-        bt = cfg["bt"]
-        gq = Hq // Hkv
-        strip = min(gq, 8)
-        # one block's score/softmax/weighted-sum strip, like flash_attention
-        # but with a single q row group per grid step (decode: one token)
-        c_strip = (_sim_cycles(p, "fmatmul", _bpl(p, bt),
+        span = cfg["bt"] * cfg["pages"]
+        strip = min(Hq, 8)
+        # one copy's score/softmax/weighted-sum strip over `span`
+        # positions for every query head of the slot (decode: one token)
+        c_strip = (_sim_cycles(p, "fmatmul", _bpl(p, span),
                                M=strip, K=D, rows_blk=strip)
-                   + _sim_cycles(p, "softmax", _bpl(p, bt), rows=strip)
+                   + _sim_cycles(p, "softmax", _bpl(p, span), rows=strip)
                    + _sim_cycles(p, "fmatmul", _bpl(p, D),
-                                 M=strip, K=bt, rows_blk=strip))
-        compute = c_strip * (gq / strip) * G
-        # each grid step streams one gathered K/V block; q/out ride once
-        stream_bytes = G * 2 * bt * D * isz + 2 * B * Hq * D * isz
+                                 M=strip, K=span, rows_blk=strip))
+        compute = c_strip * (Hq / strip) * G
+        # each loop step copies `pages` K/V blocks; q/out ride once
+        stream_bytes = G * 2 * span * Hkv * D * isz + 2 * B * Hq * D * isz
     elif kernel == "rmsnorm":
         R, D = shape
         bm = cfg["bm"]
@@ -338,17 +341,21 @@ def _measure_case(kernel: str, shape, dtype: str, cfg: dict):
                                interpret=interpret, **cfg)
         return fn, (arr(B, Hq, S, D), arr(B, Hkv, Sk, D), arr(B, Hkv, Sk, D))
     if kernel == "paged_attention":
+        from repro.models.layers import pool_row
         from . import paged_attention as _pa
         B, Hq, Hkv, T, D = shape
         bt = cfg["bt"]          # baked into the pool layout, not a kwarg
         gq, nblk = Hq // Hkv, T // bt
-        kpool = arr(Hkv, B * nblk + 1, bt, D)
-        vpool = arr(Hkv, B * nblk + 1, bt, D)
+        row = (Hkv, D) if D % 128 == 0 else (pool_row(Hkv * D),)
+        kpool = arr(1, B * nblk + 1, bt, *row)
+        vpool = arr(1, B * nblk + 1, bt, *row)
         tables = jnp.arange(1, B * nblk + 1, dtype=jnp.int32) \
             .reshape(B, nblk)   # disjoint full tables, block 0 reserved
         lens = jnp.full((B,), T, jnp.int32)
-        fn = functools.partial(_pa.paged_attention, interpret=interpret)
-        return fn, (arr(B, Hkv, gq, D), kpool, vpool, tables, lens)
+        fn = functools.partial(_pa.paged_attention, pages=cfg["pages"],
+                               interpret=interpret)
+        return fn, (arr(B, Hkv, gq, D), kpool, vpool, jnp.int32(0), tables,
+                    lens)
     if kernel == "rmsnorm":
         from . import rmsnorm as _rms
         R, D = shape

@@ -1,8 +1,9 @@
 """Jit'd public wrappers: pick the Pallas kernel on TPU, the jnp reference
 elsewhere (the CPU dry-run lowers the jnp path; interpret=True is for tests).
-The models reach Pallas through ``rmsnorm`` and, for routed experts,
-``ragged_dot_f32`` (megablox's grouped matmul); their projections go
-through ``dense``, which is XLA's dot everywhere.
+The models reach Pallas through ``rmsnorm``, ``paged_attention`` (the
+paged decode step's attention over each slot's live blocks) and, for
+routed experts, ``ragged_dot_f32`` (megablox's grouped matmul); their
+projections go through ``dense``, which is XLA's dot everywhere.
 A call traced for a multi-device mesh also takes the reference: XLA cannot
 partition a Mosaic kernel across devices, and GSPMD partitions the jnp
 expression natively.
@@ -260,18 +261,25 @@ def ragged_dot_f32(x, w, group_sizes):
                               group_sizes)
 
 
-def paged_attention(q, kpool, vpool, tables, lens, *, use_pallas=None):
-    """Paged decode attention: q (B, Hkv, G, D) against a block pool
-    (Hkv, NB, bt, D) through per-sequence block tables.  The ref path is
-    the gather + masked-softmax expression the serving engine's decode
-    layers inline; the Pallas path never materialises the gathered view
-    (scalar-prefetched tables drive the DMA).  The block size is baked
-    into the pool layout, so tuning happens where the pool is *sized*
-    (``serve.paged`` / :func:`paged_block_tokens`), not per call."""
+def paged_attention(q, kpool, vpool, i, tables, lens, reference, *,
+                    use_pallas=None, pages=None):
+    """Paged decode attention: q (B, Hkv, G, hd), one token per slot,
+    against layer ``i`` of the stacked pool leaves (n, NB, bt, *row)
+    through block tables (B, max_blocks), over each slot's first
+    ``lens`` (B,) positions -> (B, Hkv, G, hd) float32.  On one TPU the
+    Pallas kernel copies in only the blocks that hold live positions,
+    ``pages`` at a time (tuned); elsewhere ``reference()`` runs: the
+    caller's own gather and softmax over the whole table.  The block size
+    is baked into the pool, so it is tuned where the pool is sized
+    (:func:`paged_block_tokens`), not per call."""
     m = _mode(use_pallas, q, kpool, vpool)
     if m == "ref":
-        return ref.paged_attention(q, kpool, vpool, tables, lens)
-    return _pa.paged_attention(q, kpool, vpool, tables, lens,
+        return reference()
+    B, Hkv, G, hd = q.shape
+    T = tables.shape[1] * kpool.shape[2]
+    pages = _resolve("paged_attention", (B, Hkv * G, Hkv, T, hd),
+                     kpool.dtype, pages=pages)["pages"]
+    return _pa.paged_attention(q, kpool, vpool, i, tables, lens, pages=pages,
                                interpret=(m == "interpret"))
 
 

@@ -70,26 +70,3 @@ def rmsnorm(x, gamma, eps=1e-6):
     ms = jnp.mean(xf * xf, axis=-1, keepdims=True)
     return (xf * jax.lax.rsqrt(ms + eps) * gamma.astype(jnp.float32)
             ).astype(x.dtype)
-
-
-def paged_attention(q, kpool, vpool, tables, lens):
-    """Paged decode attention reference: q (B,Hkv,G,D), pools
-    (Hkv,NB,bt,D), tables (B,nblk) int32, lens (B,) int32 -> (B,Hkv,G,D).
-    Gathers the dense per-sequence view through the block table and masks
-    positions >= lens; rows with no visible keys produce zeros (matching
-    the kernel's zero-initialised accumulator)."""
-    B, Hkv, G, D = q.shape
-    bt = kpool.shape[2]
-    k = jnp.transpose(kpool[:, tables], (1, 0, 2, 3, 4)) \
-        .reshape(B, Hkv, -1, D).astype(jnp.float32)
-    v = jnp.transpose(vpool[:, tables], (1, 0, 2, 3, 4)) \
-        .reshape(B, Hkv, -1, D).astype(jnp.float32)
-    s = jnp.einsum("bhgd,bhtd->bhgt", q.astype(jnp.float32), k)
-    s = s / math.sqrt(D)
-    T = k.shape[2]
-    visible = jnp.arange(T)[None, :] < lens[:, None]          # (B, T)
-    s = jnp.where(visible[:, None, None, :], s, -1e30)
-    p = jax.nn.softmax(s, axis=-1)
-    out = jnp.einsum("bhgt,bhtd->bhgd", p, v)
-    out = jnp.where(visible.any(-1)[:, None, None, None], out, 0.0)
-    return out.astype(q.dtype)

@@ -459,10 +459,12 @@ def attn_layer_decode_paged(p, x, leaf, i, tables, pos, live,
     carries ``i``, so no layer's pool is sliced out; rows are written and
     read whole.  Dead slots write a predicated no-op (they re-write the
     zero block's current value) so the batched step stays shape-stable.
-    The gathered view ``_context(pk[i, tables], ...)`` is elementwise
-    identical to the dense cache rows, and the math below is the same
-    attention core as :func:`attn_layer_decode`'s (:func:`_attend`) —
-    bit-identical streams."""
+    Attention goes through the ``kops.paged_attention`` seam: on one TPU
+    its kernel reads only each live slot's blocks of layer ``i``;
+    elsewhere the gathered view ``_context(pk[i, tables], ...)``, which
+    is elementwise identical to the dense cache rows, goes through the
+    same attention core as :func:`attn_layer_decode`'s (:func:`_attend`)
+    — bit-identical streams."""
     B, S1, d = x.shape                      # S1 == 1
     pk, pv = leaf["k"], leaf["v"]
     bt = pk.shape[2]
@@ -482,15 +484,21 @@ def attn_layer_decode_paged(p, x, leaf, i, tables, pos, live,
         pk = pk.at[i, blk, off].set(nk)
         pv = pv.at[i, blk, off].set(nv)
     with jax.named_scope("attn.core"):
-        ck = _context(pk[i, tables], (B, W), (Hkv, hd))
-        cv = _context(pv[i, tables], (B, W), (Hkv, hd))
-        ck = constraint(ck, rules, "batch", None, "kv", None)
-        cv = constraint(cv, rules, "batch", None, "kv", None)
         G = cfg.n_heads // cfg.n_kv_heads
-        qg = q.reshape(B, S1, Hkv, G, hd)
-        idx = jnp.arange(W)
-        o = _attend(qg, ck, cv,                        # (B, W) causal
-                    lambda: (idx <= pos[:, None])[:, None, None, None, :])
+        qg = q.reshape(B, Hkv, G, hd)
+
+        def gathered():
+            ck = _context(pk[i, tables], (B, W), (Hkv, hd))
+            cv = _context(pv[i, tables], (B, W), (Hkv, hd))
+            ck = constraint(ck, rules, "batch", None, "kv", None)
+            cv = constraint(cv, rules, "batch", None, "kv", None)
+            idx = jnp.arange(W)
+            return _attend(qg[:, None], ck, cv,        # (B, W) causal
+                           lambda: (idx <= pos[:, None])[:, None, None, None,
+                                                         :])[:, 0]
+
+        o = kops.paged_attention(qg, pk, pv, i, tables,
+                                 jnp.where(live, pos + 1, 0), gathered)
     with jax.named_scope("attn.out"):
         o = kops.dense(o.reshape(B, S1, cfg.n_heads * hd).astype(x.dtype),
                        p["wo"])

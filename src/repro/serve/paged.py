@@ -27,16 +27,19 @@ claimed bit-identical to the dense engine (the attention view is the
 padded ``max_seq`` window rather than the prompt length).
 
 Block sizing: ``block_tokens`` must tile ``max_seq`` (and the prefill
-chunk).  Nothing else bounds it — decode gathers the pool with XLA, so a
-block is any size the pool's memory can hold.
+chunk).  Nothing else bounds it: decode reads whole blocks (on one TPU
+the paged attention kernel copies in each live slot's blocks, elsewhere
+XLA gathers the table), so a block is any size the pool's memory can
+hold.
 
 Tracing: the three programs are named (``jit_decode_step_paged``,
 ``jit_prefill_chunk``, ``jit_prefill`` in a device trace), and every
 :meth:`PagedServingEngine.step` is a ``serve.step`` span holding
 ``serve.admit`` (``admitted``, ``blocked``), ``serve.prefill_chunk``
 (``start``, ``valid``), ``serve.decode.prepare``, ``serve.decode.dispatch``
-(``rows``), ``serve.decode.readback`` and ``serve.retire``.  A span costs
-a flag check when no profiler runs.  Each request is stamped on
+(``rows``, ``kv_blocks``: the step's share of ``kv_blocks_read``),
+``serve.decode.readback`` and ``serve.retire``.  A span costs a flag
+check when no profiler runs.  Each request is stamped on
 :func:`repro.testing.timing.now`: ``t_submit``, ``t_admit``,
 ``t_prefill_start`` (dispatch of its first chunk, or of its whole-prompt
 prefill) and ``t_first``, which split its time to the first token; at
@@ -256,6 +259,10 @@ class PagedServingEngine:
         self.peak_live = 0
         self.cow_copies = 0
         self.decode_steps = 0
+        # pool blocks holding live positions, summed over the live slots
+        # of every decode step: ceil((pos + 1) / block_tokens) per slot,
+        # what the paged attention kernel reads of each layer
+        self.kv_blocks_read = 0
         self.prefill_chunks = 0
 
         # named, so a device trace shows which program ran; ``lm`` is
@@ -518,12 +525,16 @@ class PagedServingEngine:
             for i in live:
                 tok[i, 0] = self.slots[i].out[-1]
                 lv[i] = True
-        with TraceAnnotation("serve.decode.dispatch", rows=len(live)):
+            bt = self.scfg.block_tokens
+            blocks = int(sum(self.slot_pos[i] // bt + 1 for i in live))
+        with TraceAnnotation("serve.decode.dispatch", rows=len(live),
+                             kv_blocks=blocks):
             logits, self.pool = self._step(
                 self.params, jnp.asarray(tok), self.pool,
                 jnp.asarray(self.tables), jnp.asarray(self.slot_pos),
                 jnp.asarray(lv))
             self.decode_steps += 1
+            self.kv_blocks_read += blocks
         with TraceAnnotation("serve.decode.readback"):
             nxt = np.asarray(jnp.argmax(logits[:, 0], axis=-1))
         for i in live:

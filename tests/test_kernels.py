@@ -110,6 +110,65 @@ def test_rmsnorm(shape):
     np.testing.assert_allclose(got, ref.rmsnorm(x, g), rtol=2e-5, atol=2e-5)
 
 
+def _paged_case(hd, G, dtype, *, H=4, n=3, NB=12, bt=8, max_blocks=4):
+    """A stacked pool whose layers other than 1 hold NaN, and four slots
+    at lens 0 (dead), 1, one whole block and max_seq, the last two
+    sharing their first block."""
+    from repro.models.layers import pool_row
+    row = (H, hd) if hd % 128 == 0 else (pool_row(H * hd),)
+    kp = jnp.full((n, NB, bt) + row, jnp.nan, dtype)
+    vp = jnp.full((n, NB, bt) + row, jnp.nan, dtype)
+    kp = kp.at[1].set(rand((NB, bt) + row, dtype))
+    vp = vp.at[1].set(rand((NB, bt) + row, dtype))
+    tables = np.zeros((4, max_blocks), np.int32)
+    tables[1, 0] = 1
+    tables[2, 0] = tables[3, 0] = 2                     # a shared block
+    tables[3, 1:] = [5, 9, 3]
+    lens = np.array([0, 1, bt, bt * max_blocks], np.int32)
+    q = rand((4, H, G, hd), dtype)
+    return q, kp, vp, jnp.int32(1), jnp.asarray(tables), jnp.asarray(lens)
+
+
+@pytest.mark.parametrize("pages", [1, 3, 8])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("hd,G", [(96, 1), (96, 2), (128, 1), (128, 4)],
+                         ids=["packed", "packed-gqa", "heads", "heads-gqa"])
+def test_paged_attention(hd, G, dtype, pages):
+    """The live-block kernel against the gather + softmax the model runs
+    off the TPU (``layers._attend`` over ``layers._context``), in both row
+    layouts: one packed row of heads of 96, or whole heads of 128.  Only
+    layer 1's blocks are finite, so reading any other layer's shows as
+    NaN; the dead slot gives zeros.  The kernel runs in the TPU interpret
+    mode, which lands each copy only at its wait, fills unwritten
+    on-chip memory with NaN and looks for races between copies and
+    reads."""
+    from jax._src.pallas.mosaic.interpret import interpret_pallas_call
+    from jax.experimental.pallas import tpu as pltpu
+    from repro.kernels import paged_attention as pa
+    from repro.models import layers as L
+    q, kp, vp, i, tables, lens = _paged_case(hd, G, dtype)
+    B, H = q.shape[:2]
+    W = tables.shape[1] * kp.shape[2]
+
+    def gathered():
+        ck = L._context(kp[i, tables], (B, W), (H, hd))
+        cv = L._context(vp[i, tables], (B, W), (H, hd))
+        idx = jnp.arange(W)
+        return L._attend(q[:, None], ck, cv, lambda: (
+            idx < lens[:, None])[:, None, None, None, :])[:, 0]
+
+    got = np.asarray(pa.paged_attention(
+        q, kp, vp, i, tables, lens, pages=pages,
+        interpret=pltpu.InterpretParams(detect_races=True)))
+    assert not interpret_pallas_call.races.races_found
+    want = np.asarray(ops.paged_attention(q, kp, vp, i, tables, lens,
+                                          gathered, use_pallas=False))
+    assert got.shape == q.shape and got.dtype == np.float32
+    assert np.isfinite(got).all()
+    assert not got[0].any()                             # the dead slot
+    np.testing.assert_allclose(got[1:], want[1:], rtol=1e-5, atol=1e-5)
+
+
 # ---------------------------------------------------------------------------
 # hypothesis sweeps (randomized shapes within tiling envelopes)
 # ---------------------------------------------------------------------------

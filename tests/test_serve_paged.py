@@ -510,3 +510,98 @@ def test_sublayer_record_drives_cache_pool_and_token_bytes(kind):
         (rec.pool_defs is not None)
     eng = PagedServingEngine(cfg, None, default_rules(None), scfg)
     assert (key in eng.pool["l0"]) == (rec.pool_defs is not None)
+
+
+# ---------------------------------------------------------------------------
+# the paged attention kernel in the decode step, and what it reads
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("arch", ["llama3-8b", "wide-heads"])
+def test_decode_step_with_the_kernel_forced_matches_the_gather(
+        monkeypatch, arch, dtype):
+    """Greedy decode through ``decode_step_paged`` with the live-block
+    kernel forced (interpret mode) against the default path, which off
+    the TPU is the gather + softmax: the same tokens, and logits within
+    1e-5 (float32 weights; 3.2e-6 seen) or 1e-2, about two bfloat16
+    steps (bfloat16, where the two attention outputs may round apart
+    once cast for the output projection; equal so far).  GQA
+    (2 queries per kv head), packed rows (heads of 16) and whole heads
+    (128), a dead slot and a slot that crosses a block."""
+    import dataclasses
+    import functools
+    import jax
+    import jax.numpy as jnp
+    from repro.kernels import ops as kops
+    from repro.models import lm
+    from repro.parallel.sharding import init_params
+    cfg, rules, _ = _paged_setup(arch)
+    cfg = dataclasses.replace(cfg, dtype=jnp.dtype(dtype).type)
+    params = init_params(lm.model_defs(cfg), jax.random.key(0))
+    B, S, bt = 3, 32, 8
+    nb = S // bt
+    tables = np.zeros((B, nb), np.int32)
+    tables[0] = np.arange(1, nb + 1)
+    tables[2] = np.arange(nb + 1, 2 * nb + 1)      # slot 1 is dead
+    live = np.array([True, False, True])
+    start = np.array([0, 0, 5], np.int32)
+    prompt = np.random.default_rng(0).integers(1, cfg.vocab_size, (4, B))
+
+    def run():
+        step = jax.jit(functools.partial(lm.decode_step_paged, cfg=cfg,
+                                         rules=rules), donate_argnums=(2,))
+        pool = _zero_pool(cfg, 2 * nb + 1, bt)
+        toks, logits = [], []
+        tok = prompt[0]
+        for t in range(10):
+            lg, pool = step(params, jnp.asarray(tok[:, None], jnp.int32),
+                            pool, jnp.asarray(tables),
+                            jnp.asarray(start + t * live), jnp.asarray(live))
+            lg = np.asarray(lg[:, 0], np.float32)
+            tok = prompt[t + 1] if t + 1 < len(prompt) else lg.argmax(-1)
+            toks.append(lg.argmax(-1)[live])
+            logits.append(lg[live])
+        return np.array(toks), np.array(logits)
+
+    want_tok, want = run()
+    monkeypatch.setattr(kops, "paged_attention", functools.partial(
+        kops.paged_attention, use_pallas=True))
+    got_tok, got = run()
+    np.testing.assert_array_equal(got_tok, want_tok)
+    tol = 1e-5 if dtype == "float32" else 1e-2
+    np.testing.assert_allclose(got, want, rtol=tol, atol=tol)
+
+
+def test_engine_counts_the_kv_blocks_each_decode_step_reads():
+    """``kv_blocks_read`` adds, per decode step, ceil((pos + 1) / bt) of
+    every live slot: the blocks the paged attention kernel reads."""
+    import jax
+    from repro.configs import get_smoke_config
+    from repro.models import lm
+    from repro.parallel.sharding import default_rules, init_params
+    from repro.serve import PagedServeConfig, PagedServingEngine
+    cfg = get_smoke_config("llama3-8b")
+    params = init_params(lm.model_defs(cfg), jax.random.key(0))
+    bt = 8
+    eng = PagedServingEngine(cfg, params, default_rules(None),
+                             PagedServeConfig(max_batch=3, max_seq=64,
+                                              block_tokens=bt, n_blocks=16,
+                                              chunk=16))
+    seen = []
+    real = eng._step
+
+    def spy(p, t, pool, tab, pos, lv):
+        seen.append((np.array(pos), np.array(lv)))     # copies: the engine
+        return real(p, t, pool, tab, pos, lv)          # moves slot_pos on
+
+    eng._step = spy
+    for rid, (n, new) in enumerate([(5, 12), (17, 3), (8, 9), (30, 4)]):
+        eng.submit(Request(rid=rid, prompt=np.arange(1, n + 1,
+                                                     dtype=np.int32),
+                           max_new_tokens=new))
+    eng.run()
+    eng.shutdown()
+    assert len(seen) == eng.decode_steps > 0
+    want = sum(int((pos[lv] // bt + 1).sum()) for pos, lv in seen)
+    assert eng.kv_blocks_read == want
+    assert want < eng.decode_steps * 3 * eng.max_blocks

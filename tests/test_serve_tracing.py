@@ -104,6 +104,9 @@ def test_engine_spans_nest_under_each_step(params, tmp_path):
     rows = [args["rows"] for _, _, n, args in inner
             if n == "serve.decode.dispatch"]
     assert len(rows) == eng.decode_steps and max(rows) == 2
+    # and the pool blocks its live slots' attention reads
+    assert sum(args["kv_blocks"] for _, _, n, args in inner
+               if n == "serve.decode.dispatch") == eng.kv_blocks_read > 0
 
 
 @pytest.mark.parametrize("chunk", [0, CHUNK], ids=["whole", "chunked"])

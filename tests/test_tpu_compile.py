@@ -151,15 +151,22 @@ def test_flash_attention_compiles_for_v5e(one_chip, on_tpu, S):
     assert _kernels(compiled) == 1
 
 
-def test_paged_attention_compiles_for_v5e(one_chip, on_tpu):
-    """The block-table decode kernel over a pool of 16-token blocks
-    (max_batch 4, max_seq 1024)."""
-    B, Hkv, Dh, bt, nblk = 4, PHI3.n_kv_heads, PHI3.head_dim, 16, 64
-    G = PHI3.n_heads // Hkv
-    pool = _sds((Hkv, B * nblk + 1, bt, Dh), jnp.bfloat16, one_chip)
-    compiled = jax.jit(ops.paged_attention).lower(
-        _sds((B, Hkv, G, Dh), jnp.bfloat16, one_chip), pool, pool,
-        _sds((B, nblk), jnp.int32, one_chip),
+@pytest.mark.parametrize("arch,S,layers", [("phi3-mini-3.8b", 1024, 32),
+                                           ("deepseek-7b", 2048, 15)],
+                         ids=["phi3", "deepseek"])
+def test_paged_attention_compiles_for_v5e(one_chip, on_tpu, arch, S, layers):
+    """The live-block decode kernel over a benchmark cell's stacked pool
+    (8 slots, blocks of 16, pool of 6 x max_seq tokens): phi3's heads of
+    96 packed in one 3,072-lane row, deepseek's whole heads of 128."""
+    from repro.models.layers import kv_row
+    cfg = get_config(arch)
+    B, bt, Hkv, hd = 8, 16, cfg.n_kv_heads, cfg.head_dim
+    G = cfg.n_heads // Hkv
+    pool = _sds((layers, 6 * S // bt + 1, bt) + kv_row(cfg), jnp.bfloat16,
+                one_chip)
+    compiled = jax.jit(lambda *a: ops.paged_attention(*a, None)).lower(
+        _sds((B, Hkv, G, hd), jnp.bfloat16, one_chip), pool, pool,
+        _sds((), jnp.int32, one_chip), _sds((B, S // bt), jnp.int32, one_chip),
         _sds((B,), jnp.int32, one_chip)).compile()
     assert _kernels(compiled) == 1
 
@@ -206,8 +213,9 @@ def test_one_layer_prefill_chunk_compiles_for_v5e(one_chip, on_tpu):
 
 def test_one_layer_paged_decode_step_compiles_for_v5e(one_chip, on_tpu):
     """The paged engine's decode step at full width (8 slots, max_seq
-    1024, blocks of 16): the 3 norms are the only Pallas kernels, and
-    every sublayer scope reaches the compiled program's op metadata."""
+    1024, blocks of 16): the 3 norms and the paged attention kernel are
+    the only Pallas kernels, and every sublayer scope reaches the
+    compiled program's op metadata."""
     cfg = dataclasses.replace(PHI3, n_layers=1)
     rules = default_rules(None)
     B, S, bt = 8, 1024, 16
@@ -220,7 +228,7 @@ def test_one_layer_paged_decode_step_compiles_for_v5e(one_chip, on_tpu):
         _sds((B, S // bt), jnp.int32, one_chip),
         _sds((B,), jnp.int32, one_chip),
         _sds((B,), jnp.bool_, one_chip)).compile()
-    assert _kernels(compiled) == NORMS
+    assert _kernels(compiled) == NORMS + 1
     assert _no_projection_kernel(compiled, cfg)
     assert _scopes(compiled) == SCOPES
 
@@ -230,14 +238,15 @@ def test_one_layer_paged_decode_step_compiles_for_v5e(one_chip, on_tpu):
                          ids=["phi3", "deepseek"])
 def test_two_layer_paged_decode_reads_weights_in_place(one_chip, on_tpu,
                                                        arch, B, S):
-    """The benchmark's decode step over a real layer scan (two layers):
-    each projection's dot reads its layer's slice of the stacked weight
-    in place, so no instruction writes one layer's weight out first."""
+    """The benchmark's decode step over a real layer scan (two layers),
+    jitted as the engine jits it (the pool donated): each projection's
+    dot reads its layer's slice of the stacked weight in place, so no
+    instruction writes one layer's weight out first."""
     cfg = dataclasses.replace(get_config(arch), n_layers=2)
     rules = default_rules(None)
     bt = 16
     step = jax.jit(lambda p, t, pool, tab, pos, lv: lm.decode_step_paged(
-        p, t, pool, tab, pos, lv, cfg, rules))
+        p, t, pool, tab, pos, lv, cfg, rules), donate_argnums=(2,))
     compiled = step.lower(
         _tree_sds(lm.model_defs(cfg), one_chip),
         _sds((B, 1), jnp.int32, one_chip),
